@@ -210,8 +210,10 @@ def main() -> int:
 
     from ddl_tpu.parallel.layout import POLICIES
     from ddl_tpu.parallel.mesh import virtual_cpu_mesh
+    from ddl_tpu.utils import compile_cache
 
-    virtual_cpu_mesh(args.devices, probe=False)
+    compile_cache.enable()
+    virtual_cpu_mesh(args.devices)  # a CPU audit by definition
 
     rows = [audit_layout(p, args.devices, tiny=not args.full_width)
             for p in POLICIES]

@@ -56,10 +56,10 @@ def measure(seq_len: int, workers: int, layout: str, steps: int,
         tr.params, tr.opt_state, xs, ys, ws, jnp.int32(0)
     ).compile()
     mem = compiled.memory_analysis()
-    force((xs, ys, ws), all_leaves=True)
+    force((xs, ys, ws))
     t0 = time.perf_counter()
     p, o, loss = compiled(tr.params, tr.opt_state, xs, ys, ws, jnp.int32(0))
-    loss = float(loss)  # host fetch: the true barrier
+    loss = float(loss)  # the fetch closes the bracket
     dt = time.perf_counter() - t0
     assert loss == loss, "non-finite loss"  # NaN guard
     return {
@@ -82,7 +82,10 @@ def main() -> None:
     ap.add_argument("--json", default=None)
     args = ap.parse_args()
 
-    virtual_cpu_mesh(8, probe=True)
+    from ddl_tpu.utils import compile_cache
+
+    compile_cache.enable()
+    virtual_cpu_mesh(8)  # a CPU-mesh memory audit by definition
     from ddl_tpu.models.transformer import LMSpec
 
     # Small widths keep the CPU runtime in minutes; the sequence length is

@@ -50,8 +50,10 @@ def main() -> int:
     args = ap.parse_args()
 
     from ddl_tpu.parallel.mesh import virtual_cpu_mesh
+    from ddl_tpu.utils import compile_cache
 
-    virtual_cpu_mesh(args.pp, probe=False)
+    compile_cache.enable()
+    virtual_cpu_mesh(args.pp)
 
     import jax
 
@@ -81,7 +83,7 @@ def main() -> int:
             )
             params, opt, xs, ys, ws = state
             # Warmup compiles; every timed bracket closes with the host
-            # fetch of the loss (the true barrier — bench.py discipline).
+            # fetch of the loss.
             _, _, l = fn(params, opt, xs, ys, ws)
             float(l)
             times = []

@@ -169,9 +169,8 @@ def main() -> int:
     ap.add_argument("--steps", type=int, default=30)
     ap.add_argument("--batch", type=int, default=800)
     ap.add_argument("--cpu", action="store_true",
-                    help="force the virtual CPU mesh (default: use whatever "
-                         "platform is active, CPU-forcing only if too few "
-                         "devices)")
+                    help="run on the virtual CPU mesh (default: the active "
+                         "platform, which must have --devices devices)")
     ap.add_argument("--json", default=None)
     ap.add_argument("--repeats", type=int, default=3,
                     help="measurements per cell; the record keeps best "
@@ -186,12 +185,20 @@ def main() -> int:
                          "(default: all but async_replicated)")
     args = ap.parse_args()
 
+    from ddl_tpu.utils import compile_cache
+
+    compile_cache.enable()
+    if args.cpu:
+        virtual_cpu_mesh(args.devices)
+
     import jax
 
-    if args.cpu:
-        virtual_cpu_mesh(args.devices, probe=False)
-    else:
-        virtual_cpu_mesh(args.devices, probe=True)
+    if len(jax.devices()) < args.devices:
+        raise SystemExit(
+            f"--devices {args.devices}: the active platform "
+            f"({jax.devices()[0].platform}) has {len(jax.devices())}; "
+            "pass --cpu for the virtual mesh"
+        )
 
     results: dict[str, dict[int, float]] = {}
     medians: dict[str, dict[int, float]] = {}

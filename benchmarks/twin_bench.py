@@ -81,6 +81,10 @@ def main() -> None:
     ap.add_argument("--json", type=str, default=None)
     args = ap.parse_args()
 
+    from ddl_tpu.utils import compile_cache
+
+    compile_cache.enable()
+
     from ddl_tpu.models.transformer import LMSpec
     from ddl_tpu.obs import MetricRegistry
     from ddl_tpu.obs.goodput import fleet_summary

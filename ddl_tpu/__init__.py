@@ -27,7 +27,4 @@ Layout:
     utils/      metrics/profiling, checkpoint/resume
 """
 
-from . import compat as _compat  # noqa: F401  (JAX version graft — must run
-# before any module touches jax.shard_map / lax.pcast; see compat.py)
-
 __version__ = "0.1.0"

@@ -11,7 +11,7 @@
 offline:
 
 - **Goodput**: per-span-name wall-time totals mapped onto the
-  obs.goodput phase taxonomy (``prefill_chunk`` -> prefill,
+  obs.goodput phase vocabulary (``prefill_chunk`` -> prefill,
   ``decode_tick`` -> decode, ``train/span`` -> compute, ...), with the
   trace-side goodput fraction. This is the offline twin of the live
   ``time_in_seconds{phase=}`` gauges — the trace carries only closed
@@ -62,7 +62,7 @@ import sys
 from .trace import FLEET_EVENTS, read_jsonl
 
 # Span-name -> goodput phase for the trace-side attribution (the
-# live-gauge taxonomy of obs.goodput, minus the residual-only phases).
+# live-gauge vocabulary of obs.goodput, minus the residual-only phases).
 SPAN_PHASE = {
     "prefill_chunk": "prefill",
     "decode_tick": "decode",

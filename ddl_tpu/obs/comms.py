@@ -69,7 +69,12 @@ _DTYPE_BYTES = {"f32": 4, "bf16": 2, "f16": 2, "s32": 4, "u32": 4, "f64": 8,
 _COLLECTIVES = ("all-reduce", "reduce-scatter", "all-gather", "all-to-all",
                 "collective-permute")
 
-_OP_PAT = re.compile(r"=\s*(.*?)\s(" + "|".join(_COLLECTIVES) + r")\(")
+# ``-start``: the TPU compiler schedules collective-permute (and, where
+# it overlaps them, all-gather / all-reduce) as async start/done pairs;
+# the start line carries the groups, so it is the one counted.
+_OP_PAT = re.compile(
+    r"=\s*(.*?)\s(" + "|".join(_COLLECTIVES) + r")(-start)?\("
+)
 _SHAPE_PAT = re.compile(r"([a-z0-9]+)\[([0-9,]*)\]")
 # replica_groups={{0,2},{1,3}} — the explicit form this backend emits.
 _GROUPS_PAT = re.compile(r"replica_groups=\{(\{[^}]*\}(?:,\{[^}]*\})*)\}")
@@ -130,7 +135,9 @@ def collective_ops(hlo_text: str) -> list[dict]:
     Handles tuple-shaped (fused) results — ``= (f32[5882], f32[])
     all-reduce(...)`` counts EVERY member shape, so a fused full-vector
     all-reduce can never hide behind a scalar sibling (the audit's whole
-    point is catching exactly that regression). Each row also carries
+    point is catching exactly that regression) — and the async
+    ``<op>-start`` spelling a TPU program uses (reported under the
+    plain op name; the ``-done`` half is not a second op). Each row also carries
     ``groups`` — the op's device groups (replica_groups, iota or
     permute pairs; ``None`` when the line names no groups) — the raw
     material :func:`publish_program_ledger` turns into per-mesh-axis
@@ -141,9 +148,14 @@ def collective_ops(hlo_text: str) -> list[dict]:
         if not m:
             continue
         result_txt, op = m.group(1), m.group(2)
+        members = _SHAPE_PAT.findall(result_txt)
+        if m.group(3) and op in ("all-gather", "collective-permute"):
+            # An async start returns (operand, result[, contexts]):
+            # only the result is traffic.
+            members = members[1:2]
         shapes = []
         total_bytes = 0
-        for dtype, dims in _SHAPE_PAT.findall(result_txt):
+        for dtype, dims in members:
             shape = [int(d) for d in dims.split(",") if d] if dims else []
             elems = 1
             for d in shape:
@@ -282,6 +294,9 @@ def publish_program_ledger(registry, hlo_text: str, *, program: str,
 # value `fit_roofline` recovers from measured rows is the honest one).
 ICI_BW_BY_KIND: tuple[tuple[str, float], ...] = (
     ("v5p", 6.0e11),
+    # A v5e reports device_kind "TPU v5 lite"; 1,600 Gbit/s per chip
+    # (Google Cloud "TPU v5e" page) = 2.0e11 bytes/s.
+    ("v5 lite", 2.0e11),
     ("v5e", 2.0e11),
     ("v5litepod", 2.0e11),
     ("v4", 3.0e11),
@@ -295,16 +310,14 @@ ICI_BW_BY_KIND: tuple[tuple[str, float], ...] = (
 CPU_NOMINAL_ICI_BW = 1e10
 
 
-_warned_kinds: set = set()
-
-
 def ici_bw_per_device(device=None, override: float | None = None) -> float:
     """Nominal interconnect bytes/s for one device: ``override`` wins;
-    else the ``device_kind`` table; else the CPU nominal fallback. An
-    ACCELERATOR kind the table doesn't know warns once per kind —
-    silently anchoring its comms roofline to the CPU nominal would
-    model every step as hopelessly comms-bound (the exact failure mode
-    ``cost.peak_flops_per_device`` guards for MFU)."""
+    else the ``device_kind`` table; else, for a CPU device (or none),
+    the CPU nominal fallback. An ACCELERATOR kind the table doesn't
+    know raises ``cost.UnknownDeviceKind`` — anchoring its comms
+    roofline to the CPU nominal would model every step as hopelessly
+    comms-bound (the exact failure mode ``cost.peak_flops_per_device``
+    guards for MFU)."""
     if override is not None:
         if override <= 0:
             raise ValueError(
@@ -318,17 +331,14 @@ def ici_bw_per_device(device=None, override: float | None = None) -> float:
         if key in kind:
             return bw
     platform = str(getattr(device, "platform", "cpu")).lower()
-    if platform != "cpu" and kind not in _warned_kinds:
-        import warnings
+    if platform != "cpu":
+        from .cost import UnknownDeviceKind
 
-        _warned_kinds.add(kind)
-        warnings.warn(
-            f"unknown accelerator device_kind {kind!r}: comms roofline "
-            f"gauges will use the CPU nominal anchor "
-            f"({CPU_NOMINAL_ICI_BW:.0e} B/s) and read absurdly "
-            "comms-bound — pass --ici-bw (or ici_bw=) with the chip's "
-            "real link bandwidth",
-            stacklevel=2,
+        raise UnknownDeviceKind(
+            f"unknown accelerator device_kind {kind!r}: no interconnect "
+            "row in obs.comms.ICI_BW_BY_KIND — add one with its source, "
+            "or pass --ici-bw (ici_bw=) with the chip's real link "
+            "bandwidth"
         )
     return CPU_NOMINAL_ICI_BW
 

@@ -40,10 +40,12 @@ test oracle and this module can only disagree by a real bug):
 
 Peak FLOP/s come from :data:`PEAK_FLOPS_BY_KIND` (per-chip dense
 **bf16** marketing peaks, matched on the JAX ``device_kind`` string)
-with a ``--peak-flops`` override; unknown kinds (including CPU) fall
-back to :data:`CPU_NOMINAL_PEAK_FLOPS` so CPU runs still produce a
-number — an order-of-magnitude anchor, clearly not a measured roofline
-(override it for real CPU studies).
+with a ``--peak-flops`` override; CPU devices fall back to
+:data:`CPU_NOMINAL_PEAK_FLOPS` so CPU runs still produce a number — an
+order-of-magnitude anchor, clearly not a measured roofline (override
+it for real CPU studies). An ACCELERATOR kind the table does not know
+raises (:class:`UnknownDeviceKind`): a utilization anchored to the
+wrong peak is worse than none.
 
 **Precision-aware denominator** (ISSUE 19): the table rows are bf16
 peaks, but an fp32 run's matmuls cannot reach them — TPU MXUs run fp32
@@ -61,8 +63,11 @@ from __future__ import annotations
 
 # Per-chip peak dense FLOP/s by device-kind substring (lowercase), most
 # specific first. TPU entries are the published bf16 peaks per chip.
+# A v5e reports ``device_kind == "TPU v5 lite"``; 197e12 is the Google
+# Cloud "TPU v5e" page's bf16 figure.
 PEAK_FLOPS_BY_KIND: tuple[tuple[str, float], ...] = (
     ("v5p", 459e12),
+    ("v5 lite", 197e12),
     ("v5e", 197e12),
     ("v5litepod", 197e12),
     ("v4", 275e12),
@@ -82,7 +87,10 @@ CPU_NOMINAL_PEAK_FLOPS = 5e10
 FP32_PEAK_FRACTION = 0.5
 
 
-_warned_kinds: set = set()
+class UnknownDeviceKind(LookupError):
+    """An accelerator whose ``device_kind`` is in neither peak table
+    (this module's FLOP/s rows, ``obs.comms``'s interconnect rows).
+    Add the row with its source, or pass the override."""
 
 
 def peak_flops_per_device(device=None, override: float | None = None,
@@ -92,12 +100,12 @@ def peak_flops_per_device(device=None, override: float | None = None,
     ``override`` wins (taken as the peak at the ACTIVE precision — the
     operator pinning a roofline pins the one their run can reach); else
     the ``device_kind`` table (bf16 rows, halved for fp32 per
-    :data:`FP32_PEAK_FRACTION`); else the CPU nominal fallback
-    (precision-independent — it is an fp32-ish anchor). An ACCELERATOR
-    kind the table doesn't know (a new TPU generation, a GPU) warns
-    once per kind — silently anchoring its MFU to the CPU nominal
-    would report utilizations orders of magnitude above 1.0 as if they
-    were real."""
+    :data:`FP32_PEAK_FRACTION`); else, for a CPU device (or none), the
+    CPU nominal fallback (precision-independent — it is an fp32-ish
+    anchor). An ACCELERATOR kind the table doesn't know (a new TPU
+    generation, a GPU) raises :class:`UnknownDeviceKind` — anchoring
+    its MFU to the CPU nominal would report utilizations orders of
+    magnitude above 1.0 as if they were real."""
     if precision not in ("bf16", "fp32"):
         raise ValueError(
             f"unknown peak precision {precision!r} (bf16 or fp32)"
@@ -115,16 +123,12 @@ def peak_flops_per_device(device=None, override: float | None = None,
             return peak * (FP32_PEAK_FRACTION if precision == "fp32"
                            else 1.0)
     platform = str(getattr(device, "platform", "cpu")).lower()
-    if platform != "cpu" and kind not in _warned_kinds:
-        import warnings
-
-        _warned_kinds.add(kind)
-        warnings.warn(
-            f"unknown accelerator device_kind {kind!r}: MFU gauges will "
-            f"use the CPU nominal anchor ({CPU_NOMINAL_PEAK_FLOPS:.0e} "
-            "FLOP/s) and read far above 1.0 — pass --peak-flops (or "
-            "peak_flops=) with the chip's real peak",
-            stacklevel=2,
+    if platform != "cpu":
+        raise UnknownDeviceKind(
+            f"unknown accelerator device_kind {kind!r}: no peak FLOP/s "
+            "row in obs.cost.PEAK_FLOPS_BY_KIND — add one with its "
+            "source, or pass --peak-flops (peak_flops=) with the chip's "
+            "real peak"
         )
     return CPU_NOMINAL_PEAK_FLOPS
 

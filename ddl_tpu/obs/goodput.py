@@ -22,7 +22,7 @@ tick wall time minus its measured sub-brackets — lands in ``host``
 (bookkeeping overhead) or ``idle`` (no device work this tick), never
 on the floor.
 
-Phase taxonomy (one vocabulary per kind, validated at ``add``):
+Phase names (one vocabulary per kind, validated at ``add``):
 
 - ``train``: ``compute`` (span dispatch — the goodput), ``staging``
   (host->device upload of the train set), ``compile`` (program

@@ -34,6 +34,13 @@ import jax
 
 from jax.experimental.pallas.ops.tpu import flash_attention as _fa
 
+# The bundled kernel's default q/k block (``BlockSizes.get_default``): on
+# TPU it refuses a sequence that is not a whole number of blocks
+# ("block_q=128 should be smaller or equal to q_seq_len", "kv_seq_len
+# should be divisible by block_k_major"). ``SeqConfig.validate_topology``
+# rejects such a config up front; the reference twin has no such limit.
+FLASH_BLOCK = 128
+
 
 def flash_attention_bthd(
     q: jax.Array, k: jax.Array, v: jax.Array, *, causal: bool = False,
@@ -43,8 +50,7 @@ def flash_attention_bthd(
     kernel wants ``[B, H, T, D]`` — transposed in and out). Causality is
     from position 0 (aligned q/k — the full/ulysses cases); there is no
     offset support, so this cannot serve as the ring's travelling-block
-    kernel. On TPU, T should be a multiple of the kernel's 128-lane
-    block for best tiling (the kernel validates its own constraints).
+    kernel. On TPU, T must be a multiple of :data:`FLASH_BLOCK`.
 
     ``platform`` is the platform of the devices the computation will run
     on (``mesh.devices.flat[0].platform`` for a mesh program — what

@@ -260,7 +260,14 @@ def ring_attention_shard(
     perm = [(s, (s + 1) % axis_size) for s in range(axis_size)]
 
     def block_update(m, l, acc, q, qpos, k, v, kpos):
-        s_tile = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32)
+        # Scores leave the MXU in fp32 (its accumulator's own dtype),
+        # never rounded to the inputs' bf16 first. Not only precision: on
+        # TPU, differentiating a bf16-OUTPUT dot inside the causal skip's
+        # lax.cond returns all-NaN dq/dk (v5e, jaxlib 0.9.0; the same
+        # body outside a conditional, or with this fp32 output, is exact
+        # — found by chip_smoke.py's 2x2 ring phase, PR 21).
+        s_tile = jnp.einsum("bqhd,bkhd->bhqk", q, k,
+                            preferred_element_type=jnp.float32)
         s_tile = s_tile * scale
         if causal:
             s_tile = jnp.where(

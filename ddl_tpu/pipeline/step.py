@@ -18,7 +18,7 @@ saved stage INPUT (activation-recompute pipelining: per in-flight
 microbatch a stage holds one ``[mb, T, E]`` input, never the attention
 residuals) — so no gradient ever rides an autodiff transpose of
 ``ppermute``/``psum`` whose rule varies across JAX generations
-(``ddl_tpu.compat``; the same explicit-gradient discipline as
+(the same explicit-gradient discipline as
 ``collectives.tp_allreduce``). Megatron tensor parallelism composes
 INSIDE the stage unchanged: ``jax.vjp`` honours the f/g ``custom_vjp``
 pair, so tp's activation psums run in lockstep across the tp axis at

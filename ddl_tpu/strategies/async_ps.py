@@ -517,11 +517,11 @@ class AsyncTrainer:
         if tree is not None:
             state = self._place_state(tree["state"])
         # Stage the full epoch on the mesh once, BEFORE the clock starts
-        # (transfers are async/lazy; slicing device-resident rounds is free
+        # (transfers are async; slicing device-resident rounds is free
         # and keeps the sharding).
         xs_dev = multihost.put(self.mesh, data_spec, xs_all)
         ys_dev = multihost.put(self.mesh, data_spec, ys_all)
-        guarded(lambda: force((xs_dev, ys_dev, state), all_leaves=True),
+        guarded(lambda: force((xs_dev, ys_dev, state)),
                 dispatch_timeout, "train-set staging")
         history: list[tuple[int, int, float]] = []
         worker_history: list[tuple[int, int, list[float]]] = []
@@ -593,7 +593,6 @@ class AsyncTrainer:
                         state, ps_full, _ = compiled[hi - lo](
                             state, xs_dev[lo:hi], ys_dev[lo:hi], rngs, sched
                         )
-                        # barrier: the compiled[...] round dispatch
                         force_within(
                             ps_full, dispatch_timeout,
                             f"round dispatch at global round {ground}",

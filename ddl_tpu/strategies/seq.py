@@ -205,13 +205,31 @@ class SeqConfig:
     def dtype(self):
         return self.policy().compute_dtype
 
-    def validate_topology(self) -> None:
-        """Fail-fast pipeline topology validation (one place, unit-
-        tested): SeqTrainer calls this before ANY device work, so a
+    def validate_topology(self, seq_len: int | None = None,
+                          platform: str | None = None) -> None:
+        """Fail-fast topology validation (one place, unit-tested):
+        SeqTrainer calls this before ANY device work, so a
         misconfiguration is a clean ValueError with the fix, never a
         shape error deep inside shard_map. Benchmarks that measure the
         step machinery directly (pipeline_bubble's microbatches=1
-        zero-pipelining anchor) construct configs without it."""
+        zero-pipelining anchor) construct configs without it.
+
+        ``seq_len`` and ``platform`` (the dataset's sequence length and
+        the mesh's device platform, where the caller knows them) gate
+        the one shape rule a TPU kernel imposes: the flash kernel takes
+        whole 128-token blocks only."""
+        if (self.attn_impl == "flash" and platform == "tpu"
+                and seq_len is not None):
+            from ..ops.attention import FLASH_BLOCK
+
+            if seq_len % FLASH_BLOCK:
+                padded = -(-seq_len // FLASH_BLOCK) * FLASH_BLOCK
+                raise ValueError(
+                    f"attn_impl='flash' on TPU needs seq_len to be a "
+                    f"multiple of {FLASH_BLOCK} (the kernel's block), got "
+                    f"{seq_len}; use attn_impl='xla' for this length or "
+                    f"a sequence of {padded}"
+                )
         pp = self.pipeline_parallel
         m = self.microbatches
         if pp < 1:
@@ -699,7 +717,7 @@ def _local_loss_fn(config: SeqConfig, attn, tokens, targets, weights):
     Megatron f/g custom-vjp pair inside apply_lm owns every
     tensor-parallel reduction in both directions). No gradient ever
     rides a bare psum transpose, whose rule differs across JAX
-    generations (compat.py)."""
+    generations."""
     t_local = tokens.shape[1]
     pos = _shard_positions(config, t_local)
 
@@ -785,7 +803,8 @@ class SeqTrainer:
         # Pipeline topology rules first (pp | num_layers, microbatch
         # divisibility, the rejected compositions) — one unit-tested
         # gate on SeqConfig, shared with the CLI.
-        config.validate_topology()
+        config.validate_topology(seq_len=dataset.seq_len,
+                                 platform=jax.devices()[0].platform)
         if dataset.seq_len % max(W, 1):
             raise ValueError(
                 f"seq_len {dataset.seq_len} not divisible by {W} workers"
@@ -1389,15 +1408,12 @@ class SeqTrainer:
             params = self._place_params(tree["params"])
             opt_state = self._place_opt(tree["opt"])
         guarded(
-            lambda: force(
-                (xs, ys, ws, xte, yte, wte, params, opt_state),
-                all_leaves=True,
-            ),
+            lambda: force((xs, ys, ws, xte, yte, wte, params, opt_state)),
             dispatch_timeout, "train-set staging",
         )
         if gp is not None:
-            # The whole host->device upload: stage_batches' lazy puts
-            # materialize at the force barrier just closed.
+            # The whole host->device upload: stage_batches' async puts
+            # complete at the force barrier just closed.
             gp.add("staging", time.perf_counter() - t_stage0)
 
         spans = eval_spans(batch_num, cfg.eval_every)
@@ -1502,7 +1518,7 @@ class SeqTrainer:
             opt_state = self._place_opt(rtree["opt"])
             if inj is not None and inj.heal():
                 ws = _stage_ws()
-            force((ws, params, opt_state), all_leaves=True)
+            force((ws, params, opt_state))
             return rstep
 
         timer = StepTimer()
@@ -1538,8 +1554,8 @@ class SeqTrainer:
                             params, opt_state, l = out[0], out[1], out[2]
                             hstack = out[3] if health_on else None
                             skipped = out[-1] if guard_on else None
-                            # barrier: host fetch of the span loss (the whole
-                            # span chain executes to produce it)
+                            # The span's loss is needed on the host anyway;
+                            # its fetch closes the timing bracket.
                             loss = guarded(
                                 lambda: float(l), dispatch_timeout,
                                 f"span dispatch at global batch {gstep}",

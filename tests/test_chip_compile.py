@@ -1,0 +1,239 @@
+"""What can be known about the chip without the chip (ISSUE 21).
+
+- The main path's KERNELS compiled at real widths for a described (not
+  attached) TPU v5e: the fused Adam kernel at the full flat vector (not a
+  multiple of 128 — the pad path) and at the lane-aligned quarter shard,
+  flash attention forward + backward at the ``chip_smoke.py`` LM shape and
+  at T = 4096, and the config gate that keeps a sequence the kernel would
+  refuse from ever reaching it. One to two seconds each; skipped where the
+  TPU compiler cannot describe the topology. The persistent compile cache
+  is off for the whole suite (conftest) — a described-device compile can
+  be written to it but never read back without a chip. Whole-step compiles
+  take ten seconds and more each, so they are not tier-1: the CNN span
+  and the four-chip ZeRO-1 step are here under ``-m slow`` (they take a
+  mesh, so a described one can be handed to them), and every product
+  program at full width is compiled by ``chip_smoke.py`` on the chip.
+- The device gates around the chip: peak tables that know the v5e's
+  ``device_kind`` and refuse an unknown accelerator, the compile cache's
+  placement rule, and ``chip_smoke.py`` refusing to report from a CPU.
+"""
+
+import json
+import os
+import types
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+@pytest.fixture(scope="module")
+def v5e_host():
+    """The four devices of a described (not attached) v5e 2x2 host."""
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"cannot describe a v5e topology: {e}")
+    assert topo.devices[0].device_kind == "TPU v5 lite"
+    return topo.devices
+
+
+@pytest.fixture(scope="module")
+def v5e(v5e_host):
+    """Sharding on one chip of that host."""
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(v5e_host[0])
+
+
+def _compile_adam(chip, n):
+    from ddl_tpu.ops.pallas_adam import adam_flat_fused
+
+    vec = jax.ShapeDtypeStruct((n,), jnp.float32, sharding=chip)
+    lr = jax.ShapeDtypeStruct((), jnp.float32, sharding=chip)
+    return jax.jit(adam_flat_fused).lower(vec, vec, vec, vec, lr).compile()
+
+
+def _compile_flash(chip, batch, seq_len):
+    from ddl_tpu.ops.attention import flash_attention_bthd
+
+    def loss(q, k, v):
+        out = flash_attention_bthd(q, k, v, causal=True, platform="tpu")
+        return (out.astype(jnp.float32) ** 2).sum()
+
+    qkv = jax.ShapeDtypeStruct((batch, seq_len, 8, 64), jnp.bfloat16,
+                               sharding=chip)
+    return jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
+        qkv, qkv, qkv).compile()
+
+
+@pytest.mark.parametrize("case", [
+    "adam_full_vector", "adam_quarter_shard", "flash_lm_shape",
+    "flash_t4096", "flash_refused_below_block",
+])
+def test_kernels_compile_for_v5e(v5e, case):
+    if case == "flash_refused_below_block":
+        from ddl_tpu.strategies.seq import SeqConfig
+
+        cfg = SeqConfig(scheme="full", attn_impl="flash")
+        cfg.validate_topology(seq_len=1024, platform="tpu")
+        cfg.validate_topology(seq_len=64, platform="cpu")  # reference twin
+        for bad in (64, 192):  # under one block; not whole blocks
+            with pytest.raises(ValueError, match="multiple of 128"):
+                cfg.validate_topology(seq_len=bad, platform="tpu")
+        # ...and the kernel itself does refuse what the gate refuses.
+        with pytest.raises(ValueError, match="block_q"):
+            _compile_flash(v5e, 8, 64)
+        return
+    compiled = {
+        "adam_full_vector": lambda: _compile_adam(v5e, 2_656_010),
+        "adam_quarter_shard": lambda: _compile_adam(v5e, 664_064),
+        "flash_lm_shape": lambda: _compile_flash(v5e, 8, 1024),
+        "flash_t4096": lambda: _compile_flash(v5e, 2, 4096),
+    }[case]()
+    # The kernel is in the program, not a reference twin.
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def _shapes_on(tree, sharding):
+    return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=sharding), tree)
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("program", ["cnn_span_bf16", "zero1_flat_4chip"])
+def test_cnn_step_programs_compile_for_v5e(v5e_host, program, monkeypatch):
+    """The full-width CNN programs, compiled for the described host: the
+    single-chip span (``make_epoch_chunk``, bf16, batch 100) and the
+    four-chip ZeRO-1 flat span (``make_sync_epoch``). ``steps_scan`` asks
+    ``jax.default_backend()``, which is the CPU here, so the test steers
+    it to the rolled scan the chip runs."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from ddl_tpu.models import cnn
+    from ddl_tpu.obs.comms import collective_ops
+    from ddl_tpu.ops import adam_init
+    from ddl_tpu.parallel.mesh import DP_AXIS
+    from ddl_tpu.strategies.sync import (
+        ShardedAdam, make_sync_epoch, resolve_layout)
+    from ddl_tpu.train.config import TrainConfig
+    from ddl_tpu.train.trainer import make_epoch_chunk
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    params = jax.eval_shape(lambda: cnn.init_params(jax.random.PRNGKey(0)))
+    key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
+    i32 = jax.ShapeDtypeStruct((), jnp.int32)
+    k = 10
+    if program == "cnn_span_bf16":
+        chip = NamedSharding(Mesh(np.asarray(v5e_host[:1]), (DP_AXIS,)), P())
+        cfg = TrainConfig(batch_size=100, compute_dtype="bfloat16")
+        on = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=chip)
+        compiled = make_epoch_chunk(cfg, k).lower(
+            _shapes_on(params, chip),
+            _shapes_on(jax.eval_shape(adam_init, params), chip),
+            on((k, 100, 784), jnp.bfloat16), on((k, 100, 10), jnp.float32),
+            *_shapes_on((i32, i32, key), chip)).compile()
+        assert collective_ops(compiled.as_text()) == []
+        assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 30
+        return
+    W = 4
+    mesh = Mesh(np.asarray(v5e_host), (DP_AXIS,))
+    rep, split = NamedSharding(mesh, P()), NamedSharding(mesh, P(DP_AXIS))
+    shapes = cnn.param_shapes(params)
+    sizes = {n: int(np.prod(s)) if s else 1 for n, s in shapes.items()}
+    cfg = TrainConfig(batch_size=100 * W, num_workers=W, num_ps=W,
+                      layout="flat", keep_prob=1.0)
+    layout = resolve_layout(cfg, W, sizes)
+    flat = jax.ShapeDtypeStruct((W * layout.max_shard,), jnp.float32,
+                                sharding=split)
+    lowered = make_sync_epoch(cfg, mesh, layout, shapes, k).lower(
+        _shapes_on(params, rep),
+        ShardedAdam(step=_shapes_on(i32, rep), m=flat, v=flat),
+        jax.ShapeDtypeStruct((W, k, 100, 784), jnp.float32, sharding=split),
+        jax.ShapeDtypeStruct((W, k, 100, 10), jnp.float32, sharding=split),
+        *_shapes_on((i32, i32, key), rep))
+    ops = collective_ops(lowered.compile().as_text())
+    kinds = {op["op"] for op in ops}
+    # The program asks for a reduce-scatter; on this topology the TPU
+    # compiler answers with an all-reduce of the whole flat vector and a
+    # slice (PERF.md, PR 21). Either way the gradients are reduced once
+    # and the parameters gathered once.
+    assert "reduce_scatter" in lowered.as_text()
+    assert "all-gather" in kinds
+    assert "reduce-scatter" in kinds or any(
+        op["op"] == "all-reduce" and op["max_elems"] >= W * layout.max_shard
+        for op in ops)
+
+
+@pytest.mark.parametrize("table", ["flops", "ici"])
+def test_peak_tables_know_v5e_and_refuse_unknown_accelerators(table):
+    """A v5e reports device_kind "TPU v5 lite"; an accelerator in neither
+    table raises instead of borrowing the CPU nominal."""
+    from ddl_tpu.obs import comms, cost
+
+    lookup, want = {
+        "flops": (cost.peak_flops_per_device, 197e12),
+        "ici": (comms.ici_bw_per_device, 2.0e11),
+    }[table]
+    v5e_dev = types.SimpleNamespace(device_kind="TPU v5 lite",
+                                    platform="tpu")
+    assert lookup(v5e_dev) == want
+    unknown = types.SimpleNamespace(device_kind="TPU v9 mega",
+                                    platform="tpu")
+    with pytest.raises(cost.UnknownDeviceKind, match="TPU v9 mega".lower()):
+        lookup(unknown)
+    assert lookup(jax.devices()[0]) > 0  # the CPU nominal stays defined
+
+
+@pytest.mark.parametrize("env_set", [True, False])
+def test_compile_cache_placement(env_set, monkeypatch, tmp_path):
+    """JAX_COMPILATION_CACHE_DIR, when set, is left to JAX (no config
+    write); otherwise the directory is <checkout>/.jax_cache, the same
+    on every call."""
+    from ddl_tpu.utils import compile_cache
+
+    writes = {}
+    monkeypatch.setattr(jax.config, "update",
+                        lambda k, v: writes.__setitem__(k, v))
+    if env_set:
+        monkeypatch.setenv(compile_cache.ENV_VAR, str(tmp_path))
+        assert compile_cache.enable() == str(tmp_path)
+        assert "jax_compilation_cache_dir" not in writes
+    else:
+        monkeypatch.delenv(compile_cache.ENV_VAR, raising=False)
+        first, second = compile_cache.enable(), compile_cache.enable()
+        assert first == second == os.path.join(REPO, ".jax_cache")
+        assert writes["jax_compilation_cache_dir"] == first
+
+
+def test_chip_smoke_refuses_to_report_from_cpu(capsys, monkeypatch):
+    """The platform gate alone (phases stubbed out): on the CPU backend
+    chip_smoke.py exits non-zero and never prints the result line — with
+    or without --rehearse."""
+    import chip_smoke as smoke  # repo root is on sys.path, as for bench
+
+    ran = []
+    stub = (("stub", lambda sizes, seed: ran.append(sizes) or {}),)
+    monkeypatch.setattr(smoke, "ONE_CHIP", stub)
+    monkeypatch.setattr(smoke, "FOUR_CHIPS", stub)
+    from ddl_tpu.utils import compile_cache
+
+    monkeypatch.setattr(compile_cache, "enable", lambda: "unused")
+    # No process-wide JAX monitoring listeners left behind by a test.
+    monkeypatch.setattr(smoke, "CompileMeter", lambda: types.SimpleNamespace(
+        snapshot=lambda: (0.0, 0, 0)))
+
+    assert smoke.main([]) != 0 and not ran  # stops before any phase
+    assert smoke.main(["--rehearse"]) != 0 and ran == [smoke.TINY]
+    assert smoke.main(["--chips", "4", "--rehearse"]) != 0
+    out = capsys.readouterr().out
+    assert '"ok"' not in out
+    for line in out.splitlines():
+        assert json.loads(line)["phase"] in ("setup", "stub")

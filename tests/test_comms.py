@@ -28,7 +28,6 @@ The acceptance pins:
 from __future__ import annotations
 
 import types
-import warnings
 
 import jax
 import jax.numpy as jnp
@@ -38,6 +37,7 @@ import pytest
 from ddl_tpu.data.lm import synthesize_copy
 from ddl_tpu.models.transformer import TINY_SPEC
 from ddl_tpu.obs import MetricRegistry
+from ddl_tpu.obs.cost import UnknownDeviceKind
 from ddl_tpu.obs.comms import (
     CPU_NOMINAL_ICI_BW,
     ICI_BW_BY_KIND,
@@ -109,6 +109,30 @@ def test_parser_oracle_handwritten_hlo():
     assert sorted(cp["groups"]) == [[0, 1, 2], [4, 5]]
 
 
+# Lines as the TPU compiler wrote them for the 2x2 ring LM step (tiled
+# layouts, async start/done pairs), cut to the attributes the parser reads.
+_TPU_HLO = """\
+%collective-permute-start.1 = (f32[4,1024,8,64]{1,2,3,0:T(8,128)}, f32[4,1024,8,64]{1,2,3,0:T(8,128)}, u32[]{:S(2)}, u32[]{:S(2)}) collective-permute-start(%bitcast.87), channel_id=1, source_target_pairs={{0,1},{1,0},{2,3},{3,2}}
+%collective-permute-done.1 = f32[4,1024,8,64]{1,2,3,0:T(8,128)} collective-permute-done(%collective-permute-start.1)
+%all-gather-start.2 = (f32[1,804032]{1,0:T(1,128)}, f32[4,1,804032]{2,1,0:T(1,128)}) all-gather-start(%reshape.9), channel_id=3, replica_groups={{0,1,2,3}}, dimensions={0}
+%all-gather-done.2 = f32[4,1,804032]{2,1,0:T(1,128)} all-gather-done(%all-gather-start.2)
+%all-reduce.1 = (f32[4,804032]{1,0:T(4,128)}, f32[]{:T(128)}) all-reduce(%bitcast.13, %div.3), channel_id=2, replica_groups={{0,1,2,3}}, use_global_device_ids=true, to_apply=%region_172
+"""
+
+
+def test_parser_reads_tpu_async_collectives():
+    """On the chip a ppermute is a collective-permute-start/-done pair:
+    one op, the result's bytes (not operand + result + contexts), the
+    start line's pairs as its groups."""
+    cp, ag, ar = collective_ops(_TPU_HLO)
+    assert [o["op"] for o in (cp, ag, ar)] == [
+        "collective-permute", "all-gather", "all-reduce"]
+    assert cp["bytes"] == 4 * 1024 * 8 * 64 * 4
+    assert sorted(cp["groups"]) == [[0, 1], [2, 3]]
+    assert ag["bytes"] == 4 * 804032 * 4
+    assert ar["bytes"] == 4 * 804032 * 4 + 4 and ar["max_elems"] == 4 * 804032
+
+
 # -- mesh-axis attribution ----------------------------------------------------
 
 def test_mesh_axis_attribution():
@@ -173,15 +197,13 @@ def test_ici_bw_override_table_and_fallback():
     assert ici_bw_per_device(v4) == table["v4"]
     v5p = types.SimpleNamespace(device_kind="TPU v5p slice", platform="tpu")
     assert ici_bw_per_device(v5p) == table["v5p"]
-    # An unknown ACCELERATOR warns (once per kind) before anchoring to
-    # the CPU nominal — silent would read as hopelessly comms-bound.
+    # An unknown ACCELERATOR is an error, never the CPU nominal — that
+    # anchor would read as hopelessly comms-bound. The override wins.
     weird = types.SimpleNamespace(device_kind="frobnicator-9000",
                                   platform="gpu")
-    with pytest.warns(UserWarning, match="unknown accelerator"):
-        assert ici_bw_per_device(weird) == CPU_NOMINAL_ICI_BW
-    with warnings.catch_warnings():
-        warnings.simplefilter("error")
-        assert ici_bw_per_device(weird) == CPU_NOMINAL_ICI_BW
+    with pytest.raises(UnknownDeviceKind, match="ici-bw"):
+        ici_bw_per_device(weird)
+    assert ici_bw_per_device(weird, 7e10) == 7e10
 
 
 # -- the two-roofline model ---------------------------------------------------

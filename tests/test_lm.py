@@ -119,7 +119,7 @@ def test_sharded_loss_and_grads_match_oracle(scheme, workers):
     # local grads of [this shard's CE sum / psum'd weight total], ONE
     # explicit psum over the mesh axes. No gradient rides a bare
     # psum transpose, so the pattern is exact on every JAX generation
-    # (compat.py) — the value check still goes through _shard_sums'
+    # — the value check still goes through _shard_sums'
     # psum-normalized program.
     from ddl_tpu.strategies.seq import AXES, _attn_for, _local_loss_fn
     from jax import lax

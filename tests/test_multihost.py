@@ -21,25 +21,6 @@ from ddl_tpu.parallel import multihost
 from ddl_tpu.parallel.mesh import DP_AXIS, make_mesh
 
 
-# Whether this jaxlib's XLA:CPU client can RUN computations whose arrays
-# span OS processes: the 0.4 line raises "Multiprocess computations
-# aren't implemented on the CPU backend" on the first dispatch
-# (coordination/initialize works — only execution is missing);
-# cross-host CPU collectives (gloo) landed with the 0.5 jaxlib line —
-# the SAME version threshold mesh's collective-flags gate encodes, so
-# reuse it rather than fork the parse.
-from ddl_tpu.parallel.mesh import (  # noqa: E402
-    _cpu_collective_flags_supported as _cpu_multiprocess_supported,
-)
-
-requires_multiprocess_cpu = pytest.mark.skipif(
-    not _cpu_multiprocess_supported(),
-    reason="jaxlib's XLA:CPU predates multi-process computation support "
-           "(\"Multiprocess computations aren't implemented on the CPU "
-           "backend\") — two-OS-process worlds need the 0.5 jaxlib line",
-)
-
-
 def test_local_worker_rows_single_process_owns_all():
     mesh = make_mesh(8)
     np.testing.assert_array_equal(
@@ -114,8 +95,7 @@ class _FakeDev:
 def _fake_mesh(shape: dict, owner) -> object:
     """A stand-in Mesh for the pure staging math: ``owner(coords) ->
     process id`` assigns every device. Lets the multi-dim slab path be
-    pinned without a second OS process (the jaxlib here cannot RUN one
-    — see requires_multiprocess_cpu — but the extraction logic is pure)."""
+    pinned without a second OS process (the extraction logic is pure)."""
     import types
 
     dims = tuple(shape.values())
@@ -240,7 +220,6 @@ def _run_world(cmds: list[list[str]], timeout: float) -> list[str]:
     # Sharded Hogwild serve: the two all_to_all exchanges cross processes.
     ("async_sharding", ["--num-ps", "2"]),
 ])
-@requires_multiprocess_cpu
 def test_two_process_world_trains_end_to_end(variant, extra):
     """REAL multi-controller training — two OS processes (the analogue of
     the reference's mpiexec spanning nodes, mnist_sync/run.sh:3) join one
@@ -319,7 +298,6 @@ def test_multihost_worker_count_must_split_over_processes():
     # replicate_for_host + logical-order conversion of ZeRO-1 m/v.
     ("sync_sharding", ["--num-ps", "2", "--layout", "flat"]),
 ])
-@requires_multiprocess_cpu
 def test_preemption_agreement_across_processes(tmp_path, variant, extra):
     """SIGTERM delivered to ONE process of a two-process world: the
     preemption flag goes through multihost.agree_flag, so BOTH controllers
@@ -401,7 +379,6 @@ multihost.shutdown()
 """
 
 
-@requires_multiprocess_cpu
 def test_two_process_ring_attention():
     """Ring attention across a REAL two-process world: the ppermute ring
     crosses the OS-process boundary over gloo (the DCN analogue), and the
@@ -417,7 +394,6 @@ def test_two_process_ring_attention():
         assert "RING-WORLD-OK" in out
 
 
-@requires_multiprocess_cpu
 def test_two_process_lm_world_trains_end_to_end():
     """The lm variant across a REAL two-process world: each process owns
     one device of the 2-way sequence-parallel mesh, so every ring-attention
@@ -446,7 +422,6 @@ def test_two_process_lm_world_trains_end_to_end():
     assert payloads[0]["config"]["scheme"] == "ring"
 
 
-@requires_multiprocess_cpu
 def test_two_process_tp_world_trains_end_to_end():
     """Tensor parallelism across a REAL two-process world — the lifted
     single-controller restriction: a 1x2x2 [dp, sp, tp] mesh spans two
@@ -482,7 +457,6 @@ def test_two_process_tp_world_trains_end_to_end():
     assert payloads[0]["config"]["zero1"] is True
 
 
-@requires_multiprocess_cpu
 def test_two_process_lm_world_zigzag_matches_contiguous():
     """The balanced zigzag layout across a REAL two-process world: the
     travelling kpos crosses the OS-process boundary with its K/V block,

@@ -6,7 +6,7 @@ The obs layer exists so subsystems report through the tracer/registry
 writes that no tool can consume. This audit makes that rule MECHANICAL:
 any ``print(...)`` call in ``ddl_tpu/`` outside ``cli.py`` (the
 user-facing launcher, whose job IS stdout) fails the suite. Strings
-that merely contain the word (docstrings, subprocess probe source) are
+that merely contain the word (docstrings) are
 not calls and pass; ``log=print`` default arguments are Name
 references, not calls, and pass too. Pure AST — no imports, no
 execution; runs in milliseconds."""

@@ -297,23 +297,21 @@ def test_burn_rate_rejects_unknown_window():
         mon.burn_rate("r", "Fast")
 
 
-def test_peak_flops_warns_once_on_unknown_accelerator():
-    """An accelerator kind missing from the peak table warns (once per
-    kind) instead of silently anchoring MFU to the CPU nominal; CPU
-    devices stay silent."""
+def test_peak_flops_raises_on_unknown_accelerator():
+    """An accelerator kind missing from the peak table is an error (a
+    utilization anchored to the CPU nominal would read ~4000x high);
+    the override still wins, and CPU devices stay silent."""
     import warnings
 
     class Gpu:
         device_kind = "NVIDIA H100 80GB HBM3"
         platform = "gpu"
 
-    cost._warned_kinds.discard(Gpu.device_kind.lower())
-    with pytest.warns(UserWarning, match="peak-flops"):
-        assert cost.peak_flops_per_device(Gpu()) == \
-            cost.CPU_NOMINAL_PEAK_FLOPS
-    with warnings.catch_warnings():
-        warnings.simplefilter("error")  # second call: latched silent
+    with pytest.raises(cost.UnknownDeviceKind, match="peak-flops"):
         cost.peak_flops_per_device(Gpu())
+    assert cost.peak_flops_per_device(Gpu(), override=9.9e14) == 9.9e14
+    with warnings.catch_warnings():
+        warnings.simplefilter("error")
         cost.peak_flops_per_device(None)  # CPU path never warns
 
 
