@@ -7,11 +7,13 @@ treat step-time breakdowns and per-request traces as first class
 (arXiv:2204.06514 §5; the serving comparisons of arXiv:2605.25645 are
 built entirely on such telemetry). Three pieces, one package:
 
-- :mod:`ddl_tpu.obs.trace` — nestable host wall-clock spans + instant
-  events, emitted as JSONL and convertible to a Chrome/Perfetto
-  ``trace_event`` file; ``trace_context`` wraps the existing
-  ``jax.profiler`` trace so one ``--trace-dir`` run captures both the
-  host span timeline and the XLA device timeline.
+- :mod:`ddl_tpu.obs.trace` — one span primitive with two sinks: a
+  ``jax.profiler.TraceAnnotation`` (the span lands in the profiler's
+  xplane, on the device timeline's clock) and a tracer's ``complete``
+  (the JSONL :class:`Tracer`, convertible to a Chrome/Perfetto
+  ``trace_event`` file, or anything of its three-member protocol);
+  instant events go to the tracer alone. ``trace_context`` opens both
+  sinks for one ``--trace-dir`` run.
 - :mod:`ddl_tpu.obs.registry` — counters / gauges / histograms with
   label sets, a JSONL snapshot writer (manifest-first), and a
   Prometheus-text export. Replaces the ad-hoc per-subsystem stats

@@ -1,11 +1,29 @@
-"""Structured span tracing: nestable host wall-clock spans as JSONL.
+"""Structured span tracing: one span primitive, two sinks.
 
-One record per line, so a trace survives crashes mid-run (every
-completed span is already on disk) and concatenates across processes.
-Each record carries BOTH clocks — ``t``/``t0`` are ``time.perf_counter``
-(monotonic; all intra-run math uses these) and ``t_wall`` is
-``time.time`` (correlation across hosts/files) — plus ``pid`` and the
-JAX ``process_index`` so multi-process worlds merge cleanly.
+A :func:`span` opens a ``jax.profiler.TraceAnnotation`` — so under a
+profiler session it lands on the ``/host:CPU`` plane of the same
+``.xplane.pb`` as the device's ``XLA Modules`` / ``XLA Ops`` lines, on
+THEIR clock (which the profiler aligns with the host's to about a
+millisecond, anew each session), its attributes as the event's stats —
+and, where a truthy tracer is attached, also reports
+``tracer.complete(name, t0, t1, **attrs)`` at exit. ``Tracer.span`` is
+that same code, so ``train/span`` or ``serve.tick`` read the same in
+both sinks. With no profiler session a ``TraceAnnotation`` is a no-op
+(about a microsecond); with a falsy tracer no clock is read.
+
+The tracer PROTOCOL a span (and the serve scheduler, which stamps some
+events itself) relies on is three members: ``event(name, t=None,
+**attrs)``, ``complete(name, t0, t1, **attrs)`` and truthiness. Anything
+shaped like that may stand in for a :class:`Tracer` — the benchmark's
+recorder (``perf/serve_runner.py``) does.
+
+:class:`Tracer` is the JSONL sink: one record per line, so a trace
+survives crashes mid-run (every completed span is already on disk) and
+concatenates across processes. Each record carries BOTH host clocks —
+``t``/``t0`` are ``time.perf_counter`` (monotonic; all intra-run math
+uses these) and ``t_wall`` is ``time.time`` (correlation across
+hosts/files) — plus ``pid`` and the JAX ``process_index`` so
+multi-process worlds merge cleanly.
 
 Two record types::
 
@@ -14,21 +32,24 @@ Two record types::
      "t_wall": ..., "attrs": {...}}
     {"type": "event", "name": ..., "t": ..., "depth": ..., ...}
 
-Spans nest (``depth`` is the span's own nesting level; records are
-emitted at span END, so a child's record precedes its parent's — order
-by ``t0``/``seq`` to reconstruct the tree). ``event`` accepts an
-explicit ``t`` so callers can stamp an event with the exact
-``perf_counter`` value they used for their own derived metrics — the
-serve scheduler does this, which is what makes span-derived TTFT/ITL
-EXACTLY equal to ``ServeStats`` (tests/test_obs.py).
+``Tracer.span`` spans nest (``depth`` is the span's own nesting level;
+records are emitted at span END, so a child's record precedes its
+parent's — order by ``t0``/``seq`` to reconstruct the tree); the bare
+:func:`span` leaves ``depth`` alone and nests by containment of its
+brackets. ``event`` accepts an explicit ``t`` so callers can stamp an
+event with the exact ``perf_counter`` value they used for their own
+derived metrics — the serve scheduler does this, which is what makes
+span-derived TTFT/ITL EXACTLY equal to ``ServeStats``
+(tests/test_obs.py).
 
 ``chrome_trace_events`` converts records to the Chrome/Perfetto
 ``trace_event`` format; ``python -m ddl_tpu.obs.trace in.jsonl out.json``
 converts a file (open the result at https://ui.perfetto.dev or
-chrome://tracing). ``trace_context`` combines a host tracer with the
-existing ``jax.profiler`` trace (utils.metrics.trace), so a single
-``--trace-dir`` run captures the host span timeline AND the XLA device
-timeline side by side.
+chrome://tracing). ``trace_context`` opens both sinks for one
+``--trace-dir`` run: the profiler session whose xplane holds every span
+beside the device timeline, and the JSONL file that holds the spans
+opened with a tracer plus the instant events (which the profiler has no
+form for).
 
 ``NULL_TRACER`` is the disabled instance: same API, no records, and
 FALSY — call sites guard clock reads with ``if tracer:`` so a disabled
@@ -43,6 +64,8 @@ import json
 import os
 import time
 
+from jax.profiler import TraceAnnotation
+
 
 def _process_index() -> int:
     """JAX process index, 0 when no backend is reachable. Called lazily
@@ -55,6 +78,61 @@ def _process_index() -> int:
         return int(jax.process_index())
     except Exception:  # noqa: BLE001 — no backend is a fine answer
         return 0
+
+
+class span:
+    """The span primitive: ``with span(name, tracer, **attrs) as sp``.
+
+    Opens a ``TraceAnnotation(name, **attrs)`` for the bracket and, when
+    ``tracer`` is truthy, reads ``perf_counter`` at both ends and calls
+    ``tracer.complete(name, t0, t1, **attrs)`` at exit (also on an
+    exception, so the span is on disk). ``sp.t0`` is the opening read;
+    a caller that already reads the clock where the bracket closes (the
+    scheduler's ITL stamp) assigns it to ``sp.t1`` and no second read
+    is made. :meth:`set` adds attributes known only inside the bracket
+    to both sinks."""
+
+    __slots__ = ("name", "tracer", "attrs", "t0", "t1", "_ann")
+
+    def __init__(self, name: str, tracer=None, **attrs):
+        self.name = name
+        self.tracer = tracer
+        self.attrs = attrs
+        self.t0 = self.t1 = None
+
+    def __enter__(self) -> "span":
+        self._ann = TraceAnnotation(self.name, **self.attrs)
+        self._ann.__enter__()
+        if self.tracer:
+            self.t0 = time.perf_counter()
+        return self
+
+    def set(self, **attrs) -> None:
+        self.attrs.update(attrs)
+        self._ann.set_metadata(**attrs)
+
+    def __exit__(self, *exc) -> None:
+        tracer = self.tracer
+        if tracer and self.t1 is None:
+            self.t1 = time.perf_counter()
+        self._ann.__exit__(*exc)
+        if tracer:
+            tracer.complete(self.name, self.t0, self.t1, **self.attrs)
+
+
+class _nested_span(span):
+    """A :class:`Tracer`'s own span: what is recorded inside it carries
+    ``depth`` one deeper, its own record the depth it was opened at."""
+
+    __slots__ = ()
+
+    def __enter__(self) -> span:
+        self.tracer._depth += 1
+        return super().__enter__()
+
+    def __exit__(self, *exc) -> None:
+        self.tracer._depth -= 1
+        super().__exit__(*exc)
 
 
 class Tracer:
@@ -118,17 +196,10 @@ class Tracer:
             "dur_s": t1 - t0, "depth": self._depth, "attrs": attrs,
         })
 
-    @contextlib.contextmanager
-    def span(self, name: str, **attrs):
-        """Nestable wall-clock span; the record is emitted at exit (so
-        an exception inside still leaves the span on disk)."""
-        t0 = time.perf_counter()
-        self._depth += 1
-        try:
-            yield self
-        finally:
-            self._depth -= 1
-            self.complete(name, t0, time.perf_counter(), **attrs)
+    def span(self, name: str, **attrs) -> span:
+        """:func:`span` reporting here, and nesting: records emitted
+        inside carry ``depth`` one deeper."""
+        return _nested_span(name, self, **attrs)
 
     # -- lifecycle ---------------------------------------------------------
 
@@ -163,9 +234,8 @@ class NullTracer:
     def complete(self, name: str, t0: float, t1: float, **attrs) -> None:
         pass
 
-    @contextlib.contextmanager
-    def span(self, name: str, **attrs):
-        yield self
+    def span(self, name: str, **attrs) -> span:
+        return span(name, self, **attrs)  # the profiler's sink alone
 
     def flush(self) -> None:
         pass
@@ -188,22 +258,20 @@ def host_trace_file(trace_dir: str | os.PathLike) -> str:
 
 @contextlib.contextmanager
 def trace_context(trace_dir: str | os.PathLike | None):
-    """Host tracer + ``jax.profiler`` trace in one directory (None =
-    disabled: yields ``NULL_TRACER``, starts nothing). The host spans
-    land in ``host_trace_p<process_index>.jsonl`` next to the XLA
-    profile, so one ``--trace-dir`` run captures both timelines."""
+    """Both sinks of :func:`span` for one bracket, in one directory
+    (None = disabled: yields ``NULL_TRACER``, starts nothing): a
+    ``jax.profiler`` session, whose xplane holds the spans on the
+    device's clock, and the JSONL tracer it yields
+    (``host_trace_p<process_index>.jsonl``)."""
     if trace_dir is None:
         yield NULL_TRACER
         return
-    trace_dir = os.fspath(trace_dir)
-    tracer = Tracer(host_trace_file(trace_dir))
     from ..utils.metrics import trace as profiler_trace
 
-    try:
-        with profiler_trace(trace_dir):
-            yield tracer
-    finally:
-        tracer.close()
+    trace_dir = os.fspath(trace_dir)
+    with Tracer(host_trace_file(trace_dir)) as tracer, \
+            profiler_trace(trace_dir):
+        yield tracer
 
 
 # -- Chrome/Perfetto conversion ---------------------------------------------

@@ -88,6 +88,7 @@ from jax import lax
 from ..models import transformer
 from ..models.partition import lm_param_specs
 from ..models.transformer import LMSpec
+from ..obs.trace import span
 from ..ops.kv_cache import PAD_POS
 from ..parallel import collectives as coll
 from ..parallel import multihost
@@ -106,6 +107,15 @@ from .cache import (
     write_page,
 )
 from .prefix import PrefixIndex
+
+
+def _named(fn, name: str):
+    """``fn`` under ``name``: XLA calls a jitted function's program
+    ``jit_<name>``, which is all a device trace shows of it. Prefill
+    programs are ``run_prefill_b<bucket>`` (paged) / ``_c<bucket>``
+    (contiguous), decode ``run_decode_p<pages>`` / ``run_decode_c``."""
+    fn.__name__ = fn.__qualname__ = name
+    return fn
 
 
 class _LedgeredProgram:
@@ -749,7 +759,8 @@ class InferenceEngine:
 
         fn = self._ledgered(
             "prefill", bucket,
-            jax.jit(run, donate_argnums=donation_for(self.mesh, 1)),
+            jax.jit(_named(run, f"run_prefill_c{bucket}"),
+                    donate_argnums=donation_for(self.mesh, 1)),
         )
         self._prefill_fns[bucket] = fn
         self._note_compile("prefill", bucket)
@@ -789,7 +800,8 @@ class InferenceEngine:
 
         self._decode_fn = self._ledgered(
             "decode", 0,
-            jax.jit(run, donate_argnums=donation_for(self.mesh, 1)),
+            jax.jit(_named(run, "run_decode_c"),
+                    donate_argnums=donation_for(self.mesh, 1)),
         )
         self._note_compile("decode", 0)
         return self._decode_fn
@@ -871,7 +883,8 @@ class InferenceEngine:
 
         fn = self._ledgered(
             "prefill", bucket,
-            jax.jit(run, donate_argnums=donation_for(self.mesh, 1)),
+            jax.jit(_named(run, f"run_prefill_b{bucket}"),
+                    donate_argnums=donation_for(self.mesh, 1)),
         )
         self._prefill_fns[bucket] = fn
         self._note_compile("prefill", bucket)
@@ -917,7 +930,8 @@ class InferenceEngine:
 
         fn = self._ledgered(
             "decode", pages,
-            jax.jit(run, donate_argnums=donation_for(self.mesh, 1)),
+            jax.jit(_named(run, f"run_decode_p{pages}"),
+                    donate_argnums=donation_for(self.mesh, 1)),
         )
         self._decode_paged_fns[pages] = fn
         self._note_compile("decode", pages)
@@ -1300,23 +1314,20 @@ class InferenceEngine:
             )
         bucket = self.prefill_bucket(t) if _bucket is None else _bucket
         assert bucket >= t, (bucket, t)
-        tokens = np.zeros((1, bucket), np.int32)
-        tokens[0, :t] = prompt
-        if self.paged:
-            self._ensure_rows(slot, base + t)
-            nxt, logits, self.cache = self._prefill_paged_fn(bucket)(
-                self.params, self.cache, jnp.asarray(tokens),
-                jnp.int32(t), jnp.int32(base),
-                jnp.asarray(self.tables[slot:slot + 1]),
-                jnp.int32(request_id),
-            )
-            return int(nxt), np.asarray(logits)[:t]
-        nxt, logits, self.cache = self._prefill_fn(bucket)(
-            self.params, self.cache, jnp.asarray(tokens),
-            jnp.int32(t), jnp.int32(base), jnp.int32(slot),
-            jnp.int32(request_id),
-        )
-        return int(nxt), np.asarray(logits)[:t]
+        with span("engine.upload", kind="prefill"):
+            tokens = np.zeros((1, bucket), np.int32)
+            tokens[0, :t] = prompt
+            if self.paged:
+                self._ensure_rows(slot, base + t)
+                fn = self._prefill_paged_fn(bucket)
+                where = jnp.asarray(self.tables[slot:slot + 1])
+            else:
+                fn = self._prefill_fn(bucket)
+                where = jnp.int32(slot)
+            args = (jnp.asarray(tokens), jnp.int32(t), jnp.int32(base),
+                    where, jnp.int32(request_id))
+        nxt, logits = self._call("prefill", fn, args)
+        return int(nxt), logits[:t]
 
     def decode(self, last_tokens, lengths, request_ids, active, *,
                _pages: int | None = None):
@@ -1332,32 +1343,40 @@ class InferenceEngine:
         inactive, so its writes drop and its outputs are discarded.
         ``_pages`` forces a bucket (warmup's compile trigger, called
         with every slot inactive so no state moves)."""
-        if self.paged:
+        with span("engine.upload", kind="decode"):
             lengths_np = np.asarray(lengths, np.int32)
             active_np = np.asarray(active, bool)
-            if _pages is None:
-                widest = 1
-                for s in np.nonzero(active_np)[0]:
-                    self._ensure_rows(int(s), int(lengths_np[s]) + 1)
-                    widest = max(widest, int(self.table_len[s]))
-                pb = self.decode_page_bucket(widest)
+            tables = ()
+            if self.paged:
+                if _pages is None:
+                    widest = 1
+                    for s in np.nonzero(active_np)[0]:
+                        self._ensure_rows(int(s), int(lengths_np[s]) + 1)
+                        widest = max(widest, int(self.table_len[s]))
+                    pb = self.decode_page_bucket(widest)
+                else:
+                    pb = _pages
+                self.last_attend_width = pb * self.page_size
+                fn = self._decode_paged(pb)
+                tables = (self.tables[:, :pb],)
             else:
-                pb = _pages
-            self.last_attend_width = pb * self.page_size
-            nxt, logits, self.cache = self._decode_paged(pb)(
-                self.params, self.cache,
-                jnp.asarray(np.asarray(last_tokens, np.int32)),
-                jnp.asarray(lengths_np),
-                jnp.asarray(np.asarray(request_ids, np.int32)),
-                jnp.asarray(active_np),
-                jnp.asarray(self.tables[:, :pb]),
-            )
-            return np.asarray(nxt), np.asarray(logits)
-        nxt, logits, self.cache = self._decode()(
-            self.params, self.cache,
-            jnp.asarray(np.asarray(last_tokens, np.int32)),
-            jnp.asarray(np.asarray(lengths, np.int32)),
-            jnp.asarray(np.asarray(request_ids, np.int32)),
-            jnp.asarray(np.asarray(active, bool)),
-        )
-        return np.asarray(nxt), np.asarray(logits)
+                fn = self._decode()
+            args = tuple(jnp.asarray(a) for a in (
+                np.asarray(last_tokens, np.int32), lengths_np,
+                np.asarray(request_ids, np.int32), active_np, *tables))
+        return self._call("decode", fn, args)
+
+    def _call(self, kind: str, fn, args):
+        """Run one compiled program on the placed params and the cache
+        and bring back what it returns, ``(next np, logits np)``, as
+        three phases: the dispatch returns before the device ends, the
+        fetch of ``next`` blocks until it has, the fetch of the logits
+        is then a plain transfer. The ``engine.*`` spans have no tracer:
+        they reach the profiler alone and read no clock."""
+        with span("engine.dispatch", kind=kind):
+            nxt, logits, self.cache = fn(self.params, self.cache, *args)
+        with span("engine.wait", kind=kind):
+            nxt = np.asarray(nxt)
+        with span("engine.fetch_logits", kind=kind):
+            logits = np.asarray(logits)
+        return nxt, logits

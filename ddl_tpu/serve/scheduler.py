@@ -131,7 +131,7 @@ from ..obs import comms as _comms
 from ..obs import cost as _cost
 from ..obs.goodput import GoodputTracker
 from ..obs.memory import MemorySampler, record_compile
-from ..obs.trace import NULL_TRACER
+from ..obs.trace import NULL_TRACER, span
 from ..utils.metrics import StepStats, StepTimer
 from .engine import InferenceEngine
 from .speculate import greedy_accept, propose_draft
@@ -795,13 +795,14 @@ class Scheduler:
             )
         else:
             st.pending.append(r)
-        if self.tracer:
-            self.tracer.event(
-                "submit", t=time.perf_counter(), req=int(r.id),
-                prompt_len=int(np.asarray(r.prompt).shape[0]),
-                arrival=int(r.arrival),
-                max_new_tokens=int(r.max_new_tokens),
-            )
+        with span("serve.submit", self.tracer, req=int(r.id)) as sp:
+            if self.tracer:
+                self.tracer.event(
+                    "submit", t=sp.t0, req=int(r.id),
+                    prompt_len=int(np.asarray(r.prompt).shape[0]),
+                    arrival=int(r.arrival),
+                    max_new_tokens=int(r.max_new_tokens),
+                )
 
     def _require_run(self) -> _RunState:
         if self._st is None:
@@ -1228,9 +1229,11 @@ class Scheduler:
         # `reqs` attribute lists the REAL slots that decoded, exactly
         # as the plain path does (lanes are compute, not requests).
         reqs_now = [int(st.req_ids[i]) for i in range(S) if st.active[i]]
-        t0 = time.perf_counter()
-        nxt, _ = eng.decode(last, lengths, req_ids, active)
-        now = time.perf_counter()
+        with span("serve.decode", tr) as sp:
+            t0 = sp.t0 if tr else time.perf_counter()
+            nxt, _ = eng.decode(last, lengths, req_ids, active)
+            now = sp.t1 = time.perf_counter()
+            sp.set(pages=_attended_pages(eng))
         dt = now - t0
         # Lane teardown is pure decref (the source slot's own refs keep
         # every page live) — done before bookkeeping so no later raise
@@ -1324,7 +1327,14 @@ class Scheduler:
         loop body ``run`` iterates until idle. An idle tick (nothing
         eligible, nothing active) makes NO device calls, which is what
         lets an external driver insert clock-alignment ticks without
-        perturbing the device-call sequence."""
+        perturbing the device-call sequence.
+
+        The whole tick is one ``serve.tick`` span (obs.trace.span), each
+        device call inside it a ``serve.prefill`` / ``serve.decode``."""
+        with span("serve.tick", self.tracer):
+            self._tick()
+
+    def _tick(self) -> None:
         st = self._require_run()
         eng = self.engine
         cfg = eng.config
@@ -1537,16 +1547,20 @@ class Scheduler:
                 if budget0 and budget < n:
                     break  # out of tick budget; resume next tick
                 base = int(st.prefilled[s])
-                t0 = time.perf_counter() if tr else 0.0
-                with st.prefill_timer.step(images=n):
-                    tok, _ = eng.prefill(
-                        prompt[base:base + n], slot=s,
-                        request_id=r.id, base=base,
-                    )
-                if tr:
-                    tr.complete("prefill_chunk", t0,
-                                time.perf_counter(),
-                                req=int(r.id), slot=s, base=base, n=n)
+                bucket = eng.prefill_bucket(n)
+                with span("serve.prefill", tr, req=int(r.id), n=n,
+                          bucket=bucket) as sp:
+                    with st.prefill_timer.step(images=n):
+                        tok, _ = eng.prefill(
+                            prompt[base:base + n], slot=s,
+                            request_id=r.id, base=base,
+                        )
+                    if tr:
+                        # One bracket, two names: the span shares
+                        # prefill_chunk's clock reads.
+                        sp.t1 = time.perf_counter()
+                        tr.complete("prefill_chunk", sp.t0, sp.t1,
+                                    req=int(r.id), slot=s, base=base, n=n)
                 if gp is not None:
                     # The SAME bracket the StepTimer recorded — the
                     # attribution and the latency surface cannot
@@ -1566,7 +1580,7 @@ class Scheduler:
                     # says so.
                     reg.gauge("serve_prefill_flops_per_token").set(
                         _cost.serve_prefill_flops(
-                            cfg.spec, eng.prefill_bucket(n), cfg.capacity
+                            cfg.spec, bucket, cfg.capacity
                         ) / n
                     )
                 st.prefilled[s] += n
@@ -1611,11 +1625,13 @@ class Scheduler:
             decode_s, itl_s, mfu_val = self._speculate_decode(st, step)
         elif st.active.any():
             n_active = int(st.active.sum())
-            t0 = time.perf_counter() if tr else 0.0
-            with st.decode_timer.step(images=n_active):
-                nxt, _ = eng.decode(st.last_tokens, st.lengths,
-                                    st.req_ids, st.active)
-            now = time.perf_counter()
+            with span("serve.decode", tr) as sp:
+                with st.decode_timer.step(images=n_active):
+                    nxt, _ = eng.decode(st.last_tokens, st.lengths,
+                                        st.req_ids, st.active)
+                # The ITL clock's read closes the span's bracket too.
+                now = sp.t1 = time.perf_counter()
+                sp.set(pages=_attended_pages(eng))
             chained = st.last_decode_done is not None
             if chained:
                 # The gap since the previous decode completion —
@@ -1633,7 +1649,7 @@ class Scheduler:
                 # `reqs` lists the slots' request ids that decoded this
                 # tick — the per-request/per-class ITL derivation's
                 # input (ISSUE 8: derive_request_slo group_by).
-                tr.complete("decode_tick", t0, now, step=step,
+                tr.complete("decode_tick", sp.t0, now, step=step,
                             n_active=n_active, chained=chained,
                             reqs=[int(st.req_ids[i]) for i in range(S)
                                   if st.active[i]])
@@ -1756,6 +1772,12 @@ class Scheduler:
             # to it instead of spinning one Python iteration per
             # empty step (pending is (arrival, id)-sorted).
             st.step = max(st.step, st.pending[0].arrival)
+
+
+def _attended_pages(eng) -> int:
+    """The page-count bucket of the engine's last decode (0 on the
+    contiguous layout, which attends its whole capacity)."""
+    return eng.last_attend_width // eng.page_size if eng.paged else 0
 
 
 def request_slo_samples(records) -> dict[int, tuple[float, list[float]]]:
