@@ -3,12 +3,16 @@
 - The main path's KERNELS compiled at real widths for a described (not
   attached) TPU v5e: the fused Adam kernel at the full flat vector (not a
   multiple of 128 — the pad path) and at the lane-aligned quarter shard,
-  flash attention forward + backward at the ``chip_smoke.py`` LM shape and
-  at T = 4096, and the config gate that keeps a sequence the kernel would
-  refuse from ever reaching it. One to two seconds each; skipped where the
-  TPU compiler cannot describe the topology. The persistent compile cache
-  is off for the whole suite (conftest) — a described-device compile can
-  be written to it but never read back without a chip. Whole-step compiles
+  flash attention forward + backward at the ``chip_smoke.py`` LM shape,
+  at T = 4096, at the benchmark's training shape (8 rows of 2048, 16
+  heads of 64: the blocks ``flash_block_sizes`` picks there must fit VMEM),
+  at two lengths no wide block divides (384, 1152) and at heads of 512,
+  T = 8192, where the unhalved blocks are refused; and the config gate
+  that keeps a sequence the kernel would refuse from ever reaching it.
+  One to four seconds each; skipped where the TPU compiler cannot
+  describe the topology. The persistent compile cache is off for the
+  whole suite (conftest) — a described-device compile can be written to
+  it but never read back without a chip. Whole-step compiles
   take ten seconds and more each, so they are not tier-1: the CNN span
   and the four-chip ZeRO-1 step are here under ``-m slow`` (they take a
   mesh, so a described one can be handed to them), and every product
@@ -60,22 +64,23 @@ def _compile_adam(chip, n):
     return jax.jit(adam_flat_fused).lower(vec, vec, vec, vec, lr).compile()
 
 
-def _compile_flash(chip, batch, seq_len):
+def _compile_flash(chip, batch, seq_len, heads=8, head_dim=64):
     from ddl_tpu.ops.attention import flash_attention_bthd
 
     def loss(q, k, v):
         out = flash_attention_bthd(q, k, v, causal=True, platform="tpu")
         return (out.astype(jnp.float32) ** 2).sum()
 
-    qkv = jax.ShapeDtypeStruct((batch, seq_len, 8, 64), jnp.bfloat16,
-                               sharding=chip)
+    qkv = jax.ShapeDtypeStruct((batch, seq_len, heads, head_dim),
+                               jnp.bfloat16, sharding=chip)
     return jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
         qkv, qkv, qkv).compile()
 
 
 @pytest.mark.parametrize("case", [
     "adam_full_vector", "adam_quarter_shard", "flash_lm_shape",
-    "flash_t4096", "flash_refused_below_block",
+    "flash_t4096", "flash_refused_below_block", "flash_cell_shape",
+    "flash_t384", "flash_t1152", "flash_head_dim_512",
 ])
 def test_kernels_compile_for_v5e(v5e, case):
     if case == "flash_refused_below_block":
@@ -96,6 +101,11 @@ def test_kernels_compile_for_v5e(v5e, case):
         "adam_quarter_shard": lambda: _compile_adam(v5e, 664_064),
         "flash_lm_shape": lambda: _compile_flash(v5e, 8, 1024),
         "flash_t4096": lambda: _compile_flash(v5e, 2, 4096),
+        "flash_cell_shape": lambda: _compile_flash(v5e, 8, 2048, heads=16),
+        "flash_t384": lambda: _compile_flash(v5e, 2, 384),
+        "flash_t1152": lambda: _compile_flash(v5e, 2, 1152),
+        "flash_head_dim_512": lambda: _compile_flash(v5e, 1, 8192, heads=2,
+                                                     head_dim=512),
     }[case]()
     # The kernel is in the program, not a reference twin.
     assert "tpu_custom_call" in compiled.as_text()

@@ -804,6 +804,45 @@ def test_flash_attention_matches_oracle():
     )
 
 
+# What the chip sweep of PR 27 found best at the benchmark's training shape,
+# [8, 16, 2048, 64] bf16 causal on one v5e (PERF.md section 6).
+SWEPT_T2048_D64 = dict(
+    block_q=1024, block_k_major=1024, block_k=1024, block_b=1,
+    block_q_major_dkv=1024, block_k_major_dkv=1024, block_k_dkv=1024,
+    block_q_dkv=512, block_k_major_dq=512, block_k_dq=512, block_q_dq=1024)
+
+
+@pytest.mark.parametrize("head_dim", [64, 128, 256])
+@pytest.mark.parametrize("seq_len", [128, 256, 384, 1024, 2048, 4096, 8192])
+def test_flash_block_sizes_fit_the_shape(seq_len, head_dim):
+    """The pure selection: whatever (T, D), a ``BlockSizes`` the bundled
+    kernels accept (each block a whole number of 128s that divides T,
+    each minor block dividing its major), with the backward's blocks."""
+    import dataclasses
+
+    from ddl_tpu.ops.attention import FLASH_BLOCK, flash_block_sizes
+
+    bs = flash_block_sizes(seq_len, head_dim)  # __post_init__ checks pairs
+    assert bs.has_backward_blocks
+    got = dataclasses.asdict(bs)
+    assert got.pop("block_b") == 1  # the choice sees no batch to divide
+    for name, block in got.items():
+        assert block >= FLASH_BLOCK and block % FLASH_BLOCK == 0, name
+        assert seq_len % block == 0, (name, block)
+    for minor, major in (("block_k", "block_k_major"),
+                         ("block_k_dkv", "block_k_major_dkv"),
+                         ("block_q_dkv", "block_q_major_dkv"),
+                         ("block_k_dq", "block_k_major_dq")):
+        assert got[major] % got[minor] == 0, (minor, major)
+    if seq_len == 128:  # one block: the kernel's own default
+        assert set(got.values()) == {FLASH_BLOCK}
+    if (seq_len, head_dim) == (2048, 64):
+        assert dataclasses.asdict(bs) == SWEPT_T2048_D64
+    # Wider heads never get wider blocks (VMEM holds [block, D] tiles).
+    wide = dataclasses.asdict(flash_block_sizes(seq_len, 2 * head_dim))
+    assert all(wide[name] <= block for name, block in got.items())
+
+
 def test_seq_trainer_flash_matches_xla():
     """attn_impl='flash' (reference path on the CPU mesh) trains to the
     same result as the einsum kernel, for both schemes that support it;
