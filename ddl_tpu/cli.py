@@ -417,6 +417,12 @@ def build_parser() -> argparse.ArgumentParser:
                          "drop-in; a SMALLER pool is the point: "
                          "admission becomes 'enough free pages' "
                          "instead of worst-case rows per slot")
+    sv.add_argument("--model-spec", default=None, metavar="NAME",
+                    help="serve a named spec of the window/global "
+                         "routed-expert family (models.hybrid.NAMED_SPECS) "
+                         "in place of the dense decoder the --d-model/"
+                         "--heads/--layers flags describe; needs "
+                         "--page-size > 0 (two page groups)")
     sv.add_argument("--ttft-deadline", type=float, default=None,
                     metavar="SECONDS",
                     help="default per-request time-to-first-token "
@@ -801,7 +807,7 @@ _SERVE_ONLY_DESTS = (
     "prompt_max", "temperature", "top_k", "prefix_cache", "prefill_chunk",
     "prefill_budget", "ttft_deadline", "request_deadline", "shed_threshold",
     "replicas", "traffic", "slo", "slo_rules", "autoscale", "max_replicas",
-    "roles", "speculate",
+    "roles", "speculate", "model_spec",
 )
 _SIM_ONLY_DESTS = ("scenario", "fit")
 # Serve flags whose job the SCENARIO definition does on the sim variant
@@ -1567,7 +1573,7 @@ def _run_serve(args) -> int:
         )
     from .data.lm import synthesize_prompts
     from .models.transformer import LMSpec
-    from .serve import InferenceEngine, Request, Scheduler, ServeConfig
+    from .serve import Request, Scheduler, ServeConfig, engine_cls
     from .train.trainer import checkpoint_file
 
     if args.tensor_parallel < 1:
@@ -1578,6 +1584,18 @@ def _run_serve(args) -> int:
     spec = LMSpec(vocab=args.vocab, d_model=args.d_model,
                   num_heads=args.heads, num_layers=args.layers,
                   d_ff=args.d_ff)
+    if args.model_spec is not None:
+        from .models.hybrid import NAMED_SPECS
+
+        if args.model_spec not in NAMED_SPECS:
+            raise SystemExit(
+                f"--model-spec {args.model_spec!r}: known specs are "
+                f"{', '.join(sorted(NAMED_SPECS))}")
+        if args.replicas is not None:
+            raise SystemExit(
+                "--model-spec serves one replica: the router's replicas "
+                "are engines of the dense family")
+        spec = NAMED_SPECS[args.model_spec]
     spec_k, spec_method = 0, "ngram"
     if args.speculate is not None:
         try:
@@ -1684,8 +1702,9 @@ def _run_serve(args) -> int:
         if not os.path.exists(ckpt):
             raise SystemExit(f"no checkpoint at {ckpt}")
     try:
-        engine = (InferenceEngine.from_checkpoint(cfg, ckpt)
-                  if ckpt is not None else InferenceEngine(cfg))
+        Engine = engine_cls(spec)
+        engine = (Engine.from_checkpoint(cfg, ckpt)
+                  if ckpt is not None else Engine(cfg))
     except (ValueError, KeyError) as e:
         raise SystemExit(f"serve config error: {e}")
     if ckpt is not None:
@@ -1693,7 +1712,7 @@ def _run_serve(args) -> int:
     try:
         prompts = synthesize_prompts(
             num=args.num_prompts, min_len=args.prompt_min,
-            max_len=args.prompt_max, vocab=args.vocab, seed=args.seed,
+            max_len=args.prompt_max, vocab=spec.vocab, seed=args.seed,
         )
     except ValueError as e:
         raise SystemExit(f"serve config error: {e}")

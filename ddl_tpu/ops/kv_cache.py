@@ -213,3 +213,85 @@ def attend(
     s = jnp.where(mask, s, _MASKED)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bhqk,bkhd->bqhd", p.astype(v_cache.dtype), v_cache)
+
+
+def attend_grouped(
+    q: jax.Array,
+    k_view: jax.Array,
+    v_view: jax.Array,
+    q_pos: jax.Array,
+    k_pos: jax.Array,
+    *,
+    window: int | None = None,
+    sink: jax.Array | None = None,
+) -> jax.Array:
+    """Attention of fresh queries against a cache view whose K/V heads
+    are fewer than the query heads and whose K and V rows differ in
+    width: ``q [B, T, Hq, Dk]``, ``k_view [B, C, Hkv, Dk]``, ``v_view
+    [B, C, Hkv, Dv]`` -> ``[B, T, Hq, Dv]``. Query head ``h`` reads K/V
+    head ``h // (Hq / Hkv)``.
+
+    A key at ``k_pos[b, c]`` counts for the query at ``q_pos[b, t]``
+    when ``0 <= k_pos <= q_pos`` and, with ``window``, ``k_pos > q_pos -
+    window`` (the query's own position is one of the ``window``).
+    ``sink [Hq]`` adds one learned logit a query head to the softmax's
+    denominator only, so a row's weights sum to less than one. fp32
+    scores and softmax, the same mask constant as :func:`attend`."""
+    b, t, hq, dk = q.shape
+    hkv = k_view.shape[2]
+    qg = q.reshape(b, t, hkv, hq // hkv, dk)
+    s = jnp.einsum("bthgd,bchd->bhgtc", qg, k_view).astype(jnp.float32)
+    s = s / math.sqrt(dk)
+    kp, qp = k_pos[:, None, :], q_pos[:, :, None]             # [B, T, C]
+    mask = (kp >= 0) & (kp <= qp)
+    if window is not None:
+        mask &= kp > qp - window
+    s = jnp.where(mask[:, None, None], s, _MASKED)
+    m = jnp.max(s, axis=-1, keepdims=True)
+    if sink is not None:
+        sk = sink.astype(jnp.float32).reshape(1, hkv, hq // hkv, 1, 1)
+        m = jnp.maximum(m, sk)
+    e = jnp.exp(s - m)
+    den = jnp.sum(e, axis=-1, keepdims=True)
+    if sink is not None:
+        den = den + jnp.exp(sk - m)
+    p = (e / den).astype(v_view.dtype)
+    out = jnp.einsum("bhgtc,bchd->bthgd", p, v_view)
+    return out.reshape(b, t, hq, v_view.shape[-1])
+
+
+# -- window page group: a ring of table columns ------------------------------
+#
+# A slot's window-group table has R columns; logical page ``j`` (rows
+# ``j * page_size ..``) lives in column ``j % R``. R pages cover more
+# than a window plus a page, so the logical pages that intersect the
+# window never share a column, and the column's logical page follows
+# from the last position written: no position is stored with a row.
+
+
+def ring_rows(table: jax.Array, positions: jax.Array, page_size: int,
+              num_pages: int) -> jax.Array:
+    """Flat pool rows of ``positions [B, T]`` through the ring table
+    ``table [B, R]``; a negative position or an unmapped column maps out
+    of bounds, so the scatter drops the write."""
+    r = table.shape[1]
+    at = jnp.maximum(positions, 0)
+    pid = jnp.take_along_axis(table, (at // page_size) % r, axis=1)
+    ok = (positions >= 0) & (pid >= 0)
+    return jnp.where(ok, pid * page_size + at % page_size,
+                     num_pages * page_size)
+
+
+def ring_positions(last: jax.Array, columns: int,
+                   page_size: int) -> jax.Array:
+    """The position each row of the gathered ring view ``[B, R *
+    page_size]`` holds once ``last [B]`` is the last position written:
+    column ``c`` holds the newest logical page ``j <= last // page_size``
+    with ``j % R == c``. Rows of a page not yet reached come out
+    negative, rows ahead of ``last`` above it, rows of a page the window
+    has left at or under ``last - window``: the mask drops all three."""
+    lp = (last // page_size)[:, None]                         # [B, 1]
+    c = jnp.arange(columns, dtype=jnp.int32)[None, :]
+    j = lp - (lp - c) % columns                               # [B, R]
+    pos = j[..., None] * page_size + jnp.arange(page_size, dtype=jnp.int32)
+    return pos.reshape(last.shape[0], -1)

@@ -54,6 +54,7 @@ from .disagg import (  # noqa: F401
 )
 from .engine import InferenceEngine, ServeConfig  # noqa: F401
 from .engine_iface import ServeEngine, engine_kind  # noqa: F401
+from .hybrid_engine import HybridEngine, engine_cls  # noqa: F401
 from .prefix import PrefixIndex  # noqa: F401
 from .scenarios import (  # noqa: F401
     SCENARIOS,

@@ -428,3 +428,47 @@ class PagePool:
             heapq.heappush(self._free, page)
             return True
         return False
+
+
+# -- two page groups side by side (models.hybrid) -----------------------------
+
+
+@jax.tree_util.register_dataclass
+@dataclasses.dataclass
+class HybridKVCache:
+    """The pools of a family whose layers keep different things: a K
+    and a V pool a LAYER, ``[pages, page_size, kv_heads * width]`` (a
+    token's heads side by side in one row: whole 128-lane tiles at the
+    published widths, where a ``[.., 4, 192]`` tail made the compiler
+    copy the whole pool six times a decode tick to scatter into it),
+    whose page count and head count are its group's (global layers: every row
+    kept, ``num_pages`` pages; window layers: the last ``window`` rows,
+    ``num_window_pages`` pages) and whose K and V rows differ in width.
+    A leaf a layer, so a layer's write is in place on its own donated
+    buffer. No positions are stored: ``ops.kv_cache.ring_positions`` and
+    a global row's logical index give them."""
+
+    k: tuple
+    v: tuple
+
+
+def hybrid_cache(spec, num_pages: int, num_window_pages: int,
+                 page_size: int, dtype) -> HybridKVCache:
+    """Fresh zero pools on the default device."""
+    from ..models.hybrid import WINDOW
+
+    def pool(layer: int, width: int):
+        pages = (num_window_pages if spec.layer_kinds[layer] == WINDOW
+                 else num_pages)
+        return jnp.zeros((pages, page_size, spec.kv_heads(layer) * width),
+                         dtype)
+
+    layers = range(spec.num_layers)
+    return HybridKVCache(k=tuple(pool(i, spec.head_dim) for i in layers),
+                         v=tuple(pool(i, spec.v_head_dim) for i in layers))
+
+
+def ring_columns(window: int, page_size: int) -> int:
+    """Columns of a slot's window-group table: the most logical pages
+    the last ``window`` rows can touch."""
+    return (window - 2) // page_size + 2

@@ -87,6 +87,7 @@ from jax import lax
 
 from ..models import transformer
 from ..models.partition import lm_param_specs
+from ..models.hybrid import HybridSpec
 from ..models.transformer import LMSpec
 from ..obs.trace import span
 from ..ops.kv_cache import PAD_POS
@@ -206,7 +207,7 @@ class ServeConfig:
     keeps the fp32/bf16 pool — the compiled programs are byte-identical
     to pre-int8 builds (HLO-pinned in tests/test_precision.py)."""
 
-    spec: LMSpec = LMSpec()
+    spec: LMSpec | HybridSpec = LMSpec()
     slots: int = 4
     capacity: int = 256
     tensor_parallel: int = 1
@@ -236,15 +237,13 @@ class ServeConfig:
         return None if self.compute_dtype is None else jnp.dtype(self.compute_dtype)
 
 
-def _load_host_params(path, spec: LMSpec):
+def _load_host_params(path, spec, init=transformer.init_lm_params):
     """Params-only host tree from any trainer checkpoint: the template
     is shapes-only (``jax.eval_shape`` — no arrays are initialized just
     to be overwritten)."""
     from ..utils.checkpoint import load_params
 
-    template = jax.eval_shape(
-        lambda: transformer.init_lm_params(jax.random.PRNGKey(0), spec)
-    )
+    template = jax.eval_shape(lambda: init(jax.random.PRNGKey(0), spec))
     host, _, _ = load_params(path, template)
     return host
 
@@ -265,6 +264,19 @@ class InferenceEngine:
     ``kind == "sim"``) is the other."""
 
     kind = "real"
+    handoff = True  # a slot's pages can be dumped, loaded and aliased
+    # What the last program counted beyond its tokens, as attributes for
+    # the scheduler's span around the call; the dense family counts
+    # nothing.
+    last_counters: dict = {}
+
+    # What a family's engine brings (``serve.hybrid_engine`` is the
+    # second; ``serve.engine_cls(spec)`` picks the class): its spec
+    # type, a fresh host tree, its layout (``_layout``) and how a host
+    # tree becomes the resident one (``_place``), beside its pools
+    # (``reset``) and programs.
+    spec_type = LMSpec
+    _init_params = staticmethod(transformer.init_lm_params)
 
     def __init__(self, config: ServeConfig, params=None, *,
                  placed_params=None):
@@ -275,6 +287,12 @@ class InferenceEngine:
             )
         tp = config.tensor_parallel
         spec = config.spec
+        if not isinstance(spec, self.spec_type):
+            raise ValueError(
+                f"{type(self).__name__} serves a {self.spec_type.__name__}, "
+                f"got a {type(spec).__name__}: serve.engine_cls(spec) is "
+                "the class of the spec's family"
+            )
         if tp < 1:
             raise ValueError(f"tensor_parallel must be >= 1, got {tp}")
         if tp > 1:
@@ -410,17 +428,15 @@ class InferenceEngine:
         # A 1-D tp mesh: serving has no data/sequence axis — the batch
         # dim is the slot dim, resident whole on every tp member.
         self.mesh = make_mesh(tp, axis=TP_AXIS)
-        self._pspecs = lm_param_specs(spec, tp)
-        self._cspecs = cache_specs(tp)
+        self._layout()
         if placed_params is not None:
             self.params = placed_params
         else:
             if params is None:
-                params = transformer.init_lm_params(
+                params = self._init_params(
                     jax.random.PRNGKey(config.seed), spec
                 )
-            self.params = multihost.put_tree(self.mesh, self._pspecs,
-                                             params)
+            self.params = self._place(params)
         self._row_reduce = coll.tp_allreduce(TP_AXIS) if tp > 1 else None
         # Compile-activity hook (ISSUE 10, obs/memory.py): called as
         # ``hook(kind, key)`` at every DISTINCT program build — each
@@ -450,12 +466,20 @@ class InferenceEngine:
         self._copy_page_fn = None  # paged CoW: partial tail page
         self._write_page_fn = None  # paged: cross-replica page hand-off
         self._reset_pages_fn = None  # paged: PAD_POS freed pages' pos
-        if self.paged:
-            self._pcspecs = paged_cache_specs(tp,
-                                              kv_dtype=config.kv_dtype)
         self.pool: KVCache | None = None
         self.prefix: PrefixIndex | None = None
         self.reset()
+
+    def _layout(self) -> None:
+        """What the family lays out from the checked config, before
+        params are placed and the pools made: here the partition specs
+        of the params and of both cache layouts."""
+        tp = self.config.tensor_parallel
+        self._pspecs = lm_param_specs(self.config.spec, tp)
+        self._cspecs = cache_specs(tp)
+        if self.paged:
+            self._pcspecs = paged_cache_specs(
+                tp, kv_dtype=self.config.kv_dtype)
 
     @classmethod
     def from_checkpoint(cls, config: ServeConfig, path) -> "InferenceEngine":
@@ -463,7 +487,8 @@ class InferenceEngine:
         throwaway random init is ever placed (the constructor receives
         the loaded host tree). Same params-only contract as
         :meth:`load_params`."""
-        return cls(config, params=_load_host_params(path, config.spec))
+        return cls(config, params=_load_host_params(
+            path, config.spec, cls._init_params))
 
     def _note_compile(self, kind: str, key: int) -> None:
         """One distinct program was just built (engine.__init__
@@ -546,6 +571,10 @@ class InferenceEngine:
         slot (``release_slot``)."""
         self.pages.reserve(n)
         self.reserved_for[slot] += n
+
+    def can_admit(self, need: int) -> bool:
+        """Whether ``need`` pages can be reserved for a new slot now."""
+        return self.pages.available >= need
 
     def reclaim_pages(self, need: int) -> bool:
         """Evict zero-ref prefix entries (LRU-first) until ``need``
@@ -647,10 +676,12 @@ class InferenceEngine:
         """Params-only checkpoint load (``utils.checkpoint.load_params``):
         accepts a trainer checkpoint from ANY topology — optimizer/step
         state is ignored if present and not required to exist."""
-        self.params = multihost.put_tree(
-            self.mesh, self._pspecs,
-            _load_host_params(path, self.config.spec),
-        )
+        self.params = self._place(_load_host_params(
+            path, self.config.spec, self._init_params))
+
+    def _place(self, params):
+        """A host tree onto the mesh, as the programs take it."""
+        return multihost.put_tree(self.mesh, self._pspecs, params)
 
     # -- sampling ----------------------------------------------------------
 

@@ -53,6 +53,9 @@ class ServeEngine(Protocol):
       ``hook(kind, key)`` once per distinct program build.
     * ``last_attend_width`` — rows the last decode attended (the
       paged-aware ``serve_flops_per_token`` denominator).
+    * ``last_counters`` — what the last program counted beyond its
+      tokens (``{}`` for the dense family and the twin), set as
+      attributes on the scheduler's ``serve.prefill`` / ``serve.decode``.
     """
 
     kind: str
@@ -73,6 +76,8 @@ class ServeEngine(Protocol):
     def pages_needed(self, rows: int) -> int: ...
 
     def reserve_pages(self, slot: int, n: int) -> None: ...
+
+    def can_admit(self, need: int) -> bool: ...
 
     def reclaim_pages(self, need: int) -> bool: ...
 

@@ -1,0 +1,316 @@
+"""The engine of the ``models.hybrid`` family: two page groups, one
+block table a group a slot, the same host API.
+
+``serve.engine_cls(spec)`` is this class for a ``HybridSpec``; the
+constructor's checks, the host state, scheduler, buckets, warm-up,
+dispatch and the ``engine.*`` spans are the base class's. What differs
+is what a spec of this family stands for: pool shapes
+(:func:`serve.cache.hybrid_cache`), a prefill program and a decode
+program over ``models.hybrid.apply_hybrid_paged``, and the host half of
+the window group.
+
+- **Global group**: the base class's ``pages`` / ``tables`` /
+  ``table_len`` / ``reserved_for``, every row kept, decode programs
+  bucketed on its page count.
+- **Window group**: ``win_pages`` (a second ``PagePool``), ``win_tables
+  [slots, R]`` a ring of columns (``ops.kv_cache.ring_rows``), logical
+  page ``j`` in column ``j % R``. Before a row is written the pages whose
+  last row the window has left are freed and the row's own page is
+  mapped; a prefill block keeps only its last ``window`` rows beyond the
+  call. The pool holds ``R`` pages a slot; admission reserves a slot's
+  ``R``, and a freed page returns to its slot's reservation.
+
+Programs keep the names ``jit_run_prefill_b<bucket>`` and
+``jit_run_decode_p<pages>`` (the global group's bucket; the window
+group's width is fixed). A prefill returns the last position's logits
+alone. Each program returns, behind the sampled tokens in the one small
+array the host waits for, the routed layers' ``(assigned, touched)``;
+:attr:`last_counters` holds them for the scheduler's spans.
+
+Weights are kept as handed over, cast once to ``compute_dtype`` when
+the engine is built, never inside a call.
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax import lax
+
+from ..models import hybrid
+from ..obs.trace import span
+from ..parallel.mesh import donation_for
+from .cache import HybridKVCache, PagePool, hybrid_cache, ring_columns
+from .engine import InferenceEngine, ServeConfig, _named
+
+# What the family does not serve yet, by the config field that asks for
+# it: each is refused by name when the engine is built.
+UNSUPPORTED = (
+    ("prefix cache", lambda c: c.prefix_slots > 0, "prefix_slots > 0"),
+    ("speculation", lambda c: c.speculate_k > 0, "speculate_k > 0"),
+    ("int8 pool", lambda c: c.kv_dtype is not None, "kv_dtype"),
+    ("tensor parallelism", lambda c: c.tensor_parallel != 1,
+     "tensor_parallel > 1"),
+    ("contiguous cache", lambda c: c.page_size <= 0, "page_size = 0"),
+)
+HANDOFF = ("the hybrid family does not support the disaggregated hand-off "
+           "(dump/load/alias of a slot's pages, preemption, prefill and "
+           "decode roles): its two page groups have no serialised form yet")
+
+
+class HybridEngine(InferenceEngine):
+    """See the module docstring."""
+
+    handoff = False
+    spec_type = hybrid.HybridSpec
+    _init_params = staticmethod(hybrid.init_hybrid_params)
+
+    def __init__(self, config: ServeConfig, params=None, *,
+                 placed_params=None):
+        for feature, asked, field in UNSUPPORTED:
+            if asked(config):
+                raise ValueError(
+                    f"the hybrid family does not support the {feature} "
+                    f"({field}) yet; it serves paged, tp 1, full-precision "
+                    "pools, no prefix cache, no speculation")
+        super().__init__(config, params, placed_params=placed_params)
+
+    def _layout(self) -> None:
+        cfg = self.config
+        window = cfg.spec.window
+        if cfg.capacity % window:
+            raise ValueError(
+                f"capacity ({cfg.capacity}) must be a multiple of the "
+                f"window ({window}): a prefill block is cut into bands")
+        # The window group's table width; its pool is one ring a slot,
+        # all a slot can hold (admission reserves a ring, and a page the
+        # window leaves goes back to its slot's reservation).
+        self.ring = ring_columns(window, self.page_size)
+        self.num_window_pages = cfg.slots * self.ring
+
+    # -- state -------------------------------------------------------------
+
+    def reset(self) -> None:
+        cfg = self.config
+        self.cache = hybrid_cache(
+            cfg.spec, self.num_pages, self.num_window_pages, self.page_size,
+            np.dtype(cfg.compute_dtype or np.float32))
+        self.pages = PagePool(self.num_pages)
+        self.tables = np.full((cfg.slots, self.max_pages), -1, np.int32)
+        self.table_len = np.zeros(cfg.slots, np.int64)
+        self.reserved_for = np.zeros(cfg.slots, np.int64)
+        self.page_copies = 0
+        self.win_pages = PagePool(self.num_window_pages)
+        self.win_tables = np.full((cfg.slots, self.ring), -1, np.int32)
+        # The logical page a column holds (-1: none).
+        self.win_logical = np.full((cfg.slots, self.ring), -1, np.int64)
+        self.win_reserved = np.zeros(cfg.slots, np.int64)
+        self.win_held = np.zeros(cfg.slots, bool)  # admitted by reservation
+        self.last_counters = {}
+
+    # -- page groups (host half) -------------------------------------------
+
+    def can_admit(self, need: int) -> bool:
+        """Both groups have what a new slot reserves."""
+        return self.pages.available >= need \
+            and self.win_pages.available >= self.ring
+
+    def reclaim_pages(self, need: int) -> bool:
+        return False  # no prefix entries to evict
+
+    def reserve_pages(self, slot: int, n: int) -> None:
+        super().reserve_pages(slot, n)
+        self.win_pages.reserve(self.ring)
+        self.win_reserved[slot] += self.ring
+        self.win_held[slot] = True
+
+    def _release_pages(self, pages) -> None:
+        for p in pages:  # no stored positions to reset
+            self.pages.decref(int(p))
+
+    def release_slot(self, slot: int) -> None:
+        super().release_slot(slot)
+        for c in np.nonzero(self.win_tables[slot] >= 0)[0]:
+            self._free_window(slot, int(c), keep=False)
+        self.win_pages.unreserve(int(self.win_reserved[slot]))
+        self.win_reserved[slot] = 0
+        self.win_held[slot] = False
+
+    def _free_window(self, slot: int, col: int, *, keep: bool) -> None:
+        """Return a column's page to the pool; for a slot that goes on
+        (``keep``) under an admission's reservation, to that too."""
+        self.win_pages.decref(int(self.win_tables[slot, col]))
+        self.win_tables[slot, col] = self.win_logical[slot, col] = -1
+        if keep and self.win_held[slot]:
+            self.win_pages.reserve(1)
+            self.win_reserved[slot] += 1
+
+    def _slide_window(self, slot: int, lo: int, hi: int) -> None:
+        """Before a call writes rows ``lo..hi``: its queries see the rows
+        above ``lo - window``, so every page whose last row lies at or
+        under that is freed; then the pages of the rows kept beyond the
+        call, the last ``window`` of them, are mapped."""
+        ps, w = self.page_size, self.config.spec.window
+        for c in np.nonzero(self.win_tables[slot] >= 0)[0]:
+            if (int(self.win_logical[slot, c]) + 1) * ps - 1 <= lo - w:
+                self._free_window(slot, int(c), keep=True)
+        for j in range(max(lo, hi - w + 1) // ps, hi // ps + 1):
+            c = j % self.ring
+            if self.win_tables[slot, c] < 0:
+                if self.win_reserved[slot] > 0:
+                    self.win_reserved[slot] -= 1
+                    self.win_pages.unreserve(1)
+                elif self.win_pages.available < 1:
+                    raise RuntimeError(
+                        f"slot {slot}: window page group exhausted (free "
+                        f"{self.win_pages.free}, reserved "
+                        f"{self.win_pages.reserved})")
+                self.win_tables[slot, c] = self.win_pages.alloc()
+            self.win_logical[slot, c] = j
+
+    @property
+    def window_pages_in_use(self) -> int:
+        return self.num_window_pages - self.win_pages.free
+
+    def _no_handoff(self, *args, **kwargs):
+        raise NotImplementedError(HANDOFF)
+
+    dump_slot_pages = load_slot_pages = alias_slot_pages = _no_handoff
+
+    def _place(self, params):
+        """The tree as handed over, cast once to the compute dtype."""
+        dtype = self.config.dtype()
+        return jax.tree.map(lambda a: jnp.asarray(a, dtype or a.dtype), params)
+
+    # -- compiled programs -------------------------------------------------
+
+    def _forward(self, params, cache: HybridKVCache, tokens, **kw):
+        spec = self.config.spec
+        pools = dict(enumerate(zip(cache.k, cache.v)))
+        h, pools, counts = hybrid.apply_hybrid_paged(
+            params, pools, tokens, spec, page_size=self.page_size,
+            compute_dtype=self.config.dtype(), **kw)
+        layers = range(spec.num_layers)
+        return h, counts, HybridKVCache(
+            k=tuple(pools[i][0] for i in layers),
+            v=tuple(pools[i][1] for i in layers))
+
+    def _prefill_paged_fn(self, bucket: int):
+        """``(params, cache, tokens [1, bucket], length, base, g_table [1,
+        max_pages], w_table [1, R], request_id) -> ([next, assigned,
+        touched], logits [1, vocab] of the last real position, cache)``."""
+        if bucket in self._prefill_fns:
+            return self._prefill_fns[bucket]
+
+        def run(params, cache, tokens, length, base, g_table, w_table,
+                request_id):
+            t = jnp.arange(bucket, dtype=jnp.int32)
+            real = (t < length)[None, :]
+            positions = jnp.where(real, base + t, -1)
+            h, counts, cache = self._forward(
+                params, cache, tokens, g_table=g_table, w_table=w_table,
+                positions=positions, real=real,
+                last=(base + length - 1)[None], base=base)
+            last = lax.dynamic_slice_in_dim(h[0], length - 1, 1, axis=0)
+            logits = hybrid.head_logits(params, last)
+            nxt = self._sample(logits[0], request_id, base + length)
+            return jnp.concatenate([nxt[None], counts]), logits, cache
+
+        fn = self._ledgered(
+            "prefill", bucket,
+            jax.jit(_named(run, f"run_prefill_b{bucket}"),
+                    donate_argnums=donation_for(self.mesh, 1)))
+        self._prefill_fns[bucket] = fn
+        self._note_compile("prefill", bucket)
+        return fn
+
+    def _decode_paged(self, pages: int):
+        """``(params, cache, last_tokens [S], lengths [S], request_ids [S],
+        active [S], g_table [S, pages], w_table [S, R]) -> ([next [S],
+        assigned, touched], logits [S, vocab], cache)``. A slot that is
+        not active writes nothing and counts nothing."""
+        if pages in self._decode_paged_fns:
+            return self._decode_paged_fns[pages]
+
+        def run(params, cache, last_tokens, lengths, request_ids, active,
+                g_table, w_table):
+            positions = jnp.where(active, lengths, -1)
+            h, counts, cache = self._forward(
+                params, cache, last_tokens[:, None], g_table=g_table,
+                w_table=w_table, positions=positions[:, None],
+                real=active[:, None], last=positions)
+            logits = hybrid.head_logits(params, h[:, 0])
+            nxt = jax.vmap(self._sample)(logits, request_ids, lengths + 1)
+            return jnp.concatenate([nxt, counts]), logits, cache
+
+        fn = self._ledgered(
+            "decode", pages,
+            jax.jit(_named(run, f"run_decode_p{pages}"),
+                    donate_argnums=donation_for(self.mesh, 1)))
+        self._decode_paged_fns[pages] = fn
+        self._note_compile("decode", pages)
+        return fn
+
+    # -- host API ----------------------------------------------------------
+
+    def prefill(self, prompt, *, slot: int, request_id: int, base: int = 0,
+                _bucket: int | None = None):
+        """The base class's contract, except the logits: ``(next_token,
+        logits np [1, vocab])`` of the block's last position."""
+        prompt = np.asarray(prompt, np.int32)
+        t = int(prompt.shape[0])
+        if base < 0 or base + t > self.config.capacity:
+            raise ValueError(
+                f"prefill block [base={base}, base+{t}) outside cache "
+                f"capacity {self.config.capacity}")
+        bucket = self.prefill_bucket(t) if _bucket is None else _bucket
+        assert bucket >= t, (bucket, t)
+        with span("engine.upload", kind="prefill"):
+            tokens = np.zeros((1, bucket), np.int32)
+            tokens[0, :t] = prompt
+            self._ensure_rows(slot, base + t)
+            self._slide_window(slot, base, base + t - 1)
+            fn = self._prefill_paged_fn(bucket)
+            args = (jnp.asarray(tokens), jnp.int32(t), jnp.int32(base),
+                    jnp.asarray(self.tables[slot:slot + 1]),
+                    jnp.asarray(self.win_tables[slot:slot + 1]),
+                    jnp.int32(request_id))
+        out, logits = self._call("prefill", fn, args)
+        self.last_counters = {"moe_assigned": int(out[1])}
+        return int(out[0]), logits
+
+    def decode(self, last_tokens, lengths, request_ids, active, *,
+               _pages: int | None = None):
+        """The base class's contract. Each active slot's window slides
+        first: the pages it has left are freed, the new row's mapped."""
+        with span("engine.upload", kind="decode"):
+            lengths_np = np.asarray(lengths, np.int32)
+            active_np = np.asarray(active, bool)
+            if _pages is None:
+                widest = 1
+                for s in np.nonzero(active_np)[0]:
+                    at = int(lengths_np[s])
+                    self._ensure_rows(int(s), at + 1)
+                    self._slide_window(int(s), at, at)
+                    widest = max(widest, int(self.table_len[s]))
+                pb = self.decode_page_bucket(widest)
+            else:
+                pb = _pages
+            self.last_attend_width = pb * self.page_size
+            fn = self._decode_paged(pb)
+            args = tuple(jnp.asarray(a) for a in (
+                np.asarray(last_tokens, np.int32), lengths_np,
+                np.asarray(request_ids, np.int32), active_np,
+                self.tables[:, :pb], self.win_tables))
+        out, logits = self._call("decode", fn, args)
+        self.last_counters = {"moe_assigned": int(out[-2]),
+                              "moe_touched": int(out[-1]),
+                              "win_pages": self.window_pages_in_use}
+        return out[:-2], logits
+
+
+def engine_cls(spec) -> type[InferenceEngine]:
+    """The engine class that serves ``spec``'s family."""
+    return HybridEngine if isinstance(spec, hybrid.HybridSpec) \
+        else InferenceEngine

@@ -364,6 +364,11 @@ class Scheduler:
                 f"role must be 'mixed', 'prefill' or 'decode', got "
                 f"{role!r}"
             )
+        if role != "mixed" and not engine.handoff:
+            raise ValueError(
+                f"role={role!r} needs an engine whose pages can be handed "
+                "off; this family's two page groups cannot yet"
+            )
         if role == "prefill" and not engine.paged:
             raise ValueError(
                 "role='prefill' needs the paged KV layout (page_size > "
@@ -1458,7 +1463,7 @@ class Scheduler:
                 while True:
                     need = eng.pages_needed(p + r.max_new_tokens) \
                         - hit // eng.page_size
-                    if eng.pages.available >= need:
+                    if eng.can_admit(need):
                         break
                     if not eng.reclaim_pages(need):
                         need = -1
@@ -1555,6 +1560,8 @@ class Scheduler:
                             prompt[base:base + n], slot=s,
                             request_id=r.id, base=base,
                         )
+                    if eng.last_counters:
+                        sp.set(**eng.last_counters)
                     if tr:
                         # One bracket, two names: the span shares
                         # prefill_chunk's clock reads.
@@ -1631,7 +1638,7 @@ class Scheduler:
                                         st.req_ids, st.active)
                 # The ITL clock's read closes the span's bracket too.
                 now = sp.t1 = time.perf_counter()
-                sp.set(pages=_attended_pages(eng))
+                sp.set(pages=_attended_pages(eng), **eng.last_counters)
             chained = st.last_decode_done is not None
             if chained:
                 # The gap since the previous decode completion —

@@ -115,6 +115,8 @@ class CostModelEngine:
     the router's one-checkpoint replica wiring works unchanged."""
 
     kind = "sim"
+    handoff = True
+    last_counters: dict = {}  # the real engines' per-call counters: none
 
     def __init__(self, config, params=None, *, placed_params=None,
                  cost: CostModel | None = None):
@@ -253,6 +255,9 @@ class CostModelEngine:
     def reserve_pages(self, slot: int, n: int) -> None:
         self.pages.reserve(n)
         self.reserved_for[slot] += n
+
+    def can_admit(self, need: int) -> bool:
+        return self.pages.available >= need
 
     def reclaim_pages(self, need: int) -> bool:
         def frees(e) -> bool:
