@@ -371,6 +371,20 @@ def apply_lm_paged(
     ``ops.kv_cache.attend`` runs UNCHANGED and the masking/eviction
     semantics are exactly the contiguous cache's.
 
+    **The stacked pool is updated in place** (ISSUE 29): layer ``i``
+    never leaves the ``[L, pages, ...]`` arrays. Its fresh rows scatter
+    straight into the stack at flat row ``i * pages * page_size +
+    flat_rows`` and its pages gather straight out of it through ``i *
+    pages + table`` (``write_rows_flat`` / ``gather_pages`` with
+    ``layer=i``), so with the pools donated a program moves the rows it
+    writes and the pages it reads, not a layer's whole pool out and
+    back. ``flat_rows`` and ``table`` stay PER-LAYER indices, the same
+    for every layer; a dropped row (``>= pages * page_size``) goes out
+    of bounds of the whole stack (``L * pages * page_size``), never
+    into layer ``i + 1``'s row 0. Values stored, view gathered and
+    every dtype are what ``pool[i]`` / ``.at[i].set`` gave: logits and
+    pools are bitwise the same (pinned in tests/test_serve_paged.py).
+
     Parity contract: bitwise-identical logits to :func:`apply_lm_cached`
     over the same resident history, at ANY page-count bucket — masked
     padding contributes exactly 0 (verified on this backend; pinned
@@ -415,29 +429,31 @@ def apply_lm_paged(
         if quantized:
             kq, ks = kv_cache.quantize_rows(k)
             vq, vs = kv_cache.quantize_rows(v)
-            ck = kv_cache.write_rows_flat(pool_k[i], kq, flat_rows)
-            cv = kv_cache.write_rows_flat(pool_v[i], vq, flat_rows)
-            cks = kv_cache.write_rows_flat(pool_k_scale[i], ks, flat_rows)
-            cvs = kv_cache.write_rows_flat(pool_v_scale[i], vs, flat_rows)
-            pool_k_scale = pool_k_scale.at[i].set(cks)
-            pool_v_scale = pool_v_scale.at[i].set(cvs)
+            pool_k = kv_cache.write_rows_flat(
+                pool_k, kq, flat_rows, layer=i)
+            pool_v = kv_cache.write_rows_flat(
+                pool_v, vq, flat_rows, layer=i)
+            pool_k_scale = kv_cache.write_rows_flat(
+                pool_k_scale, ks, flat_rows, layer=i)
+            pool_v_scale = kv_cache.write_rows_flat(
+                pool_v_scale, vs, flat_rows, layer=i)
             k_view = kv_cache.dequantize_rows(
-                kv_cache.gather_pages(ck, table),
-                kv_cache.gather_pages(cks, table), q.dtype,
+                kv_cache.gather_pages(pool_k, table, layer=i),
+                kv_cache.gather_pages(pool_k_scale, table, layer=i), q.dtype,
             )
             v_view = kv_cache.dequantize_rows(
-                kv_cache.gather_pages(cv, table),
-                kv_cache.gather_pages(cvs, table), q.dtype,
+                kv_cache.gather_pages(pool_v, table, layer=i),
+                kv_cache.gather_pages(pool_v_scale, table, layer=i), q.dtype,
             )
         else:
-            ck = kv_cache.write_rows_flat(pool_k[i], k.astype(pool_k.dtype),
-                                          flat_rows)
-            cv = kv_cache.write_rows_flat(pool_v[i], v.astype(pool_v.dtype),
-                                          flat_rows)
-            k_view = kv_cache.gather_pages(ck, table).astype(q.dtype)
-            v_view = kv_cache.gather_pages(cv, table).astype(q.dtype)
-        pool_k = pool_k.at[i].set(ck)
-        pool_v = pool_v.at[i].set(cv)
+            pool_k = kv_cache.write_rows_flat(
+                pool_k, k.astype(pool_k.dtype), flat_rows, layer=i)
+            pool_v = kv_cache.write_rows_flat(
+                pool_v, v.astype(pool_v.dtype), flat_rows, layer=i)
+            k_view = kv_cache.gather_pages(
+                pool_k, table, layer=i).astype(q.dtype)
+            v_view = kv_cache.gather_pages(
+                pool_v, table, layer=i).astype(q.dtype)
         a = kv_cache.attend(q, k_view, v_view, positions, k_pos)
         h = h + reduce_(a.reshape(b, t, -1) @ blk["wo"])
         x = _layernorm(h, blk["ln2_g"], blk["ln2_b"])

@@ -105,6 +105,20 @@ def copy_prefix(
 #   bitwise equal to the contiguous attend over the same history
 #   (verified on this XLA:CPU before building; pinned in
 #   tests/test_serve_paged.py).
+#
+# **Layer offset** (ISSUE 29). The dense pool's K/V leaves are STACKED,
+# ``[L, pages, page_size, ...]``, and a forward touches layer ``i`` of
+# them without ever taking ``pool[i]`` out (a 134-MB copy a layer on the
+# chip, and another to put it back): :func:`write_rows_flat` and
+# :func:`gather_pages` take ``layer=i`` and address the stack through
+# its flat views — row ``i * pages * page_size + flat`` of ``[L * pages
+# * page_size, ...]``, page ``i * pages + max(table, 0)`` of ``[L *
+# pages, page_size, ...]`` (the reshapes merge leading axes only: free,
+# and untouched by a tp spec on the heads). The flat rows and the table
+# stay per-layer quantities (:func:`table_rows` knows no layer), and a
+# DROPPED row — anything outside ``[0, pages * page_size)`` — goes to
+# ``L * pages * page_size``, out of bounds of the WHOLE stack: adding
+# the layer's offset to it would land it in row 0 of layer ``i + 1``.
 
 
 def table_rows(
@@ -124,13 +138,25 @@ def table_rows(
                      num_pages * page_size)
 
 
-def gather_pages(pool: jax.Array, table: jax.Array) -> jax.Array:
+def gather_pages(pool: jax.Array, table: jax.Array,
+                 layer: int | None = None) -> jax.Array:
     """Per-slot contiguous K/V view ``[B, TP * page_size, ...]`` gathered
     from ``pool [pages, page_size, ...]`` through ``table [B, TP]``.
     Unmapped (``-1``) entries clamp to page 0 — their VALUES are live
     data of some other slot, which is exactly why masking happens on
-    :func:`table_positions`' ``PAD_POS``, never on the gathered values."""
-    g = pool[jnp.maximum(table, 0)]  # [B, TP, page, ...]
+    :func:`table_positions`' ``PAD_POS``, never on the gathered values.
+
+    With ``layer=i`` the pool is the STACKED ``[L, pages, page_size,
+    ...]`` array and the view is layer ``i``'s, gathered straight from
+    the stack viewed as ``[L * pages, page_size, ...]`` at pages ``i *
+    pages + max(table, 0)`` — the values ``gather_pages(pool[i],
+    table)`` gives, without the copy of ``pool[i]``."""
+    pages = jnp.maximum(table, 0)
+    if layer is not None:
+        num_layers, num_pages = pool.shape[:2]
+        pool = pool.reshape((num_layers * num_pages,) + pool.shape[2:])
+        pages = layer * num_pages + pages
+    g = pool[pages]  # [B, TP, page, ...]
     return g.reshape((g.shape[0], g.shape[1] * g.shape[2]) + g.shape[3:])
 
 
@@ -174,15 +200,30 @@ def dequantize_rows(q: jax.Array, scale: jax.Array, dtype) -> jax.Array:
             * scale[..., None].astype(jnp.float32)).astype(dtype)
 
 
-def write_rows_flat(pool: jax.Array, new: jax.Array,
-                    flat: jax.Array) -> jax.Array:
+def write_rows_flat(pool: jax.Array, new: jax.Array, flat: jax.Array,
+                    layer: int | None = None) -> jax.Array:
     """Write ``new [B, T, ...]`` into ``pool [pages, page_size, ...]``
     at FLAT row indices ``flat [B, T]`` (from :func:`table_rows`). All
     slots scatter into the ONE shared pool — distinct rows are the
     allocator's invariant (disjoint pages per slot; shared prefix pages
-    are never written while shared). Out-of-bounds rows drop."""
-    p, page = pool.shape[:2]
-    out = pool.reshape((p * page,) + pool.shape[2:]).at[
+    are never written while shared). Out-of-bounds rows drop.
+
+    With ``layer=i`` the pool is the STACKED ``[L, pages, page_size,
+    ...]`` array, ``flat`` still indexes ONE layer's ``pages *
+    page_size`` rows, and the write lands in layer ``i`` through the
+    stack's flat view at row ``i * pages * page_size + flat``: one
+    in-place scatter under donation, where ``pool.at[i].set(
+    write_rows_flat(pool[i], ...))`` copies the layer out and back. A
+    row outside ``[0, pages * page_size)`` is a DROPPED row and maps to
+    ``L * pages * page_size`` — out of bounds of the whole stack, never
+    offset into the next layer. No other layer's rows are touched."""
+    lead = 2 if layer is None else 3
+    rows = math.prod(pool.shape[:lead])
+    if layer is not None:
+        per_layer = rows // pool.shape[0]
+        flat = jnp.where((flat >= 0) & (flat < per_layer),
+                         layer * per_layer + flat, rows)
+    out = pool.reshape((rows,) + pool.shape[lead:]).at[
         flat.reshape(-1)
     ].set(new.reshape((-1,) + new.shape[2:]))
     return out.reshape(pool.shape)

@@ -90,6 +90,139 @@ def test_table_rows_gather_and_write_roundtrip():
     assert (np.asarray(kpos)[1, ps:] == PAD_POS).all()  # unmapped page
 
 
+# -- the stacked pool is written in place (ISSUE 29) --------------------------
+
+
+def _paged_forward_per_layer(params, tokens, pools, pool_pos, table, spec,
+                             *, positions, flat_rows):
+    """``apply_lm_paged`` as it stood before ISSUE 29, kept as the
+    oracle: layer ``i``'s pool is taken out of the stack
+    (``pool[i]``), written and gathered as a one-layer pool, and put
+    back whole (``.at[i].set``). ``pools`` is ``(k, v)`` or, int8,
+    ``(k, v, k_scale, v_scale)``; returns ``(logits, pools, pos)``."""
+    from ddl_tpu.models.transformer import _layernorm, rope
+
+    quantized = len(pools) == 4
+    pools = list(pools)
+    h = params["embed"][tokens]
+    b, t, _ = h.shape
+    pool_pos = kv_cache.write_rows_flat(
+        pool_pos, positions.astype(pool_pos.dtype), flat_rows)
+    k_pos = kv_cache.table_positions(pool_pos, table)
+    heads = lambda a: a.reshape(b, t, -1, spec.head_dim)
+    for i, blk in enumerate(params["blocks"]):
+        x = _layernorm(h, blk["ln1_g"], blk["ln1_b"])
+        q = rope(heads(x @ blk["wq"]), positions, spec.rope_base)
+        k = rope(heads(x @ blk["wk"]), positions, spec.rope_base)
+        v = heads(x @ blk["wv"])
+        if quantized:
+            (kq, ks), (vq, vs) = (kv_cache.quantize_rows(k),
+                                  kv_cache.quantize_rows(v))
+            fresh = (kq, vq, ks, vs)
+        else:
+            fresh = (k.astype(pools[0].dtype), v.astype(pools[1].dtype))
+        layer = [kv_cache.write_rows_flat(p[i], f, flat_rows)
+                 for p, f in zip(pools, fresh)]
+        pools = [p.at[i].set(c) for p, c in zip(pools, layer)]
+        views = [kv_cache.gather_pages(c, table) for c in layer]
+        if quantized:
+            k_view = kv_cache.dequantize_rows(views[0], views[2], q.dtype)
+            v_view = kv_cache.dequantize_rows(views[1], views[3], q.dtype)
+        else:
+            k_view, v_view = (c.astype(q.dtype) for c in views)
+        a = kv_cache.attend(q, k_view, v_view, positions, k_pos)
+        h = h + a.reshape(b, t, -1) @ blk["wo"]
+        x = _layernorm(h, blk["ln2_g"], blk["ln2_b"])
+        h = h + jax.nn.gelu(x @ blk["w1"] + blk["b1"]) @ blk["w2"] + blk["b2"]
+    h = _layernorm(h, params["lnf_g"], params["lnf_b"])
+    return (h @ params["head"]).astype(jnp.float32), tuple(pools), pool_pos
+
+
+@pytest.mark.parametrize("step", ["padded_prefill_tail",
+                                  "inactive_decode_slots"])
+@pytest.mark.parametrize("kv", ["bf16", "int8"])
+def test_dropped_writes_stay_dropped_under_the_layer_offset(kv, step):
+    """The in-place write of the stacked pool (layer ``i`` at flat row
+    ``i * pages * page + flat``) keeps the drop discipline: a padded
+    prefill tail and inactive decode slots, whose rows ``table_rows``
+    maps to ``pages * page``, touch NO row of ANY layer — row 0 of
+    layer ``i + 1``, where a naive offset would put them, above all.
+    Every row of every leaf other than the rows written is bitwise as
+    it was, and logits, pools and positions are bitwise what taking
+    ``pool[i]`` out and putting it back whole gives."""
+    from ddl_tpu.models.transformer import (LMSpec, apply_lm_paged,
+                                            init_lm_params)
+
+    spec = LMSpec(vocab=32, d_model=32, num_heads=2, num_layers=3, d_ff=64)
+    ps, P, L, H, D = 4, 6, spec.num_layers, spec.num_heads, spec.head_dim
+    keys = jax.random.split(jax.random.PRNGKey(29), 6)
+    params = init_lm_params(keys[0], spec)
+    if kv == "int8":
+        payload = lambda k: jax.random.randint(
+            k, (L, P, ps, H, D), -127, 128, jnp.int8)
+        scale = lambda k: jax.random.uniform(
+            k, (L, P, ps, H), jnp.float32, 0.001, 0.02)
+        pools = (payload(keys[1]), payload(keys[2]),
+                 scale(keys[3]), scale(keys[4]))
+    else:
+        pools = tuple(jax.random.normal(k, (L, P, ps, H, D), jnp.bfloat16)
+                      for k in keys[1:3])
+    pos = jnp.full((P, ps), PAD_POS, jnp.int32)
+    if step == "padded_prefill_tail":
+        # One slot, a bucket of 8 holding 5 real tokens over pages
+        # [2, 1]: rows 0-3 -> page 2, row 4 -> page 1; the three padded
+        # rows carry logical row = reach and drop.
+        table = jnp.asarray([[2, 1, -1, -1]], jnp.int32)
+        real = jnp.arange(8) < 5
+        positions = jnp.where(real, jnp.arange(8), PAD_POS)[None, :]
+        logical = jnp.where(real, jnp.arange(8), 4 * ps)[None, :]
+        tokens = jax.random.randint(keys[5], (1, 8), 0, spec.vocab)
+        written = {8, 9, 10, 11, 4}
+    else:
+        # Three slots, the middle one inactive (no page mapped); the
+        # others hold 5 and 2 tokens of history and write their next.
+        table = jnp.asarray([[2, 1], [-1, -1], [4, -1]], jnp.int32)
+        active = jnp.asarray([True, False, True])
+        lengths = jnp.asarray([5, 0, 2], jnp.int32)
+        positions = jnp.where(active, lengths, PAD_POS)[:, None]
+        logical = jnp.where(active, lengths, 2 * ps)[:, None]
+        tokens = jax.random.randint(keys[5], (3, 1), 0, spec.vocab)
+        pos = pos.at[2, :].set(jnp.arange(4)).at[1, 0].set(4)
+        pos = pos.at[4, :2].set(jnp.arange(2))
+        written = {1 * ps + 1, 4 * ps + 2}
+    flat = kv_cache.table_rows(table, logical, ps, P)
+    dropped = int((np.asarray(flat) == P * ps).sum())
+    assert dropped == (3 if step == "padded_prefill_tail" else 1)
+    assert {int(r) for r in np.asarray(flat).ravel()} - {P * ps} == written
+
+    scales = (dict(pool_k_scale=pools[2], pool_v_scale=pools[3])
+              if kv == "int8" else {})
+    out = apply_lm_paged(params, tokens, pools[0], pools[1], pos, table,
+                         spec, positions=positions, flat_rows=flat, **scales)
+    logits, new_pools, new_pos = out[0], out[1:3] + out[4:], out[3]
+    want_logits, want_pools, want_pos = _paged_forward_per_layer(
+        params, tokens, pools, pos, table, spec, positions=positions,
+        flat_rows=flat)
+    np.testing.assert_array_equal(np.asarray(logits),
+                                  np.asarray(want_logits))
+    np.testing.assert_array_equal(np.asarray(new_pos), np.asarray(want_pos))
+    untouched = np.asarray(sorted(set(range(P * ps)) - written))
+    for leaf, (old, new, want) in enumerate(
+            zip(pools, new_pools, want_pools)):
+        old, new, want = (np.asarray(a).reshape((L, P * ps) + a.shape[3:])
+                          for a in (old, new, want))
+        assert new.dtype == old.dtype
+        np.testing.assert_array_equal(new, want, err_msg=f"leaf {leaf}")
+        np.testing.assert_array_equal(new[:, untouched], old[:, untouched],
+                                      err_msg=f"leaf {leaf}")
+        np.testing.assert_array_equal(new[:, 0], old[:, 0],
+                                      err_msg=f"leaf {leaf}: row 0")
+        # ...and the rows written did change, in every layer.
+        for r in written:
+            assert (new[:, r] != old[:, r]).reshape(L, -1).any(1).all(), (
+                leaf, r)
+
+
 # -- validation: loud ctor + loud submit (ISSUE 7 satellite) ------------------
 
 
