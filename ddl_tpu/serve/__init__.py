@@ -22,8 +22,10 @@ batching — the inference half of the sharded-mesh story.
   lookup) verified bit-identically through free decode-batch lanes
 - ``serve.engine_iface`` — the ServeEngine protocol: the narrow engine
   surface the control plane actually calls (ISSUE 18)
-- ``serve.sim``       — the cost-model engine: no arrays, per-phase
-  virtual time, identical host bookkeeping — the million-request
+- ``serve.host``      — the engines' host half, written once: config
+  check, block tables, reservations, prefix paging, bucket ladders
+- ``serve.sim``       — the cost-model engine: that host half with no
+  arrays under it, per-phase virtual time — the million-request
   digital twin's engine
 - ``serve.scenarios`` — the named scenario library (seeded burst,
   diurnal, crash-storm, role-mix, longtail-prefix) shared by the

@@ -347,8 +347,8 @@ class PagePool:
     - **Refcounts**: a page is held by every slot whose table maps it
       AND every prefix entry that registered it — zero-copy sharing is
       just ``incref``. The last ``decref`` frees the page; the caller
-      (``serve.engine``) then resets its ``pos`` rows to ``PAD_POS`` on
-      device (the free-list invariant ``PagedKVCache`` documents).
+      (``serve.host._pages_freed``) then resets its ``pos`` rows to
+      ``PAD_POS`` on device (the invariant ``PagedKVCache`` documents).
     - **Reservations**: the scheduler admits a request only when
       ``available`` (free minus already-promised) covers its worst case
       ``ceil((prompt + max_new) / page_size)`` minus the pages a prefix
@@ -402,7 +402,7 @@ class PagePool:
 
     def alloc(self) -> int:
         """Pop the lowest free page id at refcount 1. The caller owns
-        the reservation bookkeeping (``serve.engine._map_page``)."""
+        the reservation bookkeeping (``serve.host._map_page``)."""
         if not self._free:
             raise RuntimeError("page pool exhausted (no free pages)")
         page = heapq.heappop(self._free)

@@ -1,9 +1,11 @@
 """The inference engine: a jitted ``(prefill, decode)`` pair over the
 serving mesh.
 
-This is the device half of the serving subsystem (the host half — slot
-admission, eviction, batching policy — is ``serve.scheduler``). Two
-compiled programs cover a request's whole life:
+This is the device half of the serving subsystem (batching policy is
+``serve.scheduler``; the engine's own host half — config check, block
+tables, reservations, prefix paging — is ``serve.host.EngineHost``,
+which the twin ``serve.sim`` inherits too). Two compiled programs cover
+a request's whole life:
 
 - **prefill**: one block of a request's prompt (padded to a power-of-two
   bucket so a handful of programs serve every length) runs through
@@ -97,17 +99,16 @@ from ..parallel.mesh import TP_AXIS, donation_for, make_mesh
 from .cache import (
     KVCache,
     PagedKVCache,
-    PagePool,
     cache_specs,
     copy_page,
     copy_slot_prefix,
     host_cache,
     host_paged_cache,
-    kv_row_bytes,
     paged_cache_specs,
     write_page,
 )
-from .prefix import PrefixIndex
+from .host import EngineHost
+from .speculate import SPECULATE_METHODS
 
 
 def _named(fn, name: str):
@@ -178,19 +179,13 @@ class ServeConfig:
     chunking, and must be >= the chunk so every tick can make progress).
 
     ``page_size > 0`` switches the KV cache to the PAGED block-table
-    layout (``serve.cache.PagedKVCache``): one shared pool of
-    ``num_pages`` fixed-size pages replaces the per-slot rings —
-    capacity pools across slots (admission becomes "enough free pages"
-    instead of a worst-case ``capacity`` reservation per slot), prefix
-    hits share pages zero-copy by refcount, and decode programs bucket
-    on PAGE COUNT so attention cost tracks actual residency, not
-    ``capacity``. ``capacity`` still bounds one slot's reach
-    (``capacity // page_size`` block-table entries). ``num_pages = 0``
-    defaults to ``slots * capacity / page_size`` — the slot-major
-    memory envelope, no pooling savings but drop-in. The contiguous
-    path (``page_size = 0``, the default) is retained as the
-    bit-exactness oracle: paged decode is PINNED bit-identical to it
-    (tests/test_serve_paged.py).
+    layout (the module docstring's "Paged KV pool"): one shared pool of
+    ``num_pages`` pages of ``page_size`` rows. ``capacity`` still bounds
+    one slot's reach (``capacity // page_size`` block-table entries).
+    ``num_pages = 0`` defaults to ``slots * capacity / page_size`` — the
+    slot-major memory envelope, no pooling savings but drop-in.
+    ``page_size = 0`` (the default) is the contiguous cache, retained as
+    the bit-exactness oracle (tests/test_serve_paged.py).
 
     ``kv_dtype = "int8"`` (paged layout only; ISSUE 19) stores the pool
     as int8 payloads plus per-head fp32 scales
@@ -224,17 +219,106 @@ class ServeConfig:
     # Speculative decoding (ISSUE 15, serve.speculate): k > 0 drafts up
     # to k tokens per active slot per tick and verifies them through
     # FREE SLOTS of the one batched decode call (zero new programs —
-    # the draft lanes alias the speculating slot's pages). Greedy-
-    # accept needs the greedy target (temperature 0), the paged layout
-    # (lane tables are page aliases) and slots >= 2 (somewhere for a
-    # lane to ride). method: "ngram" (prompt + generated lookup) or
-    # "prompt" (prompt-only lookup). k = 0 is the byte-identical
+    # the draft lanes alias the speculating slot's pages); what that
+    # requires is in REFUSED. method: "ngram" (prompt + generated
+    # lookup) or "prompt" (prompt-only lookup). k = 0 is the byte-identical
     # pre-speculation tick (HLO-pinned in tests/test_serve_speculate).
     speculate_k: int = 0
     speculate_method: str = "ngram"
 
     def dtype(self):
         return None if self.compute_dtype is None else jnp.dtype(self.compute_dtype)
+
+    def check(self, also=()) -> tuple[int, int, int]:
+        """Raise for the first rule of ``also`` (one engine's own) or
+        :data:`REFUSED` that this config breaks; then the page geometry
+        ``(page_size, max_pages, num_pages)``: ``max_pages`` the block
+        table's width, all zero for the contiguous cache. Called by
+        every engine's constructor (``EngineHost._configure``)."""
+        for asked, message in (*also, *REFUSED):
+            if asked(self):
+                raise ValueError(message.format(
+                    c=self, methods=", ".join(SPECULATE_METHODS)))
+        ps = self.page_size
+        if not ps:
+            return 0, 0, 0
+        max_pages = self.capacity // ps
+        return ps, max_pages, self.num_pages or self.slots * max_pages
+
+
+def _pow2(n: int) -> bool:
+    return n > 0 and not n & (n - 1)
+
+
+# What no engine serves, whatever its family or device, so that the real
+# engines and the twin fail alike, at construction, never mid-run: every
+# rule that reads a ServeConfig alone, as ``(asked(config), message)``
+# pairs in the order they are refused, the message formatted with
+# ``c=config``.
+REFUSED = (
+    (lambda c: c.slots < 1 or c.capacity < 2,
+     "need slots >= 1 and capacity >= 2, got {c.slots} / {c.capacity}"),
+    (lambda c: not 0 <= c.top_k <= c.spec.vocab,
+     "top_k must be in [0, vocab={c.spec.vocab}], got {c.top_k}"),
+    (lambda c: c.prefix_slots < 0,
+     "prefix_slots must be >= 0, got {c.prefix_slots}"),
+    # Power-of-two >= 8: a chunk is ITS OWN prefill bucket (plus the
+    # smaller buckets any final partial chunk already uses), keeping the
+    # compiled-program count logarithmic.
+    (lambda c: c.prefill_chunk
+     and not (c.prefill_chunk >= 8 and _pow2(c.prefill_chunk)),
+     "prefill_chunk must be 0 or a power of two >= 8, got "
+     "{c.prefill_chunk}"),
+    (lambda c: c.prefill_budget and not c.prefill_chunk,
+     "prefill_budget requires prefill_chunk (the budget meters chunk "
+     "interleaving; whole-prompt prefill ignores it silently otherwise)"),
+    (lambda c: c.prefill_budget and c.prefill_budget < c.prefill_chunk,
+     "prefill_budget ({c.prefill_budget}) below prefill_chunk "
+     "({c.prefill_chunk}) could never start a chunk"),
+    (lambda c: c.page_size and not _pow2(c.page_size),
+     "page_size must be 0 (contiguous) or a power of two, got "
+     "{c.page_size} (pages tile the capacity and the row->page split is "
+     "a shift/mask)"),
+    (lambda c: c.num_pages and not c.page_size,
+     "num_pages ({c.num_pages}) requires page_size > 0 (the contiguous "
+     "layout has no page pool)"),
+    (lambda c: c.num_pages < 0, "num_pages must be >= 0, got {c.num_pages}"),
+    # int8 storage is a property of the PAGE pool — the contiguous ring
+    # is the bit-exactness oracle and stays full-precision.
+    (lambda c: c.kv_dtype not in (None, "int8"),
+     "kv_dtype must be None or 'int8', got {c.kv_dtype!r}"),
+    (lambda c: c.kv_dtype == "int8" and not c.page_size,
+     "kv_dtype='int8' needs the paged KV layout (page_size > 0): "
+     "quantized storage lives in the shared page pool; the contiguous "
+     "ring is the full-precision oracle"),
+    # Every speculation requirement is structural — a violated one could
+    # only surface as silently-never-speculating or a mid-run lane
+    # failure.
+    (lambda c: c.speculate_k < 0,
+     "speculate_k must be >= 0, got {c.speculate_k}"),
+    (lambda c: c.speculate_method not in SPECULATE_METHODS,
+     "speculate_method must be one of {methods}, got "
+     "{c.speculate_method!r}"),
+    (lambda c: c.speculate_k > 0 and not c.page_size,
+     "speculate_k={c.speculate_k} needs the paged KV layout (page_size > "
+     "0): draft lanes verify through block-table ALIASES of the "
+     "speculating slot's pages, and contiguous slot rings have no pages "
+     "to alias"),
+    (lambda c: c.speculate_k > 0 and c.temperature > 0.0,
+     "speculate_k={c.speculate_k} needs temperature=0 (greedy): greedy-"
+     "accept is what keeps speculative output bit-identical to plain "
+     "decode; sampled acceptance is a different algorithm"),
+    (lambda c: c.speculate_k > 0 and c.slots < 2,
+     "speculate_k={c.speculate_k} needs slots >= 2: drafts verify through "
+     "FREE slots of the batched decode, and a 1-slot batch has no lane to "
+     "ride"),
+    (lambda c: c.page_size and c.capacity % c.page_size,
+     "capacity ({c.capacity}) must be a multiple of page_size "
+     "({c.page_size}) — the block table holds whole pages"),
+    (lambda c: 0 < c.num_pages < c.slots,
+     "num_pages ({c.num_pages}) below slots ({c.slots}) — every admitted "
+     "slot needs at least one page; the pool could never fill the batch"),
+)
 
 
 def _load_host_params(path, spec, init=transformer.init_lm_params):
@@ -248,7 +332,7 @@ def _load_host_params(path, spec, init=transformer.init_lm_params):
     return host
 
 
-class InferenceEngine:
+class InferenceEngine(EngineHost):
     """Owns the placed params, the cache state, and the compiled
     program pair. ``params`` is a host pytree (e.g. a fresh init or a
     ``utils.checkpoint.load_params`` result); ``None`` seeds a random
@@ -258,17 +342,8 @@ class InferenceEngine:
     ISSUE 8) — no re-placement, no transient duplicate copy; safe
     because no compiled program donates the params argument.
 
-    This class is one implementation of the control-plane engine
-    contract (:class:`~ddl_tpu.serve.engine_iface.ServeEngine`); the
-    device-free twin (:class:`~ddl_tpu.serve.sim.CostModelEngine`,
-    ``kind == "sim"``) is the other."""
-
-    kind = "real"
-    handoff = True  # a slot's pages can be dumped, loaded and aliased
-    # What the last program counted beyond its tokens, as attributes for
-    # the scheduler's span around the call; the dense family counts
-    # nothing.
-    last_counters: dict = {}
+    The host half of :class:`~ddl_tpu.serve.engine_iface.ServeEngine`
+    is the base class's; here are the pools, the programs, its hooks."""
 
     # What a family's engine brings (``serve.hybrid_engine`` is the
     # second; ``serve.engine_cls(spec)`` picks the class): its spec
@@ -277,15 +352,19 @@ class InferenceEngine:
     # (``reset``) and programs.
     spec_type = LMSpec
     _init_params = staticmethod(transformer.init_lm_params)
+    refuses = (
+        (lambda c: c.tensor_parallel < 1,
+         "tensor_parallel must be >= 1, got {c.tensor_parallel}"),
+        (lambda c: c.spec.num_heads % c.tensor_parallel,
+         "tensor_parallel needs num_heads ({c.spec.num_heads}) divisible "
+         "by tp ({c.tensor_parallel})"),
+        (lambda c: c.spec.d_ff % c.tensor_parallel,
+         "tensor_parallel needs d_ff ({c.spec.d_ff}) divisible by tp "
+         "({c.tensor_parallel})"),
+    )
 
     def __init__(self, config: ServeConfig, params=None, *,
                  placed_params=None):
-        if params is not None and placed_params is not None:
-            raise ValueError(
-                "pass params (host tree, placed here) OR placed_params "
-                "(an already-placed tree to share), not both"
-            )
-        tp = config.tensor_parallel
         spec = config.spec
         if not isinstance(spec, self.spec_type):
             raise ValueError(
@@ -293,138 +372,9 @@ class InferenceEngine:
                 f"got a {type(spec).__name__}: serve.engine_cls(spec) is "
                 "the class of the spec's family"
             )
-        if tp < 1:
-            raise ValueError(f"tensor_parallel must be >= 1, got {tp}")
-        if tp > 1:
-            if spec.num_heads % tp:
-                raise ValueError(
-                    f"tensor_parallel needs num_heads ({spec.num_heads}) "
-                    f"divisible by tp ({tp})"
-                )
-            if spec.d_ff % tp:
-                raise ValueError(
-                    f"tensor_parallel needs d_ff ({spec.d_ff}) "
-                    f"divisible by tp ({tp})"
-                )
-        if config.slots < 1 or config.capacity < 2:
-            raise ValueError(
-                f"need slots >= 1 and capacity >= 2, got "
-                f"{config.slots} / {config.capacity}"
-            )
-        if not 0 <= config.top_k <= spec.vocab:
-            raise ValueError(
-                f"top_k must be in [0, vocab={spec.vocab}], got "
-                f"{config.top_k}"
-            )
-        if config.prefix_slots < 0:
-            raise ValueError(
-                f"prefix_slots must be >= 0, got {config.prefix_slots}"
-            )
-        ck = config.prefill_chunk
-        if ck and (ck < 8 or ck & (ck - 1)):
-            # Power-of-two >= 8: a chunk is ITS OWN prefill bucket (plus
-            # the smaller buckets any final partial chunk already uses),
-            # keeping the compiled-program count logarithmic.
-            raise ValueError(
-                f"prefill_chunk must be 0 or a power of two >= 8, got {ck}"
-            )
-        if config.prefill_budget:
-            if not ck:
-                raise ValueError(
-                    "prefill_budget requires prefill_chunk (the budget "
-                    "meters chunk interleaving; whole-prompt prefill "
-                    "ignores it silently otherwise)"
-                )
-            if config.prefill_budget < ck:
-                raise ValueError(
-                    f"prefill_budget ({config.prefill_budget}) below "
-                    f"prefill_chunk ({ck}) could never start a chunk"
-                )
-        # Paged-layout config (loud-ctor discipline, ISSUE 7 satellite):
-        # a malformed page geometry is a config error here, never a
-        # mid-run surprise.
-        ps = config.page_size
-        if ps < 0 or (ps and ps & (ps - 1)):
-            raise ValueError(
-                f"page_size must be 0 (contiguous) or a power of two, "
-                f"got {ps} (pages tile the capacity and the row->page "
-                "split is a shift/mask)"
-            )
-        if config.num_pages and not ps:
-            raise ValueError(
-                f"num_pages ({config.num_pages}) requires page_size > 0 "
-                "(the contiguous layout has no page pool)"
-            )
-        if config.num_pages < 0:
-            raise ValueError(f"num_pages must be >= 0, got {config.num_pages}")
-        self.paged = ps > 0
-        # Quantized-pool config (loud-ctor discipline): int8 storage is
-        # a property of the PAGE pool — the contiguous ring is the bit-
-        # exactness oracle and stays full-precision by definition.
-        if config.kv_dtype not in (None, "int8"):
-            raise ValueError(
-                f"kv_dtype must be None or 'int8', got {config.kv_dtype!r}"
-            )
-        if config.kv_dtype == "int8" and not self.paged:
-            raise ValueError(
-                "kv_dtype='int8' needs the paged KV layout (page_size > "
-                "0): quantized storage lives in the shared page pool; "
-                "the contiguous ring is the full-precision oracle"
-            )
+        self._configure(config, params, placed_params)
         self.quantized = config.kv_dtype == "int8"
-        # Speculation config (loud-ctor discipline): every requirement
-        # is structural — a violated one could only surface as silently
-        #-never-speculating or a mid-run lane failure.
-        sk = config.speculate_k
-        if sk < 0:
-            raise ValueError(f"speculate_k must be >= 0, got {sk}")
-        from .speculate import SPECULATE_METHODS
-
-        if config.speculate_method not in SPECULATE_METHODS:
-            raise ValueError(
-                f"speculate_method must be one of "
-                f"{', '.join(SPECULATE_METHODS)}, got "
-                f"{config.speculate_method!r}"
-            )
-        if sk > 0:
-            if not self.paged:
-                raise ValueError(
-                    f"speculate_k={sk} needs the paged KV layout "
-                    "(page_size > 0): draft lanes verify through block-"
-                    "table ALIASES of the speculating slot's pages, and "
-                    "contiguous slot rings have no pages to alias"
-                )
-            if config.temperature > 0.0:
-                raise ValueError(
-                    f"speculate_k={sk} needs temperature=0 (greedy): "
-                    "greedy-accept is what keeps speculative output "
-                    "bit-identical to plain decode; sampled acceptance "
-                    "is a different algorithm"
-                )
-            if config.slots < 2:
-                raise ValueError(
-                    f"speculate_k={sk} needs slots >= 2: drafts verify "
-                    "through FREE slots of the batched decode, and a "
-                    "1-slot batch has no lane to ride"
-                )
-        if self.paged:
-            if config.capacity % ps:
-                raise ValueError(
-                    f"capacity ({config.capacity}) must be a multiple of "
-                    f"page_size ({ps}) — the block table holds whole pages"
-                )
-            self.page_size = ps
-            self.max_pages = config.capacity // ps  # block-table width
-            self.num_pages = config.num_pages or config.slots * self.max_pages
-            if self.num_pages < config.slots:
-                raise ValueError(
-                    f"num_pages ({self.num_pages}) below slots "
-                    f"({config.slots}) — every admitted slot needs at "
-                    "least one page; the pool could never fill the batch"
-                )
-        else:
-            self.page_size = self.max_pages = self.num_pages = 0
-        self.config = config
+        tp = config.tensor_parallel
         # A 1-D tp mesh: serving has no data/sequence axis — the batch
         # dim is the slot dim, resident whole on every tp member.
         self.mesh = make_mesh(tp, axis=TP_AXIS)
@@ -438,26 +388,6 @@ class InferenceEngine:
                 )
             self.params = self._place(params)
         self._row_reduce = coll.tp_allreduce(TP_AXIS) if tp > 1 else None
-        # Compile-activity hook (ISSUE 10, obs/memory.py): called as
-        # ``hook(kind, key)`` at every DISTINCT program build — each
-        # cached program serves exactly one shape signature, so builds
-        # and XLA compiles are 1:1. None (the default) is a no-op; the
-        # scheduler attaches a registry-backed hook when telemetry is
-        # on, so the off path is unchanged.
-        self.compile_hook = None
-        # Collective-ledger hook (ISSUE 20, obs.comms): called as
-        # ``hook(kind, key, compiled)`` once per distinct program at
-        # its first real dispatch, with the AOT ``Compiled`` object
-        # (the only handle the optimized HLO text hangs off). None
-        # (the default) leaves every cached program a bare jitted
-        # callable — no wrapper, no HLO fetch, the off path unchanged
-        # by construction. The scheduler attaches it beside
-        # ``compile_hook`` when a registry is on.
-        self.ledger_hook = None
-        # The width the LAST decode attended per slot (paged: the
-        # page-count bucket's rows; contiguous: the fixed capacity) —
-        # the paged-aware denominator of serve_flops_per_token.
-        self.last_attend_width = config.capacity
         self._prefill_fns: dict[int, object] = {}
         self._decode_fn = None
         self._decode_paged_fns: dict[int, object] = {}
@@ -466,8 +396,6 @@ class InferenceEngine:
         self._copy_page_fn = None  # paged CoW: partial tail page
         self._write_page_fn = None  # paged: cross-replica page hand-off
         self._reset_pages_fn = None  # paged: PAD_POS freed pages' pos
-        self.pool: KVCache | None = None
-        self.prefix: PrefixIndex | None = None
         self.reset()
 
     def _layout(self) -> None:
@@ -504,25 +432,13 @@ class InferenceEngine:
             return jfn
         return _LedgeredProgram(self, kind, key, jfn)
 
-    def handoff_bytes(self, n_pages: int) -> int:
-        """Device bytes ``n_pages`` dumped/loaded pages represent,
-        priced by the ``serve.cache.kv_row_bytes`` oracle (int8 pools:
-        payloads + scale planes — the compressed wire size the
-        ``handoff_bytes_total{path=}`` counters publish)."""
-        dtype = np.dtype(self.config.compute_dtype or np.float32)
-        return int(n_pages) * self.page_size * kv_row_bytes(
-            self.config.spec, self.config.kv_dtype, dtype
-        )
-
     # -- state -------------------------------------------------------------
 
     def reset(self) -> None:
-        """Fresh (empty) cache — every slot free, nothing attendable.
-        The prefix pool and its host index reset TOGETHER (an index
-        entry without its device rows, or vice versa, would be
-        corruption by construction). Paged mode rebuilds the page pool,
-        the block tables and the allocator as one unit for the same
-        reason."""
+        """Fresh (empty) cache — every slot free, nothing attendable —
+        beside fresh host state (``EngineHost._reset_host``): the pools
+        and what indexes them reset TOGETHER."""
+        self._reset_host()
         dtype = np.dtype(self.config.compute_dtype or np.float32)
         if self.paged:
             self.cache = multihost.put_tree(
@@ -531,18 +447,6 @@ class InferenceEngine:
                                  self.page_size, dtype,
                                  kv_dtype=self.config.kv_dtype),
             )
-            self.pages = PagePool(self.num_pages)
-            self.tables = np.full(
-                (self.config.slots, self.max_pages), -1, np.int32
-            )
-            self.table_len = np.zeros(self.config.slots, np.int64)
-            self.reserved_for = np.zeros(self.config.slots, np.int64)
-            self.page_copies = 0  # CoW tail copies — the zero-copy pin
-            if self.config.prefix_slots > 0:
-                self.prefix = PrefixIndex(
-                    self.config.prefix_slots,
-                    on_evict=lambda e: self._release_pages(e.pages),
-                )
             return
         self.cache = multihost.put_tree(
             self.mesh, self._cspecs,
@@ -555,88 +459,16 @@ class InferenceEngine:
                 host_cache(self.config.spec, self.config.prefix_slots,
                            self.config.capacity, dtype),
             )
-            self.prefix = PrefixIndex(self.config.prefix_slots)
 
-    # -- paged page management (host half) ---------------------------------
+    # -- the host half's device hooks (serve.host) --------------------------
 
-    def pages_needed(self, rows: int) -> int:
-        """Worst-case page count for ``rows`` resident rows."""
-        return -(-rows // self.page_size)
-
-    def reserve_pages(self, slot: int, n: int) -> None:
-        """Admission promise: hold ``n`` pages of headroom for ``slot``
-        so its prefill chunks and decode page-boundary crossings can
-        never find the pool empty mid-flight. Consumed page-by-page as
-        the slot actually maps them; the remainder releases with the
-        slot (``release_slot``)."""
-        self.pages.reserve(n)
-        self.reserved_for[slot] += n
-
-    def can_admit(self, need: int) -> bool:
-        """Whether ``need`` pages can be reserved for a new slot now."""
-        return self.pages.available >= need
-
-    def reclaim_pages(self, need: int) -> bool:
-        """Evict zero-ref prefix entries (LRU-first) until ``need``
-        pages are available, dropping their page references — shared
-        pages whose last holder was the entry return to the free list.
-        Only entries whose eviction would actually FREE a page are
-        candidates (an entry whose every page is still mapped by a live
-        slot frees nothing now — evicting it would just burn future
-        hits; its pages free naturally when the slots finish). False
-        when no candidate can reach the target."""
-
-        def frees(e) -> bool:
-            return any(int(self.pages.refs[int(p)]) == 1
-                       for p in set(e.pages))
-
-        while self.pages.available < need:
-            if self.prefix is None or self.prefix.evict_lru(frees) is None:
-                return False
-        return True
-
-    def _map_page(self, slot: int) -> int:
-        """Append one freshly allocated page to ``slot``'s block table,
-        consuming the slot's admission reservation when it has one
-        (direct engine use — tests, warmup — allocates unreserved)."""
-        if self.reserved_for[slot] > 0:
-            self.reserved_for[slot] -= 1
-            self.pages.unreserve(1)
-        elif self.pages.available < 1:
-            raise RuntimeError(
-                f"slot {slot}: page pool exhausted (free "
-                f"{self.pages.free}, reserved {self.pages.reserved}) — "
-                "admission must reserve before the slot grows"
-            )
-        page = self.pages.alloc()
-        t = int(self.table_len[slot])
-        self.tables[slot, t] = page
-        self.table_len[slot] = t + 1
-        return page
-
-    def _ensure_rows(self, slot: int, rows: int) -> None:
-        """Map pages so logical rows ``[0, rows)`` of ``slot`` are
-        writable. Reach is bounded by the table width (validated at
-        submit — ``scheduler._validate``)."""
-        need = self.pages_needed(rows)
-        if need > self.max_pages:
-            raise ValueError(
-                f"slot {slot}: {rows} rows need {need} pages, table "
-                f"reach is {self.max_pages} pages "
-                f"({self.config.capacity} rows)"
-            )
-        while int(self.table_len[slot]) < need:
-            self._map_page(slot)
-
-    def _release_pages(self, pages) -> None:
-        """Drop one reference per page; pages hitting zero return to
-        the free list AND get their device ``pos`` rows reset to
+    def _pages_freed(self, pages) -> None:
+        """Freed pages get their device ``pos`` rows reset to
         ``PAD_POS`` (one batched scatter — the free-list invariant that
         lets a freshly mapped page join the gathered attend view with
         nothing attendable)."""
-        freed = [p for p in pages if self.pages.decref(int(p))]
-        while freed:
-            batch, freed = freed[: self.max_pages], freed[self.max_pages:]
+        while pages:
+            batch, pages = pages[: self.max_pages], pages[self.max_pages:]
             ids = np.full(self.max_pages, self.num_pages, np.int32)
             ids[: len(batch)] = batch  # padding is out of bounds: dropped
             if self._reset_pages_fn is None:
@@ -656,21 +488,11 @@ class InferenceEngine:
                 self._note_compile("pages_reset", 0)
             self.cache = self._reset_pages_fn(self.cache, jnp.asarray(ids))
 
-    def release_slot(self, slot: int) -> None:
-        """Free ``slot``'s residency: drop its page references (shared
-        prefix pages survive on the entry's reference), clear its block
-        table, and return any unused admission reservation — eviction
-        and completion are the same host bookkeeping, exactly like the
-        contiguous path's pos masking."""
-        n = int(self.table_len[slot])
-        pages = [int(p) for p in self.tables[slot, :n]]
-        self.tables[slot, :] = -1
-        self.table_len[slot] = 0
-        left = int(self.reserved_for[slot])
-        if left:
-            self.pages.unreserve(left)
-            self.reserved_for[slot] = 0
-        self._release_pages(pages)
+    def _copy_tail_page(self, src_page: int, dst_page: int, n: int) -> None:
+        self.cache = self._copy_page()(
+            self.cache, jnp.int32(src_page), jnp.int32(dst_page),
+            jnp.int32(n),
+        )
 
     def load_params(self, path) -> None:
         """Params-only checkpoint load (``utils.checkpoint.load_params``):
@@ -1051,12 +873,7 @@ class InferenceEngine:
         the disagg coordinator) store and forward them opaquely, so the
         hand-off moves the compressed bytes and ``load_slot_pages`` on
         the destination reassembles the exact source rows."""
-        if not self.paged:
-            raise RuntimeError(
-                "dump_slot_pages needs the paged KV layout (page_size > "
-                "0) — the contiguous ring has no slot-independent pages "
-                "to hand off"
-            )
+        self._need_pages("dump_slot_pages")
         n = int(self.table_len[slot])
         pages = jnp.asarray(self.tables[slot, :n], jnp.int32)
 
@@ -1083,10 +900,7 @@ class InferenceEngine:
         Int8 pools receive ``k``/``v`` as the ``(payload, scale)``
         pairs their ``dump_slot_pages`` produced — payloads and scales
         land together, page by page."""
-        if not self.paged:
-            raise RuntimeError(
-                "load_slot_pages needs the paged KV layout (page_size > 0)"
-            )
+        self._need_pages("load_slot_pages")
         ks = vs = None
         if self.quantized:
             if not (isinstance(k, tuple) and isinstance(v, tuple)):
@@ -1126,49 +940,6 @@ class InferenceEngine:
                 self.cache = fn(self.cache, jnp.int32(page), kk, vv, pp)
             mapped.append(page)
         return mapped
-
-    def alias_slot_pages(self, dst_slot: int, src_slot: int,
-                         rows: int) -> int:
-        """Make ``dst_slot`` a zero-copy alias of ``src_slot``'s table
-        covering logical rows ``[0, rows)`` — the draft-LANE setup of
-        speculative decoding (ISSUE 15, ``serve.speculate``): the lane
-        writes its draft token's K/V row through the SHARED pages and
-        attends the shared history, so one batched decode call verifies
-        k drafts with zero copies and zero new programs. Maps any page
-        ``src_slot`` still needs first (consuming ITS admission
-        reservation — the lane itself reserves nothing), then increfs
-        each page into the lane's table. The lane is torn down with the
-        ordinary ``release_slot`` (pure decref — the source's own
-        references keep every page live). Returns the aliased page
-        count."""
-        if not self.paged:
-            raise RuntimeError(
-                "alias_slot_pages needs the paged KV layout "
-                "(page_size > 0) — contiguous slots have no pages to "
-                "alias"
-            )
-        if int(self.table_len[dst_slot]) or int(self.reserved_for[dst_slot]):
-            raise RuntimeError(
-                f"alias_slot_pages into non-empty slot {dst_slot} "
-                "(lanes must be free slots)"
-            )
-        self._ensure_rows(src_slot, rows)
-        n = int(self.table_len[src_slot])
-        for i in range(n):
-            page = int(self.tables[src_slot, i])
-            self.pages.incref(page)
-            self.tables[dst_slot, i] = page
-        self.table_len[dst_slot] = n
-        return n
-
-    def decode_page_bucket(self, pages: int) -> int:
-        """The page-count bucket ladder: smallest power of two >=
-        ``pages``, capped at the table width — a handful of compiled
-        decode programs cover every residency."""
-        b = 1
-        while b < pages:
-            b *= 2
-        return min(b, self.max_pages)
 
     # -- prefix-cache device half ------------------------------------------
 
@@ -1214,113 +985,36 @@ class InferenceEngine:
         return fn
 
     def prefix_fetch(self, entry_id: int, n: int, slot: int) -> int:
-        """HIT: make the first ``n`` rows of entry ``entry_id`` resident
-        in decode ``slot`` and pin the entry (refcount) until the caller
-        releases it — LRU pressure can never free a prefix a live
-        request was admitted from. Returns the number of K/V rows
-        DEVICE-COPIED for the hit.
-
-        Contiguous mode: one donated gather program copies all ``n``
-        rows pool -> slot (returns ``n``). Paged mode: the entry's full
-        pages map straight into the slot's block table (incref — ZERO
-        copies); only when ``n`` is not page-aligned does the one
-        PARTIAL boundary page copy-on-write into a freshly mapped page
-        (returns ``n % page_size`` — the ``page_copies`` counter and
-        the scheduler's trace events assert exactly this bound)."""
-        e = self.prefix.entry(entry_id)
+        """``EngineHost.prefix_fetch`` over pages; on the contiguous
+        cache one donated gather program copies all ``n`` rows pool ->
+        slot (returns ``n``)."""
         if self.paged:
-            ps = self.page_size
-            shared, tail = n // ps, n % ps
-            if int(self.table_len[slot]):
-                raise RuntimeError(
-                    f"prefix_fetch into non-empty slot {slot} (admission "
-                    "maps shared pages into a fresh table only)"
-                )
-            for i in range(shared):
-                page = int(e.pages[i])
-                self.pages.incref(page)
-                self.tables[slot, i] = page
-            self.table_len[slot] = shared
-            copied = 0
-            if tail:
-                # The entry always covers the boundary page: its token
-                # coverage is a page multiple >= any match depth n.
-                dst = self._map_page(slot)
-                self.cache = self._copy_page()(
-                    self.cache, jnp.int32(int(e.pages[shared])),
-                    jnp.int32(dst), jnp.int32(tail),
-                )
-                self.page_copies += 1
-                copied = tail
-            self.prefix.touch(entry_id)
-            self.prefix.acquire(entry_id)
-            return copied
+            return super().prefix_fetch(entry_id, n, slot)
         self.cache = self._copy_fn(into_cache=True)(
             self.cache, self.pool,
-            jnp.int32(e.slot), jnp.int32(slot), jnp.int32(n),
+            jnp.int32(self.prefix.entry(entry_id).slot), jnp.int32(slot),
+            jnp.int32(n),
         )
-        self.prefix.touch(entry_id)
-        self.prefix.acquire(entry_id)
+        self._pin(entry_id)
         return n
 
-    def prefix_release(self, entry_id: int) -> None:
-        self.prefix.release(entry_id)
-
     def prefix_store(self, prompt, slot: int) -> bool:
-        """REGISTRATION: index ``prompt`` and make its freshly prefilled
-        rows ``0..p-1`` resident for future hits. Must run before the
-        slot's first decode write (the scheduler does — row ``p`` is
-        still stale here). False = registration skipped (index full of
-        pinned entries, or — paged — the prompt spans no full page).
-
-        Contiguous mode snapshots the rows into a claimed pool slot (one
-        donated copy program). Paged mode DONATES instead of
-        snapshotting: the entry takes a reference on each of the slot's
-        FULL prompt pages (the partial last page stays slot-private —
-        decode is about to write into it), so registration moves zero
-        K/V bytes and the pages are shared from that moment on. The
-        slot's own reference keeps every donated page live until it
-        finishes, so an eviction racing this insert can never free
-        them."""
-        prompt = np.asarray(prompt, np.int32)
+        """``EngineHost.prefix_store`` over pages; on the contiguous
+        cache the rows are snapshot into a claimed pool slot (one
+        donated copy program)."""
         if self.paged:
-            full = int(prompt.shape[0]) // self.page_size
-            if full < 1:
-                return False
-            pages = [int(p) for p in self.tables[slot, :full]]
-            got = self.prefix.insert(
-                prompt[: full * self.page_size], pages=pages
-            )
-            if got is None:
-                return False
-            for page in pages:
-                self.pages.incref(page)
-            return True
+            return super().prefix_store(prompt, slot)
+        prompt = np.asarray(prompt, np.int32)
         got = self.prefix.insert(prompt)
         if got is None:
             return False
-        _, pool_slot = got
         self.pool = self._copy_fn(into_cache=False)(
-            self.cache, self.pool,
-            jnp.int32(slot), jnp.int32(pool_slot),
+            self.cache, self.pool, jnp.int32(slot), jnp.int32(got[1]),
             jnp.int32(int(prompt.shape[0])),
         )
         return True
 
     # -- host API ----------------------------------------------------------
-
-    def prefill_bucket(self, prompt_len: int) -> int:
-        """Smallest power-of-two bucket >= max(prompt_len, 8), capped at
-        capacity — a handful of compiled programs cover every length."""
-        if not 1 <= prompt_len <= self.config.capacity:
-            raise ValueError(
-                f"prompt length {prompt_len} outside [1, capacity="
-                f"{self.config.capacity}]"
-            )
-        b = 8
-        while b < prompt_len:
-            b *= 2
-        return min(b, self.config.capacity)
 
     def prefill(self, prompt, *, slot: int, request_id: int, base: int = 0,
                 _bucket: int | None = None):
@@ -1336,15 +1030,7 @@ class InferenceEngine:
         warmup ladder's compile trigger, so compiling a big bucket
         costs one real row (and, paged, one page) instead of a full
         bucket of writes."""
-        prompt = np.asarray(prompt, np.int32)
-        t = int(prompt.shape[0])
-        if base < 0 or base + t > self.config.capacity:
-            raise ValueError(
-                f"prefill block [base={base}, base+{t}) outside cache "
-                f"capacity {self.config.capacity}"
-            )
-        bucket = self.prefill_bucket(t) if _bucket is None else _bucket
-        assert bucket >= t, (bucket, t)
+        prompt, t, bucket = self._prefill_block(prompt, base, _bucket)
         with span("engine.upload", kind="prefill"):
             tokens = np.zeros((1, bucket), np.int32)
             tokens[0, :t] = prompt
@@ -1366,28 +1052,15 @@ class InferenceEngine:
         ``(next_tokens np [S], logits np [S, vocab])`` out; the fetch is
         the step's true barrier (latency timing hangs off it).
 
-        Paged mode first maps any page a growing slot is about to cross
-        into (consuming its admission reservation — this can never find
-        the pool empty), then runs the program whose PAGE-COUNT bucket
-        covers the widest ACTIVE table: attend cost tracks residency.
-        A mid-prefill slot's wider table truncates harmlessly — it is
-        inactive, so its writes drop and its outputs are discarded.
-        ``_pages`` forces a bucket (warmup's compile trigger, called
-        with every slot inactive so no state moves)."""
+        Paged mode runs the program of ``EngineHost._decode_bucket``:
+        attend cost tracks residency. ``_pages`` forces a bucket
+        (warmup's compile trigger, called with every slot inactive)."""
         with span("engine.upload", kind="decode"):
             lengths_np = np.asarray(lengths, np.int32)
             active_np = np.asarray(active, bool)
             tables = ()
             if self.paged:
-                if _pages is None:
-                    widest = 1
-                    for s in np.nonzero(active_np)[0]:
-                        self._ensure_rows(int(s), int(lengths_np[s]) + 1)
-                        widest = max(widest, int(self.table_len[s]))
-                    pb = self.decode_page_bucket(widest)
-                else:
-                    pb = _pages
-                self.last_attend_width = pb * self.page_size
+                pb = self._decode_bucket(lengths_np, active_np, _pages)
                 fn = self._decode_paged(pb)
                 tables = (self.tables[:, :pb],)
             else:

@@ -7,21 +7,20 @@ burn, anomaly edges — is deterministic host logic on a tick clock; only
 the engine underneath touches a device.  :class:`ServeEngine` is the
 written-down contract of that boundary: the attributes and methods
 ``Scheduler`` / ``Router`` / ``FleetController`` / ``DisaggCoordinator``
-read, and nothing else.  Two implementations exist:
-
-* :class:`~ddl_tpu.serve.engine.InferenceEngine` (``kind == "real"``)
-  — placed params, compiled programs, device arrays.
-* :class:`~ddl_tpu.serve.sim.CostModelEngine` (``kind == "sim"``) — no
-  arrays; advances the same host bookkeeping (page pool, block tables,
-  prefix index) and charges per-phase *virtual* time fitted from the
-  goodput plane's measured ``time_in_seconds{phase=}``.
+read, and nothing else.  The real engines
+(:class:`~ddl_tpu.serve.engine.InferenceEngine`, ``kind == "real"``, and
+its second family :class:`~ddl_tpu.serve.hybrid_engine.HybridEngine`)
+and the twin (:class:`~ddl_tpu.serve.sim.CostModelEngine`, ``kind ==
+"sim"``: no arrays, virtual time) implement its host half ONCE, by
+inheriting :class:`~ddl_tpu.serve.host.EngineHost`, which names the two
+places where an engine's device comes in.
 
 The contract is structural (``typing.Protocol``): the control plane
-stays duck-typed and the real engine needs no inheritance edge — the
-protocol is the *specification*, checked by tests, not a base class.
-Because every control decision reads only this surface, any engine
-satisfying it replays the identical controller event timeline — the
-tick-for-tick parity pin in tests/test_twin.py.
+stays duck-typed, and tests/test_twin.py asks every name the scheduler,
+the controller and the CLI read of all three classes.  Because every
+control decision reads only this surface, any engine satisfying it
+replays the identical controller event timeline — the tick-for-tick
+parity pin in tests/test_twin.py.
 """
 from __future__ import annotations
 
@@ -49,8 +48,9 @@ class ServeEngine(Protocol):
       lookup) and ``.devices.size`` (MFU denominator).
     * ``params`` — opaque; replicas share one tree via
       ``placed_params`` (may be ``None`` for a cost-model engine).
-    * ``compile_hook`` — set by the scheduler; the engine calls
-      ``hook(kind, key)`` once per distinct program build.
+    * ``compile_hook`` — set by the scheduler when a registry is on;
+      the engine calls ``hook(kind, key)`` at every DISTINCT program
+      build (one shape signature each: builds and compiles are 1:1).
     * ``last_attend_width`` — rows the last decode attended (the
       paged-aware ``serve_flops_per_token`` denominator).
     * ``last_counters`` — what the last program counted beyond its
@@ -90,6 +90,8 @@ class ServeEngine(Protocol):
 
     def alias_slot_pages(self, dst_slot: int, src_slot: int,
                          rows: int) -> int: ...
+
+    def handoff_bytes(self, n_pages: int) -> int: ...
 
     # -- prefix cache -------------------------------------------------------
     def prefix_fetch(self, entry_id: int, n: int, slot: int) -> int: ...

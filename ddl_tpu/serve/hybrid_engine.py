@@ -42,7 +42,7 @@ from ..models import hybrid
 from ..obs.trace import span
 from ..parallel.mesh import donation_for
 from .cache import HybridKVCache, PagePool, hybrid_cache, ring_columns
-from .engine import InferenceEngine, ServeConfig, _named
+from .engine import InferenceEngine, _named
 
 # What the family does not serve yet, by the config field that asks for
 # it: each is refused by name when the engine is built.
@@ -65,16 +65,11 @@ class HybridEngine(InferenceEngine):
     handoff = False
     spec_type = hybrid.HybridSpec
     _init_params = staticmethod(hybrid.init_hybrid_params)
-
-    def __init__(self, config: ServeConfig, params=None, *,
-                 placed_params=None):
-        for feature, asked, field in UNSUPPORTED:
-            if asked(config):
-                raise ValueError(
-                    f"the hybrid family does not support the {feature} "
-                    f"({field}) yet; it serves paged, tp 1, full-precision "
-                    "pools, no prefix cache, no speculation")
-        super().__init__(config, params, placed_params=placed_params)
+    refuses = tuple(
+        (asked, f"the hybrid family does not support the {feature} "
+                f"({field}) yet; it serves paged, tp 1, full-precision "
+                "pools, no prefix cache, no speculation")
+        for feature, asked, field in UNSUPPORTED)
 
     def _layout(self) -> None:
         cfg = self.config
@@ -93,14 +88,10 @@ class HybridEngine(InferenceEngine):
 
     def reset(self) -> None:
         cfg = self.config
+        self._reset_host()  # the global group
         self.cache = hybrid_cache(
             cfg.spec, self.num_pages, self.num_window_pages, self.page_size,
             np.dtype(cfg.compute_dtype or np.float32))
-        self.pages = PagePool(self.num_pages)
-        self.tables = np.full((cfg.slots, self.max_pages), -1, np.int32)
-        self.table_len = np.zeros(cfg.slots, np.int64)
-        self.reserved_for = np.zeros(cfg.slots, np.int64)
-        self.page_copies = 0
         self.win_pages = PagePool(self.num_window_pages)
         self.win_tables = np.full((cfg.slots, self.ring), -1, np.int32)
         # The logical page a column holds (-1: none).
@@ -113,11 +104,8 @@ class HybridEngine(InferenceEngine):
 
     def can_admit(self, need: int) -> bool:
         """Both groups have what a new slot reserves."""
-        return self.pages.available >= need \
+        return super().can_admit(need) \
             and self.win_pages.available >= self.ring
-
-    def reclaim_pages(self, need: int) -> bool:
-        return False  # no prefix entries to evict
 
     def reserve_pages(self, slot: int, n: int) -> None:
         super().reserve_pages(slot, n)
@@ -125,9 +113,8 @@ class HybridEngine(InferenceEngine):
         self.win_reserved[slot] += self.ring
         self.win_held[slot] = True
 
-    def _release_pages(self, pages) -> None:
-        for p in pages:  # no stored positions to reset
-            self.pages.decref(int(p))
+    def _pages_freed(self, pages) -> None:
+        pass  # no stored positions to reset
 
     def release_slot(self, slot: int) -> None:
         super().release_slot(slot)
@@ -258,14 +245,7 @@ class HybridEngine(InferenceEngine):
                 _bucket: int | None = None):
         """The base class's contract, except the logits: ``(next_token,
         logits np [1, vocab])`` of the block's last position."""
-        prompt = np.asarray(prompt, np.int32)
-        t = int(prompt.shape[0])
-        if base < 0 or base + t > self.config.capacity:
-            raise ValueError(
-                f"prefill block [base={base}, base+{t}) outside cache "
-                f"capacity {self.config.capacity}")
-        bucket = self.prefill_bucket(t) if _bucket is None else _bucket
-        assert bucket >= t, (bucket, t)
+        prompt, t, bucket = self._prefill_block(prompt, base, _bucket)
         with span("engine.upload", kind="prefill"):
             tokens = np.zeros((1, bucket), np.int32)
             tokens[0, :t] = prompt
@@ -287,17 +267,11 @@ class HybridEngine(InferenceEngine):
         with span("engine.upload", kind="decode"):
             lengths_np = np.asarray(lengths, np.int32)
             active_np = np.asarray(active, bool)
+            pb = self._decode_bucket(lengths_np, active_np, _pages)
             if _pages is None:
-                widest = 1
                 for s in np.nonzero(active_np)[0]:
                     at = int(lengths_np[s])
-                    self._ensure_rows(int(s), at + 1)
                     self._slide_window(int(s), at, at)
-                    widest = max(widest, int(self.table_len[s]))
-                pb = self.decode_page_bucket(widest)
-            else:
-                pb = _pages
-            self.last_attend_width = pb * self.page_size
             fn = self._decode_paged(pb)
             args = tuple(jnp.asarray(a) for a in (
                 np.asarray(last_tokens, np.int32), lengths_np,

@@ -2,28 +2,26 @@
 (ISSUE 18, ROADMAP item 5).
 
 :class:`CostModelEngine` implements the :class:`ServeEngine` contract
-with **no arrays**: it runs the *identical* host bookkeeping as the
-real engine — the same :class:`~ddl_tpu.serve.cache.PagePool`
-allocator, the same block tables, reservation accounting and CoW
-counters, the same :class:`~ddl_tpu.serve.prefix.PrefixIndex` — and
-replaces every device program with a deterministic token hash plus a
-per-phase *virtual time* charge (prefill per token, decode per tick,
-hand-off per page) fitted from the goodput plane's measured
-``time_in_seconds{phase=}`` (:func:`ddl_tpu.obs.goodput.phase_cost_fit`).
+with **no arrays**. Its host half is the real engines' own
+(:class:`~ddl_tpu.serve.host.EngineHost`: config check, page pool, block
+tables, reservations, prefix index, bucket ladders), inherited, with
+neither device hook filled in. What is written here is what stands in
+for the device: a deterministic token hash, the ``rows`` a dump's
+``pos`` is made from, and a per-phase *virtual time* charge (prefill
+per token, decode per tick, hand-off per page) fitted from the goodput
+plane's measured ``time_in_seconds{phase=}``
+(:func:`ddl_tpu.obs.goodput.phase_cost_fit`).
 
-Because every control decision in the serve stack reads only the host
-half of the engine (pressure, pages, block tables, prefix index, tick
-clock), a fleet running on cost-model engines replays the **identical
-controller event timeline and per-class shed/admit/requeue counts** as
-the real fleet — the tick-for-tick parity pin in tests/test_twin.py.
-What the twin does *not* reproduce is token VALUES (the hash stands in
-for the transformer; it is stable in ``(seed, request_id, position)``
-exactly like the real sampling key, so requeues and preemptions replay
-the same stream) and wall-clock time (virtual seconds accumulate in
-:meth:`CostModelEngine.virtual_time`, never in the scheduler's
-``perf_counter`` clock — which is why the real-engine paths stay
-byte-identical).  This is what lets 100–1000-replica fleets replay
-million-request traces on a CPU box in seconds.
+Every control decision in the serve stack reads only that host half, so
+a fleet on cost-model engines replays the **identical controller event
+timeline and per-class shed/admit/requeue counts** as the real fleet —
+the tick-for-tick parity pin in tests/test_twin.py. What the twin does
+*not* reproduce is token VALUES (the hash is stable in ``(seed,
+request_id, position)`` exactly like the real sampling key, so requeues
+and preemptions replay the same stream) and wall-clock time (virtual
+seconds accumulate in :meth:`CostModelEngine.virtual_time`, never in the
+scheduler's ``perf_counter`` clock).  This is what lets 100–1000-replica
+fleets replay million-request traces on a CPU box in seconds.
 """
 from __future__ import annotations
 
@@ -34,8 +32,7 @@ from typing import Mapping
 import numpy as np
 
 from ..ops.kv_cache import PAD_POS
-from .cache import PagePool
-from .prefix import PrefixIndex
+from .host import EngineHost
 
 __all__ = ["CostModel", "CostModelEngine", "sim_engine_factory"]
 
@@ -108,110 +105,30 @@ class _SimDevice:
         return "SimDevice(cost-model)"
 
 
-class CostModelEngine:
-    """No-array :class:`ServeEngine`: identical host bookkeeping,
-    virtual time instead of device time, hashed tokens instead of a
-    transformer.  Accepts (and ignores) ``params``/``placed_params`` so
-    the router's one-checkpoint replica wiring works unchanged."""
+class CostModelEngine(EngineHost):
+    """No-array :class:`ServeEngine` (the module docstring): virtual time
+    instead of device time, hashed tokens instead of a transformer.
+    Accepts (and ignores) ``params``/``placed_params`` so the router's
+    one-checkpoint replica wiring works unchanged."""
 
     kind = "sim"
-    handoff = True
-    last_counters: dict = {}  # the real engines' per-call counters: none
+    refuses = (
+        (lambda c: c.speculate_k > 0,
+         "speculate_k={c.speculate_k} has no cost-model implementation: "
+         "draft acceptance depends on token CONTENT, which the twin does "
+         "not model — run speculative configs on the real engine"),
+    )
 
     def __init__(self, config, params=None, *, placed_params=None,
                  cost: CostModel | None = None):
-        if params is not None and placed_params is not None:
-            raise ValueError(
-                "pass params (host tree, placed here) OR placed_params "
-                "(an already-placed tree to share), not both"
-            )
-        # Loud-ctor discipline, mirrored from InferenceEngine: a config
-        # the real engine would reject must fail identically here — a
-        # twin that accepts an unservable geometry would "evaluate"
-        # policies no real fleet can run.
-        spec = config.spec
-        if config.slots < 1 or config.capacity < 2:
-            raise ValueError(
-                f"need slots >= 1 and capacity >= 2, got "
-                f"{config.slots} / {config.capacity}"
-            )
-        if not 0 <= config.top_k <= spec.vocab:
-            raise ValueError(
-                f"top_k must be in [0, vocab={spec.vocab}], got "
-                f"{config.top_k}"
-            )
-        if config.prefix_slots < 0:
-            raise ValueError(
-                f"prefix_slots must be >= 0, got {config.prefix_slots}"
-            )
-        ck = config.prefill_chunk
-        if ck and (ck < 8 or ck & (ck - 1)):
-            raise ValueError(
-                f"prefill_chunk must be 0 or a power of two >= 8, got {ck}"
-            )
-        if config.prefill_budget:
-            if not ck:
-                raise ValueError(
-                    "prefill_budget requires prefill_chunk (the budget "
-                    "meters chunk interleaving; whole-prompt prefill "
-                    "ignores it silently otherwise)"
-                )
-            if config.prefill_budget < ck:
-                raise ValueError(
-                    f"prefill_budget ({config.prefill_budget}) below "
-                    f"prefill_chunk ({ck}) could never start a chunk"
-                )
-        ps = config.page_size
-        if ps < 0 or (ps and ps & (ps - 1)):
-            raise ValueError(
-                f"page_size must be 0 (contiguous) or a power of two, "
-                f"got {ps} (pages tile the capacity and the row->page "
-                "split is a shift/mask)"
-            )
-        if config.num_pages and not ps:
-            raise ValueError(
-                f"num_pages ({config.num_pages}) requires page_size > 0 "
-                "(the contiguous layout has no page pool)"
-            )
-        if config.num_pages < 0:
-            raise ValueError(f"num_pages must be >= 0, got {config.num_pages}")
-        if config.speculate_k > 0:
-            raise ValueError(
-                f"speculate_k={config.speculate_k} has no cost-model "
-                "implementation: draft acceptance depends on token "
-                "CONTENT, which the twin does not model — run "
-                "speculative configs on the real engine"
-            )
-        self.paged = ps > 0
-        if self.paged:
-            if config.capacity % ps:
-                raise ValueError(
-                    f"capacity ({config.capacity}) must be a multiple of "
-                    f"page_size ({ps}) — the block table holds whole pages"
-                )
-            self.page_size = ps
-            self.max_pages = config.capacity // ps
-            self.num_pages = config.num_pages or config.slots * self.max_pages
-            if self.num_pages < config.slots:
-                raise ValueError(
-                    f"num_pages ({self.num_pages}) below slots "
-                    f"({config.slots}) — every admitted slot needs at "
-                    "least one page; the pool could never fill the batch"
-                )
-        else:
-            self.page_size = self.max_pages = self.num_pages = 0
-        self.config = config
+        self._configure(config, params, placed_params)
         self.cost = cost if cost is not None else CostModel()
         self.params = placed_params  # opaque; replicas may share None
-        self.compile_hook = None
-        self.last_attend_width = config.capacity
         # One fake CPU 'device' behind the same mesh surface the
         # observability plane reads (.devices.flat / .devices.size).
         self.mesh = types.SimpleNamespace(
             devices=np.array([_SimDevice()], dtype=object)
         )
-        self.pool = None
-        self.prefix: PrefixIndex | None = None
         self.reset()
 
     # -- state -------------------------------------------------------------
@@ -222,21 +139,8 @@ class CostModelEngine:
         virtual-time ledger resets too — warmup resets the engine before
         the timed run, so reported virtual seconds cover exactly the
         run, matching the wall-clock methodology."""
-        S = self.config.slots
-        if self.paged:
-            self.pages = PagePool(self.num_pages)
-            self.tables = np.full((S, self.max_pages), -1, np.int32)
-            self.table_len = np.zeros(S, np.int64)
-            self.reserved_for = np.zeros(S, np.int64)
-            self.page_copies = 0
-            if self.config.prefix_slots > 0:
-                self.prefix = PrefixIndex(
-                    self.config.prefix_slots,
-                    on_evict=lambda e: self._release_pages(e.pages),
-                )
-        elif self.config.prefix_slots > 0:
-            self.prefix = PrefixIndex(self.config.prefix_slots)
-        self.rows = np.zeros(S, np.int64)  # resident rows, for dump pos
+        self._reset_host()
+        self.rows = np.zeros(self.config.slots, np.int64)  # for a dump's pos
         self.virtual = {"prefill": 0.0, "decode": 0.0, "handoff": 0.0}
 
     def virtual_time(self) -> dict:
@@ -247,77 +151,25 @@ class CostModelEngine:
         out["total"] = float(sum(self.virtual.values()))
         return out
 
-    # -- paged page management (identical host half) ------------------------
-
-    def pages_needed(self, rows: int) -> int:
-        return -(-rows // self.page_size)
-
-    def reserve_pages(self, slot: int, n: int) -> None:
-        self.pages.reserve(n)
-        self.reserved_for[slot] += n
-
-    def can_admit(self, need: int) -> bool:
-        return self.pages.available >= need
-
-    def reclaim_pages(self, need: int) -> bool:
-        def frees(e) -> bool:
-            return any(int(self.pages.refs[int(p)]) == 1
-                       for p in set(e.pages))
-
-        while self.pages.available < need:
-            if self.prefix is None or self.prefix.evict_lru(frees) is None:
-                return False
-        return True
-
-    def _map_page(self, slot: int) -> int:
-        if self.reserved_for[slot] > 0:
-            self.reserved_for[slot] -= 1
-            self.pages.unreserve(1)
-        elif self.pages.available < 1:
-            raise RuntimeError(
-                f"slot {slot}: page pool exhausted (free "
-                f"{self.pages.free}, reserved {self.pages.reserved}) — "
-                "admission must reserve before the slot grows"
-            )
-        page = self.pages.alloc()
-        t = int(self.table_len[slot])
-        self.tables[slot, t] = page
-        self.table_len[slot] = t + 1
-        return page
-
-    def _ensure_rows(self, slot: int, rows: int) -> None:
-        need = self.pages_needed(rows)
-        if need > self.max_pages:
-            raise ValueError(
-                f"slot {slot}: {rows} rows need {need} pages, table "
-                f"reach is {self.max_pages} pages "
-                f"({self.config.capacity} rows)"
-            )
-        while int(self.table_len[slot]) < need:
-            self._map_page(slot)
-
-    def _release_pages(self, pages) -> None:
-        # Pure refcount half of the real engine's release — a freed sim
-        # page has no device pos rows to PAD_POS-reset.
-        for p in pages:
-            self.pages.decref(int(p))
+    # -- the twin's own beside the shared host half: resident rows -----------
 
     def release_slot(self, slot: int) -> None:
-        if not self.paged:
-            raise RuntimeError(
-                "release_slot needs the paged KV layout (page_size > 0) "
-                "— contiguous slots free by pos masking, not page return"
-            )
-        n = int(self.table_len[slot])
-        pages = [int(p) for p in self.tables[slot, :n]]
-        self.tables[slot, :] = -1
-        self.table_len[slot] = 0
-        left = int(self.reserved_for[slot])
-        if left:
-            self.pages.unreserve(left)
-            self.reserved_for[slot] = 0
+        super().release_slot(slot)
         self.rows[slot] = 0
-        self._release_pages(pages)
+
+    def prefix_fetch(self, entry_id: int, n: int, slot: int) -> int:
+        if self.paged:
+            copied = super().prefix_fetch(entry_id, n, slot)
+        else:  # no rows to copy: pin the entry, as a real engine does
+            self._pin(entry_id)
+            copied = n
+        self.rows[slot] = n
+        return copied
+
+    def prefix_store(self, prompt, slot: int) -> bool:
+        if self.paged:
+            return super().prefix_store(prompt, slot)
+        return self.prefix.insert(np.asarray(prompt, np.int32)) is not None
 
     # -- cross-replica hand-off --------------------------------------------
 
@@ -329,12 +181,7 @@ class CostModelEngine:
         matches (``k.shape[1] == pos.shape[0]``, the shape invariant
         the preemption pin asserts).  Charges hand-off virtual time per
         page — one dump+load pair is one hand-off."""
-        if not self.paged:
-            raise RuntimeError(
-                "dump_slot_pages needs the paged KV layout (page_size > "
-                "0) — the contiguous ring has no slot-independent pages "
-                "to hand off"
-            )
+        self._need_pages("dump_slot_pages")
         n = int(self.table_len[slot])
         ps = self.page_size
         rows = int(self.rows[slot])
@@ -350,10 +197,7 @@ class CostModelEngine:
         return k, v, pos
 
     def load_slot_pages(self, slot: int, k, v, pos) -> list[int]:
-        if not self.paged:
-            raise RuntimeError(
-                "load_slot_pages needs the paged KV layout (page_size > 0)"
-            )
+        self._need_pages("load_slot_pages")
         n = int(k.shape[1])
         mapped = []
         for _ in range(n):
@@ -363,110 +207,11 @@ class CostModelEngine:
         ))
         return mapped
 
-    def alias_slot_pages(self, dst_slot: int, src_slot: int,
-                         rows: int) -> int:
-        if not self.paged:
-            raise RuntimeError(
-                "alias_slot_pages needs the paged KV layout "
-                "(page_size > 0) — contiguous slots have no pages to "
-                "alias"
-            )
-        if int(self.table_len[dst_slot]) or int(self.reserved_for[dst_slot]):
-            raise RuntimeError(
-                f"alias_slot_pages into non-empty slot {dst_slot} "
-                "(lanes must be free slots)"
-            )
-        self._ensure_rows(src_slot, rows)
-        n = int(self.table_len[src_slot])
-        for i in range(n):
-            page = int(self.tables[src_slot, i])
-            self.pages.incref(page)
-            self.tables[dst_slot, i] = page
-        self.table_len[dst_slot] = n
-        self.rows[dst_slot] = rows
-        return n
-
-    # -- prefix cache -------------------------------------------------------
-
-    def prefix_fetch(self, entry_id: int, n: int, slot: int) -> int:
-        e = self.prefix.entry(entry_id)
-        if self.paged:
-            ps = self.page_size
-            shared, tail = n // ps, n % ps
-            if int(self.table_len[slot]):
-                raise RuntimeError(
-                    f"prefix_fetch into non-empty slot {slot} (admission "
-                    "maps shared pages into a fresh table only)"
-                )
-            for i in range(shared):
-                page = int(e.pages[i])
-                self.pages.incref(page)
-                self.tables[slot, i] = page
-            self.table_len[slot] = shared
-            copied = 0
-            if tail:
-                self._map_page(slot)
-                self.page_copies += 1
-                copied = tail
-            self.rows[slot] = n
-            self.prefix.touch(entry_id)
-            self.prefix.acquire(entry_id)
-            return copied
-        self.rows[slot] = n
-        self.prefix.touch(entry_id)
-        self.prefix.acquire(entry_id)
-        return n
-
-    def prefix_release(self, entry_id: int) -> None:
-        self.prefix.release(entry_id)
-
-    def prefix_store(self, prompt, slot: int) -> bool:
-        prompt = np.asarray(prompt, np.int32)
-        if self.paged:
-            full = int(prompt.shape[0]) // self.page_size
-            if full < 1:
-                return False
-            pages = [int(p) for p in self.tables[slot, :full]]
-            got = self.prefix.insert(
-                prompt[: full * self.page_size], pages=pages
-            )
-            if got is None:
-                return False
-            for page in pages:
-                self.pages.incref(page)
-            return True
-        return self.prefix.insert(prompt) is not None
-
     # -- host API ----------------------------------------------------------
-
-    def prefill_bucket(self, prompt_len: int) -> int:
-        if not 1 <= prompt_len <= self.config.capacity:
-            raise ValueError(
-                f"prompt length {prompt_len} outside [1, capacity="
-                f"{self.config.capacity}]"
-            )
-        b = 8
-        while b < prompt_len:
-            b *= 2
-        return min(b, self.config.capacity)
-
-    def decode_page_bucket(self, pages: int) -> int:
-        b = 1
-        while b < pages:
-            b *= 2
-        return min(b, self.max_pages)
 
     def prefill(self, prompt, *, slot: int, request_id: int, base: int = 0,
                 _bucket: int | None = None):
-        prompt = np.asarray(prompt, np.int32)
-        t = int(prompt.shape[0])
-        if base < 0 or base + t > self.config.capacity:
-            raise ValueError(
-                f"prefill block [base={base}, base+{t}) outside cache "
-                f"capacity {self.config.capacity}"
-            )
-        bucket = self.prefill_bucket(t) if _bucket is None else _bucket
-        assert bucket >= t, (bucket, t)
+        _, t, _ = self._prefill_block(prompt, base, _bucket)
         if self.paged:
             self._ensure_rows(slot, base + t)
         self.rows[slot] = max(int(self.rows[slot]), base + t)
@@ -483,15 +228,7 @@ class CostModelEngine:
         active_np = np.asarray(active, bool)
         rids = np.asarray(request_ids, np.int64)
         if self.paged:
-            if _pages is None:
-                widest = 1
-                for s in np.nonzero(active_np)[0]:
-                    self._ensure_rows(int(s), int(lengths_np[s]) + 1)
-                    widest = max(widest, int(self.table_len[s]))
-                pb = self.decode_page_bucket(widest)
-            else:
-                pb = _pages
-            self.last_attend_width = pb * self.page_size
+            self._decode_bucket(lengths_np, active_np, _pages)
         if _pages is None:
             # One batched step = one decode tick of virtual time; an
             # all-inactive warmup probe (_pages forced) charges nothing

@@ -344,14 +344,25 @@ def test_seq_guard_converges_with_injected_nan():
 def test_seq_guard_rollback_reseeds_to_checkpoint():
     """Escalation: K consecutive bad steps roll back to the last good
     checkpoint; the transient fault heals and the replayed data stream
-    (re-seeded by step position) finishes BIT-IDENTICAL to the clean
-    run — the strongest possible rollback-correctness pin."""
+    (re-seeded by step position) finishes BIT-IDENTICAL to the same
+    guarded run without the fault — the strongest possible
+    rollback-correctness pin: same program, same batches, same order.
+    The UNGUARDED clean run steps through another program (no finite
+    check, no select), and XLA on the CPU promises no bitwise equality
+    across programs (every guarded run is 3e-8 from it, fault or none;
+    PR 30): against it the params are held to 1e-5, the accuracy
+    exactly."""
     import tempfile
 
     ds = _copy_ds(8)
     cfg = SeqConfig(epochs=1, eval_every=1, batch_size=16, num_workers=1,
                     scheme="full", spec=SPEC, seed=3)
     clean = SeqTrainer(cfg, ds).train(log=quiet)
+    guarded = SeqTrainer(cfg, ds).train(
+        log=quiet, checkpoint_dir=tempfile.mkdtemp(), checkpoint_every=1,
+        max_bad_steps=2,
+    )
+    assert guarded.rollbacks == 0 and guarded.skipped_steps == 0
     d = tempfile.mkdtemp()
     inj = FaultInjector(FaultSpec(kind="nan_grads", step=1, count=2))
     r = SeqTrainer(cfg, ds).train(
@@ -359,7 +370,8 @@ def test_seq_guard_rollback_reseeds_to_checkpoint():
         max_bad_steps=2, fault_injector=inj,
     )
     assert r.rollbacks == 1 and r.skipped_steps == 2
-    _assert_trees_equal(clean.params, r.params)
+    _assert_trees_equal(guarded.params, r.params)
+    _assert_trees_equal(clean.params, r.params, rtol=0, atol=1e-5)
     assert r.final_accuracy == clean.final_accuracy
 
 

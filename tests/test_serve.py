@@ -459,23 +459,34 @@ def test_kv_attend_all_pad_rows_is_finite_and_length_stable():
 @pytest.mark.parametrize("chunk", [8, 16])
 def test_chunked_prefill_logits_exactly_equal_one_shot(chunk):
     """Acceptance pin: prefilling a prompt in fixed chunks (base
-    offsets) produces logits EXACTLY equal — bitwise, not tolerance —
-    to the one-shot prefill of the same prompt, partial final chunk
-    included. Exactness is what lets chunking default to 'safe to turn
-    on': the token stream cannot move."""
+    offsets) produces the logits of the one-shot prefill of the same
+    prompt, partial final chunk included, and the same sampled token:
+    chunking cannot move the token stream. Through the one-shot's OWN
+    program (every chunk forced to its bucket) the logits are EXACTLY
+    equal — bitwise: chunking itself adds nothing. At a chunk's natural
+    bucket (8 / 16 against the one-shot's 32) the programs differ in
+    shape, and XLA on the CPU promises no bitwise equality across
+    shapes: a one-shot prefill at bucket 64 is as far from the one at 32
+    (1.19e-6, PR 30), so there the logits are held to 1e-5."""
     eng = InferenceEngine(ServeConfig(spec=SPEC, slots=1, capacity=64))
     prompt = synthesize_prompts(num=1, min_len=21, max_len=21,
                                 vocab=SPEC.vocab, seed=14)[0]
     tok_full, logits_full = eng.prefill(prompt, slot=0, request_id=3)
-    eng.reset()
-    got = []
-    tok_last = None
-    for base in range(0, len(prompt), chunk):
-        tok_last, lg = eng.prefill(prompt[base:base + chunk], slot=0,
-                                   request_id=3, base=base)
-        got.append(lg)
-    np.testing.assert_array_equal(np.concatenate(got, axis=0), logits_full)
-    assert tok_last == tok_full  # same sampled element p
+
+    def chunked(bucket):
+        eng.reset()
+        got = []
+        for base in range(0, len(prompt), chunk):
+            tok_last, lg = eng.prefill(prompt[base:base + chunk], slot=0,
+                                       request_id=3, base=base,
+                                       _bucket=bucket)
+            got.append(lg)
+        assert tok_last == tok_full  # same sampled element p
+        return np.concatenate(got, axis=0)
+
+    np.testing.assert_array_equal(
+        chunked(eng.prefill_bucket(len(prompt))), logits_full)
+    np.testing.assert_allclose(chunked(None), logits_full, rtol=0, atol=1e-5)
 
 
 def test_prefix_copy_then_tail_prefill_matches_full_prefill():

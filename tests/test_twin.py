@@ -41,6 +41,8 @@ from ddl_tpu.resilience.faults import (
 from ddl_tpu.serve import (
     AutoscaleConfig,
     FleetController,
+    HybridEngine,
+    InferenceEngine,
     Request,
     Router,
     Scheduler,
@@ -153,16 +155,52 @@ def test_twin_run_renders_through_analyze_report():
     assert rep["incidents"]["scale_out"] >= 1
 
 
-def test_sim_engine_satisfies_serve_engine_protocol():
-    """The control-plane contract: both engines satisfy the
-    runtime-checkable ServeEngine protocol and self-report their kind
-    (the twin can never masquerade — engine_kind defaults to real only
-    for engines predating the interface)."""
-    eng = CostModelEngine(ServeConfig(spec=SPEC, slots=1, capacity=32,
-                                      page_size=8, num_pages=8))
+# Every attribute and method the scheduler, the router, the controller,
+# the disagg coordinator and cli.py read of an engine (the scheduler SETS
+# ledger_hook and reads it nowhere).
+_ENGINE_SURFACE = (
+    "kind", "handoff", "config", "mesh", "params", "compile_hook",
+    "last_attend_width", "last_counters",
+    "paged", "page_size", "max_pages", "num_pages",
+    "pages", "tables", "table_len", "reserved_for", "page_copies", "prefix",
+    "prefill", "decode", "prefill_bucket", "decode_page_bucket", "reset",
+    "pages_needed", "reserve_pages", "can_admit", "reclaim_pages",
+    "release_slot", "dump_slot_pages", "load_slot_pages",
+    "alias_slot_pages", "handoff_bytes",
+    "prefix_fetch", "prefix_release", "prefix_store",
+)
+
+
+@pytest.mark.parametrize("family", ["sim", "dense", "hybrid"])
+def test_sim_engine_satisfies_serve_engine_protocol(family):
+    """The control-plane contract: every engine satisfies the
+    runtime-checkable ServeEngine protocol, carries every name the
+    control plane reads (a method given to one engine and not to the
+    twin, as ``handoff_bytes`` was until PR 30, fails here and not in a
+    crash replay), takes its host half from the one ``EngineHost``
+    rather than a copy, and self-reports its kind (the twin can never
+    masquerade — engine_kind defaults to real only for engines
+    predating the interface)."""
+    from ddl_tpu.models.hybrid import HybridSpec
+
+    cls, spec = {"sim": (CostModelEngine, SPEC),
+                 "dense": (InferenceEngine, SPEC),
+                 "hybrid": (HybridEngine, HybridSpec())}[family]
+    eng = cls(ServeConfig(spec=spec, slots=1, capacity=32, page_size=8,
+                          num_pages=8))
     assert isinstance(eng, ServeEngine)
-    assert engine_kind(eng) == "sim"
+    assert [n for n in _ENGINE_SURFACE if not hasattr(eng, n)] == []
+    assert engine_kind(eng) == ("sim" if family == "sim" else "real")
     assert engine_kind(object()) == "real"  # pre-interface default
+    # Written once: the bookkeeping is EngineHost's own function in every
+    # class; an engine overrides only to add state of its own around it.
+    from ddl_tpu.serve.host import EngineHost
+
+    for name in ("pages_needed", "reclaim_pages", "_map_page",
+                 "_ensure_rows", "_release_pages", "prefill_bucket",
+                 "decode_page_bucket", "handoff_bytes", "prefix_release"):
+        assert getattr(cls, name) is getattr(EngineHost, name), name
+    assert eng.handoff_bytes(2) > 0
 
 
 def test_sim_engine_scheduler_roundtrip_and_virtual_time():
