@@ -353,6 +353,7 @@ def apply_lm_paged(
     row_reduce=None,
     pool_k_scale: jax.Array | None = None,
     pool_v_scale: jax.Array | None = None,
+    platform: str | None = None,
 ) -> tuple[jax.Array, ...]:
     """Incremental forward against the PAGED (block-table) KV pool — the
     same layer math as :func:`apply_lm_cached`, with the per-slot ring
@@ -370,6 +371,25 @@ def apply_lm_paged(
     positions travel with the rows (``table_positions``) — so
     ``ops.kv_cache.attend`` runs UNCHANGED and the masking/eviction
     semantics are exactly the contiguous cache's.
+
+    **When no view is gathered** (ISSUE 31). It is one algorithm, causal
+    attention over a block table, whose best form depends on what the
+    shapes show, decided here at trace time: with ONE query a slot (``T
+    == 1``: a decode program), a pool that is not int8 (no scale
+    planes), widths ``ops.paged_attention.kernel_accepts`` (``head_dim``
+    whole 128-lane tiles, the local heads whole 8-row tiles) and
+    ``platform == "tpu"``, each layer's attention is
+    ``ops.paged_attention.paged_decode_attention``: a Pallas kernel that
+    reads each slot's mapped pages where they lie in the stack, through
+    the table, under ``attend``'s own rule (``k_pos <= q_pos`` from the
+    page's positions), and writes no view. Everything else — whole-
+    prompt and chunked prefill, speculation's verifier (``T > 1``), the
+    int8 pool, narrow heads, every CPU run — gathers the views and
+    calls ``attend`` as before, its program byte-identical to what it
+    was. ``platform`` is the platform of the devices the program will
+    run on (``mesh.devices.flat[0].platform``: what the engine passes),
+    ``None`` falling back to ``jax.default_backend()``, as
+    ``ops.attention.flash_attention_bthd`` resolves it.
 
     **The stacked pool is updated in place** (ISSUE 29): layer ``i``
     never leaves the ``[L, pages, ...]`` arrays. Its fresh rows scatter
@@ -405,7 +425,7 @@ def apply_lm_paged(
     the layer math are untouched, and a row read back dequantizes to
     the same values on every reader (sharing/hand-off stay bit-exact
     because the bytes themselves travel)."""
-    from ..ops import kv_cache
+    from ..ops import kv_cache, paged_attention
 
     if (pool_k_scale is None) != (pool_v_scale is None):
         raise ValueError("pass both pool_k_scale and pool_v_scale or neither")
@@ -414,10 +434,17 @@ def apply_lm_paged(
         params = jax.tree.map(lambda p: p.astype(compute_dtype), dict(params))
     h = params["embed"][tokens]  # [B, T, E]
     b, t, _ = h.shape
+    if platform is None:
+        platform = jax.default_backend()
+    in_place = (
+        t == 1 and not quantized and platform == "tpu"
+        and paged_attention.kernel_accepts(
+            pool_k.shape[3], spec.head_dim, pool_k.shape[2]))
     pool_pos = kv_cache.write_rows_flat(
         pool_pos, positions.astype(pool_pos.dtype), flat_rows
     )
-    k_pos = kv_cache.table_positions(pool_pos, table)  # [B, TP * page]
+    if not in_place:
+        k_pos = kv_cache.table_positions(pool_pos, table)  # [B, TP * page]
     heads = lambda a: a.reshape(b, t, -1, spec.head_dim)
     reduce_ = row_reduce if row_reduce is not None else (lambda x: x)
 
@@ -450,11 +477,17 @@ def apply_lm_paged(
                 pool_k, k.astype(pool_k.dtype), flat_rows, layer=i)
             pool_v = kv_cache.write_rows_flat(
                 pool_v, v.astype(pool_v.dtype), flat_rows, layer=i)
-            k_view = kv_cache.gather_pages(
-                pool_k, table, layer=i).astype(q.dtype)
-            v_view = kv_cache.gather_pages(
-                pool_v, table, layer=i).astype(q.dtype)
-        a = kv_cache.attend(q, k_view, v_view, positions, k_pos)
+            if not in_place:
+                k_view = kv_cache.gather_pages(
+                    pool_k, table, layer=i).astype(q.dtype)
+                v_view = kv_cache.gather_pages(
+                    pool_v, table, layer=i).astype(q.dtype)
+        if in_place:
+            a = paged_attention.paged_decode_attention(
+                q[:, 0], pool_k, pool_v, pool_pos, table, positions[:, 0],
+                i)[:, None]
+        else:
+            a = kv_cache.attend(q, k_view, v_view, positions, k_pos)
         h = h + reduce_(a.reshape(b, t, -1) @ blk["wo"])
         x = _layernorm(h, blk["ln2_g"], blk["ln2_b"])
         h = h + reduce_(

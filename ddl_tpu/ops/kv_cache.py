@@ -106,6 +106,18 @@ def copy_prefix(
 #   (verified on this XLA:CPU before building; pinned in
 #   tests/test_serve_paged.py).
 #
+# **When a view is gathered, and when it is not** (ISSUE 31). The gathered
+# view is the general form: any number of queries a slot, the int8 pool's
+# dequantised rows, any widths, any backend. A dense DECODE program on a
+# TPU (one query a slot, a pool that is not int8, ``head_dim`` whole
+# 128-lane tiles and the local heads whole 8-row tiles) gathers nothing:
+# ``ops.paged_attention.paged_decode_attention`` walks the same table,
+# reads each mapped page where it lies in the stack and applies
+# :func:`attend`'s rule (``k_pos <= q_pos``, from the page's own row of
+# the positions) in the kernel. ``models.transformer.apply_lm_paged``
+# chooses at trace time from the shapes; the pool, its layout and every
+# writer here are the same for both.
+#
 # **Layer offset** (ISSUE 29). The dense pool's K/V leaves are STACKED,
 # ``[L, pages, page_size, ...]``, and a forward touches layer ``i`` of
 # them without ever taking ``pool[i]`` out (a 134-MB copy a layer on the

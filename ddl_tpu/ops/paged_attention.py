@@ -1,0 +1,206 @@
+"""Decode attention that reads the dense paged pool's pages in place.
+
+A dense paged DECODE program (one query a slot) used to gather every
+slot's pages into ``[B, TP * page_size, H, D]`` views of K and of V, a
+layer at a time, at the WIDEST slot's page bucket, and
+``ops.kv_cache.attend`` then read the copies back: 84% of
+``serve-1b-closed32``'s decode program (PERF.md section 6, PR 31). This
+kernel walks each slot's block table instead and reads the pages where
+they lie in the stacked ``[L, pages, page_size, H, D]`` pool: the bytes
+moved follow the pages RESIDENT, no view is written, and the pool keeps
+its layout (every other program reads and writes it as before).
+
+How it reads a page. The stack is viewed as ``[L * pages, page_size * H,
+D]``: leading axes merged, and rows and heads merged into one axis, which
+on the chip is the same bytes when ``H`` is a whole number of 8-row tiles
+(the tiled layout of ``[..., H, D]`` puts the heads on the sublanes; the
+view ``[..., page_size, H * D]`` is NOT the same bytes and costs a copy
+of the whole pool). One page is then a ``[page_size * H, D]`` matrix
+whose row ``s * H + h`` is key ``s`` of head ``h``, and the MXU takes all
+heads at once: ``q [H, D] x page^T -> [H, page_size * H]``, of which
+entry ``(h, s * H + h')`` counts where ``h == h'``. The other seven
+eighths are masked like any key the query may not see: the MXU's time is
+the page passing through it either way, and no head is ever sliced out
+of the sublanes.
+
+Which keys count is ``ops.kv_cache.attend``'s rule, from the page's own
+positions: ``k_pos <= q_pos`` (``PAD_POS`` rows never), so shared prefix
+pages, stale rows of a reused page and a table wider than the slot
+behave as in the gathered path. A slot with no mapped page gives zeros.
+Online softmax over pages, fp32 running max, sum and accumulator.
+"""
+
+from __future__ import annotations
+
+import functools
+import math
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from .kv_cache import _MASKED
+
+# Pages a grid step reads, each through its own BlockSpec of the same
+# pool. Timed on one v5e at ``serve-1b-closed32``'s shape (32 slots, 286
+# of 1,024 table entries mapped, 150 MB to read; PERF.md section 6, PR
+# 31): a layer's call took 0.293 ms at 2 pages a step and at 4, 0.304 at
+# 8, and 0.306 at 2 with a softmax update of its own for each page; the
+# same grid copying pages and computing nothing 0.248, computing on one
+# page and copying none 0.173; the gathered views and ``attend`` 1.916.
+PAGES_PER_STEP = 2
+
+
+def kernel_accepts(num_heads: int, head_dim: int, page_size: int) -> bool:
+    """Whether :func:`paged_decode_attention`'s tiles fit these widths:
+    ``head_dim`` whole 128-lane tiles, heads whole 8-row tiles (so rows
+    and heads merge for free), and a page of at least one lane tile of
+    scores."""
+    return (head_dim % 128 == 0 and num_heads % 8 == 0
+            and (page_size * num_heads) % 128 == 0)
+
+
+def _kernel(fetch_ref, mapped_ref, qpos_ref, layer_ref, q_ref, *refs,
+            group: int, num_heads: int, scale: float):
+    del fetch_ref, layer_ref  # the index maps' operands
+    pos_refs, k_refs, v_refs = (refs[i * group:(i + 1) * group]
+                                for i in range(3))
+    o_ref, m_ref, l_ref, acc_ref = refs[3 * group:]
+    b, j = pl.program_id(0), pl.program_id(1)
+
+    @pl.when(j == 0)
+    def _():
+        m_ref[...] = jnp.full_like(m_ref, _MASKED)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    q = q_ref[0]                                            # [H, D]
+    cols = k_refs[0].shape[1]                               # page_size * H
+    own_head = (
+        jax.lax.broadcasted_iota(jnp.int32, (num_heads, cols), 1) % num_heads
+        == jax.lax.broadcasted_iota(jnp.int32, (num_heads, cols), 0))
+    at = (b * pl.num_programs(1) + j) * group
+    mapped = [mapped_ref[at + g] > 0 for g in range(group)]
+
+    # One softmax update for the step's pages: their products are
+    # independent, so the MXU takes them back to back. An unmapped page
+    # of a step that has a mapped one holds the page its input fetched
+    # last, and is masked whole.
+    @pl.when(functools.reduce(jnp.logical_or, mapped))
+    def _():
+        oks, scores = [], []
+        for g in range(group):
+            # [S * H, D], in the query's dtype as the gathered view is
+            k = k_refs[g][0].astype(q.dtype)
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale  # [H, S * H]
+            ok = own_head & (pos_refs[g][0] <= qpos_ref[b]) & mapped[g]
+            oks.append(ok)
+            scores.append(jnp.where(ok, s, _MASKED))
+        m_prev = m_new = m_ref[...]
+        for s in scores:
+            m_new = jnp.maximum(m_new, s.max(axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        l, acc = alpha * l_ref[...], alpha * acc_ref[...]
+        for g in range(group):
+            p = jnp.where(oks[g], jnp.exp(scores[g] - m_new), 0.0)
+            l = l + p.sum(axis=-1, keepdims=True)
+            acc = acc + jnp.dot(
+                p.astype(q.dtype), v_refs[g][0].astype(q.dtype),
+                preferred_element_type=jnp.float32)
+        m_ref[...], l_ref[...], acc_ref[...] = m_new, l, acc
+
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _():
+        l = l_ref[...]
+        o_ref[0] = jnp.where(l > 0, acc_ref[...] / l, 0.0).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("scale", "pages_per_step", "interpret"))
+def paged_decode_attention(
+    q: jax.Array,
+    pool_k: jax.Array,
+    pool_v: jax.Array,
+    pool_pos: jax.Array,
+    table: jax.Array,
+    q_pos: jax.Array,
+    layer: int | jax.Array,
+    *,
+    scale: float | None = None,
+    pages_per_step: int = PAGES_PER_STEP,
+    interpret: bool = False,
+) -> jax.Array:
+    """Causal attention of one query a slot against layer ``layer`` of
+    the stacked pool, through the block table: ``q [B, H, D]`` at
+    positions ``q_pos [B]``; ``pool_k``/``pool_v [L, P, S, H, D]``;
+    ``pool_pos [P, S]`` the position each row of a page holds; ``table
+    [B, TP]`` each slot's page ids (``-1`` = unmapped). Returns ``[B, H,
+    D]`` in ``q``'s dtype: what ``attend(q[:, None], gather_pages(pool_k,
+    table, layer).astype(q.dtype), gather_pages(pool_v, table,
+    layer).astype(q.dtype), q_pos[:, None], table_positions(pool_pos,
+    table))[:, 0]`` gives, to rounding (fp32 scores straight from the
+    product, online softmax).
+
+    ``layer`` reaches the kernel as a scalar operand and the function is
+    jitted, so the calls of one program are one traced function lowered
+    once: lowering a Pallas kernel costs the host a tenth of a second,
+    and sixteen of them in each of seven decode buckets showed as 20 s
+    of ``setup_s``. A grid step reads ``pages_per_step`` pages of a slot;
+    a step or a page past the slot's last mapped one names the page
+    fetched last (no new copy is issued) and skips its compute."""
+    b, h, d = q.shape
+    num_layers, num_pages, page_size = pool_k.shape[:3]
+    tp = table.shape[1]
+    group = math.gcd(pages_per_step, tp)
+    steps = tp // group
+    if scale is None:
+        scale = 1.0 / math.sqrt(d)
+    cols = page_size * h
+
+    # What each of the ``group`` page inputs fetches at each step: the
+    # table's page where it is mapped, else the page that input fetched
+    # last (an unchanged block index issues no copy).
+    at = jnp.arange(b * tp, dtype=jnp.int32).reshape(b * steps, group)
+    mapped = (table >= 0).reshape(b * steps, group)
+    last = jax.lax.cummax(jnp.where(mapped, at, -1), axis=0)
+    fetch = jnp.where(
+        last >= 0, table.reshape(-1)[jnp.maximum(last, 0)], 0).reshape(-1)
+    layer_off = (jnp.asarray(layer, jnp.int32) * num_pages).reshape(1)
+
+    stack = lambda pool: pool.reshape(num_layers * num_pages, cols, d)
+    pos_rows = jnp.repeat(pool_pos, h, axis=1).reshape(num_pages, 1, cols)
+
+    def page(g, layered):
+        def index(bi, ji, fetch_ref, mapped_ref, qpos_ref, layer_ref):
+            pid = fetch_ref[(bi * steps + ji) * group + g]
+            return ((layer_ref[0] + pid) if layered else pid, 0, 0)
+        return index
+
+    slot = lambda bi, ji, *_: (bi, 0, 0)
+    in_specs = [pl.BlockSpec((1, h, d), slot)]
+    in_specs += [pl.BlockSpec((1, 1, cols), page(g, False))
+                 for g in range(group)]
+    in_specs += [pl.BlockSpec((1, cols, d), page(g, True))
+                 for g in range(group)] * 2
+    return pl.pallas_call(
+        functools.partial(_kernel, group=group, num_heads=h, scale=scale),
+        name="paged_decode_attention",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(b, steps),
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec((1, h, d), slot),
+            scratch_shapes=[pltpu.VMEM((h, 1), jnp.float32),
+                            pltpu.VMEM((h, 1), jnp.float32),
+                            pltpu.VMEM((h, d), jnp.float32)],
+        ),
+        out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
+        interpret=interpret,
+    )(fetch, mapped.reshape(-1).astype(jnp.int32), q_pos.astype(jnp.int32),
+      layer_off, q, *[pos_rows] * group, *[stack(pool_k)] * group,
+      *[stack(pool_v)] * group)

@@ -669,12 +669,14 @@ class InferenceEngine(EngineHost):
         ``self.quantized``, so the full-precision programs are
         byte-identical to pre-int8 builds."""
         cfg = self.config
+        platform = self.mesh.devices.flat[0].platform
         if self.quantized:
             logits, k, v, pos, ks, vs = transformer.apply_lm_paged(
                 params, tokens, pool.k, pool.v, pool.pos, table,
                 cfg.spec, positions=positions, flat_rows=flat_rows,
                 compute_dtype=cfg.dtype(), row_reduce=self._row_reduce,
                 pool_k_scale=pool.k_scale, pool_v_scale=pool.v_scale,
+                platform=platform,
             )
             return logits, PagedKVCache(k=k, v=v, pos=pos,
                                         k_scale=ks, v_scale=vs)
@@ -682,6 +684,7 @@ class InferenceEngine(EngineHost):
             params, tokens, pool.k, pool.v, pool.pos, table, cfg.spec,
             positions=positions, flat_rows=flat_rows,
             compute_dtype=cfg.dtype(), row_reduce=self._row_reduce,
+            platform=platform,
         )
         return logits, PagedKVCache(k=k, v=v, pos=pos)
 

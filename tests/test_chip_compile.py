@@ -7,8 +7,10 @@
   at T = 4096, at the benchmark's training shape (8 rows of 2048, 16
   heads of 64: the blocks ``flash_block_sizes`` picks there must fit VMEM),
   at two lengths no wide block divides (384, 1152) and at heads of 512,
-  T = 8192, where the unhalved blocks are refused; and the config gate
-  that keeps a sequence the kernel would refuse from ever reaching it.
+  T = 8192, where the unhalved blocks are refused; the config gate
+  that keeps a sequence the kernel would refuse from ever reaching it;
+  and the paged decode-attention kernel at the serving cell's shape and
+  at an fp32 pool under a bucket no page group divides (ISSUE 31).
   One to four seconds each; skipped where the TPU compiler cannot
   describe the topology. Beside them the dense paged forward at the
   serving cell's widths, decode and prefill: no layer's pool is copied
@@ -82,10 +84,25 @@ def _compile_flash(chip, batch, seq_len, heads=8, head_dim=64):
         qkv, qkv, qkv).compile()
 
 
+def _compile_paged_decode(chip, dtype, slots, bucket, pool=(16, 512, 64),
+                          heads=8, head_dim=256):
+    from ddl_tpu.ops.paged_attention import (kernel_accepts,
+                                             paged_decode_attention)
+
+    assert kernel_accepts(heads, head_dim, pool[2])
+    on = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=chip)
+    kv = on(pool + (heads, head_dim), dtype)
+    return jax.jit(paged_decode_attention).lower(
+        on((slots, heads, head_dim), dtype), kv, kv,
+        on(pool[1:], jnp.int32), on((slots, bucket), jnp.int32),
+        on((slots,), jnp.int32), on((), jnp.int32)).compile()
+
+
 @pytest.mark.parametrize("case", [
     "adam_full_vector", "adam_quarter_shard", "flash_lm_shape",
     "flash_t4096", "flash_refused_below_block", "flash_cell_shape",
     "flash_t384", "flash_t1152", "flash_head_dim_512",
+    "paged_decode_cell_shape", "paged_decode_fp32_odd_bucket",
 ])
 def test_kernels_compile_for_v5e(v5e, case):
     if case == "flash_refused_below_block":
@@ -111,9 +128,20 @@ def test_kernels_compile_for_v5e(v5e, case):
         "flash_t1152": lambda: _compile_flash(v5e, 2, 1152),
         "flash_head_dim_512": lambda: _compile_flash(v5e, 1, 8192, heads=2,
                                                      head_dim=512),
+        # The serving cell's widest bucket, and one page a grid step
+        # (a bucket no group divides) over an fp32 pool of 16-row pages
+        # and 16 heads of 128.
+        "paged_decode_cell_shape": lambda: _compile_paged_decode(
+            v5e, jnp.bfloat16, 32, 36),
+        "paged_decode_fp32_odd_bucket": lambda: _compile_paged_decode(
+            v5e, jnp.float32, 4, 5, pool=(2, 16, 16), heads=16,
+            head_dim=128),
     }[case]()
     # The kernel is in the program, not a reference twin.
     assert "tpu_custom_call" in compiled.as_text()
+    if case.startswith("paged_decode"):
+        # The kernel's view of the stack is the stack's own bytes.
+        assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 20
 
 
 def _shapes_on(tree, sharding):
@@ -169,7 +197,17 @@ def test_paged_forward_writes_the_stacked_pool_in_place(v5e, program):
     out of the stacked array or back into it. Before ISSUE 29 each layer
     cost a ``slice`` and a ``dynamic-update-slice`` fusion of that size
     for K and for V, 42% of the cell's busy time on the chip, and the
-    bf16 decode held 794 MB of temporaries."""
+    bf16 decode held 794 MB of temporaries.
+
+    The bf16 decode reads its pages in place (ISSUE 31): one
+    ``paged_decode_attention`` kernel a layer and no gathered view
+    ``[32, 32 x 64, 8, 256]`` (268 MB each for K and V a layer, 84% of
+    the decode program before it; under 400 MB of temporaries then).
+    The kernel's view of the stack, ``[L * pages, 64 x 8, 256]``, has
+    to be the same bytes: a view the chip's tiled layout does not share
+    (``[..., 64, 8 x 256]``) would show here as a copy of the whole
+    pool. Prefill (512 queries a slot) and the int8 pool keep the
+    gathered path, and no kernel."""
     from ddl_tpu.models.transformer import (LMSpec, apply_lm_paged,
                                             init_lm_params)
 
@@ -192,7 +230,7 @@ def test_paged_forward_writes_the_stacked_pool_in_place(v5e, program):
             params, tokens, pool_k, pool_v, pool_pos, table, spec,
             positions=positions, flat_rows=flat_rows,
             compute_dtype=jnp.bfloat16, pool_k_scale=k_scale,
-            pool_v_scale=v_scale)
+            pool_v_scale=v_scale, platform="tpu")
 
     compiled = jax.jit(forward, donate_argnums=(1, 2, 3, 4, 5)).lower(
         _shapes_on(jax.eval_shape(
@@ -200,9 +238,17 @@ def test_paged_forward_writes_the_stacked_pool_in_place(v5e, program):
         pool, pool, on((pages, page), jnp.int32), *scales,
         on((b, t), jnp.int32), on((b, tp), jnp.int32), on((b, t), jnp.int32),
         on((b, t), jnp.int32)).compile()
-    assert _pool_sized_copies(compiled.as_text(), layer_pool_bytes) == []
+    hlo = compiled.as_text()
+    assert _pool_sized_copies(hlo, layer_pool_bytes) == []
     if program == "decode_32x1_p32":
-        assert compiled.memory_analysis().temp_size_in_bytes < 400e6
+        assert sum("custom-call(" in line and "paged_decode_attention" in line
+                   for line in hlo.splitlines()) == spec.num_layers
+        assert f"[{slots},{tp * page},{spec.num_heads},{spec.head_dim}]" \
+            not in hlo
+        # 206 MB of them the embedding table's cast to bf16.
+        assert compiled.memory_analysis().temp_size_in_bytes < 250e6
+    else:
+        assert "paged_decode_attention" not in hlo
 
 
 @pytest.mark.slow

@@ -1,0 +1,193 @@
+"""Decode attention through the block table (ISSUE 31).
+
+``ops.paged_attention.paged_decode_attention`` reads each slot's pages
+where they lie in the stacked pool; here it runs in Pallas interpret
+mode on the CPU against the path it replaces on the chip, ``gather_pages``
++ ``attend`` over the gathered views, on small stacked pools. What the
+block table can hold is the cases: a partly filled last page, a slot
+with no page, a bucket wider than every slot, prefix pages two slots
+share, two lanes over one slot's pages at different positions, stale
+rows of a reused page, a hole in a table. Then the rule by which
+``apply_lm_paged`` chooses between the two, from what the shapes show.
+"""
+
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from ddl_tpu.ops import kv_cache
+from ddl_tpu.ops.kv_cache import PAD_POS
+from ddl_tpu.ops.paged_attention import (kernel_accepts,
+                                         paged_decode_attention)
+
+L, P, S, H, D = 3, 12, 16, 8, 128
+B, TP = 4, 4
+
+
+def _case(name):
+    """``(table [B, TP], pool_pos [P, S], q_pos [B], empty slots)``. A
+    slot is a list of pages and the tokens it holds; its rows carry
+    positions 0.. in table order, the rest of its last page ``PAD_POS``."""
+    pos = np.full((P, S), PAD_POS, np.int32)
+    table = np.full((B, TP), -1, np.int32)
+    q_pos = np.zeros(B, np.int32)
+
+    def fill(slot, pages, length, at=None):
+        table[slot, :len(pages)] = pages
+        for i, page in enumerate(pages):
+            rows = min(S, length - i * S)
+            pos[page, :rows] = i * S + np.arange(rows)
+        q_pos[slot] = length - 1 if at is None else at
+
+    fill(1, [1], S)
+    fill(3, [2, 4], S + 3)
+    empty = []
+    if name == "partly_filled_last_page":
+        fill(0, [3, 5, 7], 2 * S + 9)
+        fill(2, [9], 1)
+    elif name == "slot_without_pages":
+        fill(0, [3, 5], 2 * S)
+        empty = [2]
+    elif name == "table_wider_than_every_slot":
+        fill(0, [3, 5], S + 1)
+        fill(2, [9], 7)
+    elif name == "shared_prefix_pages":
+        fill(0, [3, 5, 7], 2 * S + 4)
+        fill(2, [3, 5, 8], 2 * S + 11)
+    elif name == "lanes_aliasing_one_slot":
+        fill(0, [3, 5, 7], 2 * S + 9, at=S + 2)
+        fill(2, [3, 5, 7], 2 * S + 9, at=2 * S + 6)
+    elif name == "stale_rows_of_a_reused_page":
+        fill(0, [3, 5], S + 5)
+        pos[5, 5:12] = 100 + np.arange(7)   # the page's last holder's
+        fill(2, [9], 3)
+        pos[9, 3:] = 40 + np.arange(S - 3)
+    elif name == "hole_in_the_table":
+        fill(0, [3, 5, 7], 2 * S + 9)
+        table[0, 1] = -1                     # the gathered path masks it too
+        fill(2, [9], 2)
+    else:
+        raise KeyError(name)
+    return table, pos, q_pos, empty
+
+
+def _gathered(q, pool_k, pool_v, pos, table, q_pos, layer):
+    """The path the kernel replaces, in fp32."""
+    f32 = lambda a: a.astype(jnp.float32)
+    return kv_cache.attend(
+        f32(q)[:, None],
+        f32(kv_cache.gather_pages(pool_k, table, layer=layer)),
+        f32(kv_cache.gather_pages(pool_v, table, layer=layer)),
+        q_pos[:, None], kv_cache.table_positions(pos, table))[:, 0]
+
+
+@pytest.fixture(scope="module")
+def kernel():
+    """One traced program a dtype: the layer is an operand."""
+    return jax.jit(lambda *a: paged_decode_attention(*a, interpret=True))
+
+
+@pytest.mark.parametrize("layer", [0, L - 1])
+@pytest.mark.parametrize("dtype,tol", [("float32", 1e-5), ("bfloat16", 2e-2)])
+@pytest.mark.parametrize("case", [
+    "partly_filled_last_page", "slot_without_pages",
+    "table_wider_than_every_slot", "shared_prefix_pages",
+    "lanes_aliasing_one_slot", "stale_rows_of_a_reused_page",
+    "hole_in_the_table",
+])
+def test_kernel_matches_gather_and_attend(kernel, case, dtype, tol, layer):
+    table, pos, q_pos, empty = _case(case)
+    keys = jax.random.split(jax.random.PRNGKey(31), 3)
+    pool_k, pool_v = (jax.random.normal(k, (L, P, S, H, D), dtype)
+                      for k in keys[:2])
+    q = jax.random.normal(keys[2], (B, H, D), dtype)
+    args = (q, pool_k, pool_v, jnp.asarray(pos), jnp.asarray(table),
+            jnp.asarray(q_pos))
+    got = kernel(*args, jnp.int32(layer))
+    assert got.dtype == q.dtype and got.shape == (B, H, D)
+    got = np.asarray(got, np.float32)
+    want = np.asarray(_gathered(*args, layer))
+    live = [b for b in range(B) if b not in empty]
+    np.testing.assert_allclose(got[live], want[live], atol=tol, rtol=0)
+    # A slot with no page reads nothing: finite, and dropped by the caller.
+    assert (got[empty] == 0).all()
+    # ...and the layer asked for is the layer read.
+    other = np.asarray(_gathered(*args, (layer + 1) % L))
+    assert np.abs(other[live] - want[live]).max() > 10 * tol
+
+
+def _lowered_forward(t, int8, heads, platform):
+    """``apply_lm_paged`` traced over shapes alone: a decode (or ``t``
+    tokens a slot) of 2 slots over a 2-layer stack of 4 pages."""
+    from ddl_tpu.models.transformer import (LMSpec, apply_lm_paged,
+                                            init_lm_params)
+
+    spec = LMSpec(vocab=32, d_model=1024, num_heads=heads, num_layers=2,
+                  d_ff=64)
+    on = jax.ShapeDtypeStruct
+    stack = (spec.num_layers, 4, S, heads)
+    pool = on(stack + (spec.head_dim,), jnp.int8 if int8 else jnp.bfloat16)
+    scales = (on(stack, jnp.float32),) * 2 if int8 else (None, None)
+
+    def forward(params, pool_k, pool_v, pool_pos, k_scale, v_scale, tokens,
+                table, positions, flat_rows):
+        return apply_lm_paged(
+            params, tokens, pool_k, pool_v, pool_pos, table, spec,
+            positions=positions, flat_rows=flat_rows,
+            compute_dtype=jnp.bfloat16, pool_k_scale=k_scale,
+            pool_v_scale=v_scale, platform=platform)
+
+    i32 = lambda *shape: on(shape, jnp.int32)
+    return forward, (
+        jax.eval_shape(lambda: init_lm_params(jax.random.PRNGKey(0), spec)),
+        pool, pool, i32(4, S), *scales, i32(2, t), i32(2, 2), i32(2, t),
+        i32(2, t))
+
+
+@pytest.mark.parametrize("why,t,int8,heads,platform", [
+    ("two_queries_a_slot", 2, False, 8, "tpu"),
+    ("int8_scale_planes", 1, True, 8, "tpu"),
+    ("head_dim_64", 1, False, 16, "tpu"),
+    ("platform_cpu", 1, False, 8, "cpu"),
+    ("platform_unnamed_on_a_cpu_backend", 1, False, 8, None),
+])
+def test_apply_lm_paged_gathers_where_the_kernel_does_not_fit(
+        why, t, int8, heads, platform):
+    """One query a slot, no scale planes, heads of whole lane tiles and
+    a TPU: short of any one of them the forward gathers its views and
+    calls ``attend``, and its lowered text holds no kernel."""
+    forward, shapes = _lowered_forward(t, int8, heads, platform)
+    assert "paged_decode_attention" not in str(
+        jax.make_jaxpr(forward)(*shapes))
+    text = jax.jit(forward).lower(*shapes).as_text()
+    assert "paged_decode_attention" not in text
+    assert "tpu_custom_call" not in text
+
+
+def test_apply_lm_paged_reads_pages_in_place_where_the_kernel_fits():
+    """All four hold: one call a layer of ONE traced kernel (the layer
+    is an operand of a jitted function, so a program lowers the kernel
+    once however many layers it has), and no gathered view (``[2 slots,
+    2 pages x 16 rows, 8, 128]``) in the trace."""
+    forward, shapes = _lowered_forward(1, False, 8, "tpu")
+    trace = str(jax.make_jaxpr(forward)(*shapes))
+    assert len(re.findall(r"jit\[\s*name=paged_decode_attention",
+                          trace)) == 2
+    assert trace.count("pallas_call[") == 1
+    assert "bf16[2,32,8,128]" not in trace
+    gathered = str(jax.make_jaxpr(_lowered_forward(1, False, 8, "cpu")[0])(
+        *shapes))
+    assert "bf16[2,32,8,128]" in gathered
+
+
+@pytest.mark.parametrize("heads,head_dim,page,fits", [
+    (8, 256, 64, True), (8, 128, 16, True), (16, 128, 8, True),
+    (8, 64, 64, False),    # chip_smoke's toy spec: half a lane tile
+    (4, 256, 64, False),   # 8 heads over tp 2: half a sublane tile
+    (8, 128, 8, False),    # a page of 64 scores: half a lane tile
+])
+def test_kernel_accepts_whole_tiles_only(heads, head_dim, page, fits):
+    assert kernel_accepts(heads, head_dim, page) is fits
