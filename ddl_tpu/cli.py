@@ -418,11 +418,13 @@ def build_parser() -> argparse.ArgumentParser:
                          "admission becomes 'enough free pages' "
                          "instead of worst-case rows per slot")
     sv.add_argument("--model-spec", default=None, metavar="NAME",
-                    help="serve a named spec of the window/global "
-                         "routed-expert family (models.hybrid.NAMED_SPECS) "
-                         "in place of the dense decoder the --d-model/"
-                         "--heads/--layers flags describe; needs "
-                         "--page-size > 0 (two page groups)")
+                    help="serve a named spec of the second decoder "
+                         "family (models.hybrid.NAMED_SPECS: layers of "
+                         "three kinds, global, window and latent "
+                         "attention, with routed experts) in place of "
+                         "the dense decoder the --d-model/--heads/"
+                         "--layers flags describe; needs --page-size > 0 "
+                         "(page groups)")
     sv.add_argument("--ttft-deadline", type=float, default=None,
                     metavar="SECONDS",
                     help="default per-request time-to-first-token "

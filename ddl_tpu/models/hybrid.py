@@ -1,15 +1,16 @@
-"""The second decoder family: window and global attention layers mixed by
-a per-layer pattern, routed experts, written once over a cache view.
+"""The second decoder family: global, window and latent attention layers
+mixed by a per-layer pattern, routed experts (beside a shared one where
+the spec has it), written once over a cache view.
 
 Where ``models.transformer`` writes its dense block three times (full,
 contiguous cache, paged cache), this family has ONE block function,
 :func:`apply_block`, over a (mixer kind, FFN kind) pair a layer. The
-block projects, rotates and normalises; what it attends over is the
-caller's: ``mix(layer, q, k, v) -> a`` is the cache view. The uncached
-forward (:func:`apply_hybrid`, the tests' oracle) passes plain attention
-over the call's own rows; the serving engine passes
-:class:`PagedMixer`, which writes the rows into two kinds of page pool
-and attends what they hold.
+block projects, rotates and normalises by the layer's kind
+(:func:`project`); what it attends over is the caller's: ``mix(layer,
+q, k, v) -> a`` is the cache view. The uncached forward
+(:func:`apply_hybrid`, the tests' oracle) passes plain attention over
+the call's own rows; the serving engine passes :class:`PagedMixer`,
+which writes the rows into the page pools and attends what they hold.
 
 The block (every symbol a field of :class:`HybridSpec`): ``h = x +
 Attn(RMS(x))``, ``y = h + FFN(RMS(h))``, no bias anywhere, a final RMS
@@ -23,24 +24,42 @@ and an untied head with fp32 logits.
   A global layer attends every earlier position, a window layer the
   last ``window`` (its own included) and adds a learned sink logit a
   head to the softmax's denominator.
+- **Latent attention** (kind ``LATENT``; every earlier position, as a
+  global layer). ``c_q = RMS(x W_qa)`` (``q_lora_rank``, its own gain),
+  ``q = c_q W_qb`` -> ``num_heads`` heads ``[q_n (nope_dim) | q_r
+  (rope_dim)]``; ``[c | k_r] = x W_kva`` (``kv_lora_rank | rope_dim``),
+  ``c = RMS(c)`` (its own gain), ``k_r`` ONE a token, shared by all
+  heads; rotary on ``q_r`` and ``k_r`` (:func:`yarn_freqs`, pairs ``(i,
+  i + rope_dim / 2)``); ``[k_n,h | v_h] = c W_kvb`` a head (``nope_dim |
+  v_head_dim``); scores ``latent_scale x (q_n . k_n + q_r . k_r)``.
+  What a cache keeps of a token is ``[c | k_r]`` alone, after the norm
+  and the rotation: the cache view is handed that row in ``k``'s place
+  and up-projects it (the published form), or folds ``W_kvb`` into the
+  query and the output instead (the absorbed form: ``num_heads`` query
+  heads of ``kv_lora_rank + rope_dim`` over ONE K/V head whose V is the
+  first ``kv_lora_rank`` values of K). Both are the same attention.
 - **FFN.** Dense gated SiLU of width ``d_ff``, or ``num_experts`` routed
   experts of width ``expert_ff``, ``experts_per_token`` a token
-  (``ops.moe``). The layer holds the experts ``experts_held[0] ..
-  experts_held[1] - 1`` and computes their part alone.
+  (``ops.moe``), their weights times ``route_scale`` where the spec
+  gives one. The layer holds the experts ``experts_held[0] ..
+  experts_held[1] - 1`` and computes their part alone; a shared expert
+  of width ``shared_ff`` (0: none) sees every token, whole, beside them.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..ops import kv_cache, moe
+from ..ops import kv_cache, moe, paged_attention
 
 GLOBAL, WINDOW = 0, 1   # layer_kinds, as the published pattern counts them
+LATENT = 2              # a compressed row a token in the global page group
 DENSE, MOE = 0, 1       # ffn_kinds
 
 
@@ -69,6 +88,20 @@ class HybridSpec:
     layer_kinds: tuple[int, ...] = (GLOBAL, WINDOW, WINDOW, GLOBAL)
     ffn_kinds: tuple[int, ...] = (DENSE, MOE, MOE, MOE)
     norm_eps: float = 1e-5
+    # Routed layers: a factor on the routed weights, a shared expert.
+    route_scale: float | None = None
+    shared_ff: int = 0
+    # Latent layers (none of it read by the other kinds). Their rotary
+    # base is ``rope_base_global``, stretched as YaRN stretches it.
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    nope_dim: int = 0
+    rope_dim: int = 0
+    rope_factor: float = 1.0
+    rope_original: int = 4096     # positions before the stretch
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_mscale_all_dim: float = 0.0
 
     def __post_init__(self):
         if len(self.layer_kinds) != len(self.ffn_kinds):
@@ -85,10 +118,17 @@ class HybridSpec:
             raise ValueError(f"rotary_dim ({self.rotary_dim}) must be even "
                              f"and at most head_dim ({self.head_dim})")
         w = self.window
-        if w < 8 or w & (w - 1):
+        if WINDOW in self.layer_kinds and (w < 8 or w & (w - 1)):
             raise ValueError(f"window ({w}) must be a power of two >= 8: "
                              "a window layer's prefill works on blocks of "
                              "one window")
+        if LATENT in self.layer_kinds:
+            for name in ("q_lora_rank", "kv_lora_rank", "nope_dim",
+                         "rope_dim"):
+                if getattr(self, name) < 1:
+                    raise ValueError(f"a latent layer needs {name} >= 1")
+            if self.rope_dim % 2:
+                raise ValueError(f"rope_dim ({self.rope_dim}) must be even")
 
     @property
     def num_layers(self) -> int:
@@ -102,16 +142,39 @@ class HybridSpec:
         return (self.kv_heads_window if self.layer_kinds[layer] == WINDOW
                 else self.kv_heads_global)
 
+    @property
+    def latent_row(self) -> int:
+        """Values a latent layer caches a token: ``[c | k_r]``."""
+        return self.kv_lora_rank + self.rope_dim
+
+    @property
+    def latent_scale(self) -> float:
+        """A latent layer's score scale: ``(nope_dim + rope_dim) ** -0.5``
+        times the square of YaRN's ``0.1 x mscale_all_dim x ln(factor) +
+        1`` (1 without a stretch)."""
+        m = 1.0
+        if self.rope_factor > 1.0 and self.rope_mscale_all_dim:
+            m = 0.1 * self.rope_mscale_all_dim * math.log(self.rope_factor) + 1
+        return (self.nope_dim + self.rope_dim) ** -0.5 * m * m
+
     def layers_of(self, kind: int) -> tuple[int, ...]:
         return tuple(i for i, k in enumerate(self.layer_kinds) if k == kind)
 
     def block_shapes(self, layer: int) -> dict[str, tuple[int, ...]]:
         """Every leaf of one layer's weights, by name."""
         e, hq, hkv = self.d_model, self.num_heads, self.kv_heads(layer)
-        out = {"ln1": (e,), "ln2": (e,),
-               "wq": (e, hq * self.head_dim), "wk": (e, hkv * self.head_dim),
-               "wv": (e, hkv * self.v_head_dim),
-               "wo": (hq * self.v_head_dim, e)}
+        out = {"ln1": (e,), "ln2": (e,)}
+        if self.layer_kinds[layer] == LATENT:
+            ql, kl = self.q_lora_rank, self.kv_lora_rank
+            out.update(wqa=(e, ql), qn=(ql,),
+                       wqb=(ql, hq * (self.nope_dim + self.rope_dim)),
+                       wkva=(e, self.latent_row), kvn=(kl,),
+                       wkvb=(kl, hq * (self.nope_dim + self.v_head_dim)))
+        else:
+            out.update(wq=(e, hq * self.head_dim),
+                       wk=(e, hkv * self.head_dim),
+                       wv=(e, hkv * self.v_head_dim))
+        out["wo"] = (hq * self.v_head_dim, e)
         if self.layer_kinds[layer] == WINDOW:
             out["sink"] = (hq,)
         if self.ffn_kinds[layer] == DENSE:
@@ -121,6 +184,9 @@ class HybridSpec:
             f, h = self.expert_ff, self.held
             out.update(wr=(e, self.num_experts), rc=(self.num_experts,),
                        eg=(h, e, f), eu=(h, e, f), ed=(h, f, e))
+            if self.shared_ff:
+                sf = self.shared_ff
+                out.update(sg=(e, sf), su=(e, sf), sd=(sf, e))
         return out
 
     @property
@@ -141,6 +207,16 @@ NAMED_SPECS = {
         num_experts=256, experts_per_token=8, experts_held=(0, 16),
         layer_kinds=(GLOBAL, WINDOW, WINDOW, WINDOW, WINDOW, WINDOW, GLOBAL),
         ffn_kinds=(DENSE, MOE, MOE, MOE, MOE, MOE, MOE)),
+    "kimi-k2-ep32": HybridSpec(
+        vocab=20480, d_model=7168, num_heads=64, head_dim=192,
+        v_head_dim=128, q_lora_rank=1536, kv_lora_rank=512, nope_dim=128,
+        rope_dim=64, rope_base_global=50_000.0, rope_factor=32.0,
+        rope_original=4096, rope_beta_fast=1.0, rope_beta_slow=1.0,
+        rope_mscale_all_dim=1.0, d_ff=18432,
+        expert_ff=2048, shared_ff=2048, num_experts=384,
+        experts_per_token=8, experts_held=(0, 12), route_scale=2.827,
+        layer_kinds=(LATENT,) * 5, ffn_kinds=(DENSE, MOE, MOE, MOE, MOE),
+        norm_eps=1e-6),
 }
 
 
@@ -151,7 +227,7 @@ def init_hybrid_params(key: jax.Array, spec: HybridSpec) -> dict:
     to the context's mean, every token then chooses the same few experts,
     and most of them are never chosen."""
     def leaf(k, name, shape):
-        if name in ("ln1", "ln2"):
+        if name in ("ln1", "ln2", "qn", "kvn"):
             return jnp.ones(shape, jnp.float32)
         if name == "embed":
             return jax.random.normal(k, shape, jnp.float32)
@@ -179,12 +255,35 @@ def rms_norm(x, g, eps: float):
     return (y * g.astype(jnp.float32)).astype(x.dtype)
 
 
-def partial_rope(x, positions, base: float, rotary_dim: int):
+def yarn_freqs(spec: HybridSpec):
+    """The ``rope_dim / 2`` rotary frequencies of a latent layer, fp32:
+    ``theta_i = base ** (-2 i / rope_dim)``, left as they are below
+    ``low``, divided by ``rope_factor`` from ``high`` on, a linear ramp
+    between. ``d(beta) = rope_dim ln(original / (2 pi beta)) / (2 ln
+    base)`` is the index whose wavelength turns ``beta`` times in the
+    original context; ``low = floor(d(beta_fast))``, ``high =
+    ceil(d(beta_slow))``, both kept inside the indices."""
+    dim, base = spec.rope_dim, spec.rope_base_global
+    theta = base ** (-jnp.arange(dim // 2, dtype=jnp.float32) * 2.0 / dim)
+    if spec.rope_factor <= 1.0:
+        return theta
+    turn = lambda beta: dim * math.log(
+        spec.rope_original / (2 * math.pi * beta)) / (2 * math.log(base))
+    low = max(math.floor(turn(spec.rope_beta_fast)), 0)
+    high = min(math.ceil(turn(spec.rope_beta_slow)), dim - 1)
+    ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - low)
+                    / max(high - low, 1e-3), 0.0, 1.0)
+    return theta * (1.0 - ramp) + theta / spec.rope_factor * ramp
+
+
+def partial_rope(x, positions, base: float, rotary_dim: int, freqs=None):
     """Rotate the first ``rotary_dim`` dimensions of each head of ``x [B,
     T, H, D]`` by ``positions [B, T]``, pairing ``(i, i + rotary_dim /
-    2)``; the rest pass through."""
+    2)``; the rest pass through. ``freqs [rotary_dim / 2]`` takes
+    ``base``'s place where the kind has frequencies of its own."""
     half = rotary_dim // 2
-    freqs = base ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    if freqs is None:
+        freqs = base ** (-jnp.arange(half, dtype=jnp.float32) / half)
     ang = positions.astype(jnp.float32)[..., None, None] * freqs
     cos, sin = jnp.cos(ang), jnp.sin(ang)
     xf = x.astype(jnp.float32)
@@ -193,24 +292,80 @@ def partial_rope(x, positions, base: float, rotary_dim: int):
     return jnp.concatenate([rot.astype(x.dtype), x[..., rotary_dim:]], -1)
 
 
-def apply_block(h, blk, spec: HybridSpec, layer: int, positions, real, mix):
-    """One layer on ``h [B, T, E]`` at ``positions [B, T]`` (``real [B,
-    T]``: not padding). ``mix(layer, q, k, v)`` is the cache view: it
-    keeps ``k``/``v`` where it keeps them and returns the attention
-    output ``[B, T, Hq, Dv]``. Returns ``(h, counts)``, ``counts`` the
-    routed FFN's ``(assigned, touched)`` or ``None``."""
-    b, t, _ = h.shape
-    window = spec.layer_kinds[layer] == WINDOW
-    base = spec.rope_base_window if window else spec.rope_base_global
+def project(x, blk, spec: HybridSpec, layer: int, positions):
+    """What a layer's kind hands its cache view, from the normed ``x [B,
+    T, E]``: ``(q, k, v)`` heads, rotated and scaled; a latent layer
+    gives ``q [B, T, Hq, nope + rope]``, its cached row ``[c | k_r]``
+    as ``k [B, T, 1, kv_lora + rope]`` and no ``v``."""
+    b, t, _ = x.shape
     heads = lambda a, d: a.reshape(b, t, -1, d)
-    x = rms_norm(h, blk["ln1"], spec.norm_eps)
+    kind = spec.layer_kinds[layer]
+    if kind == LATENT:
+        freqs, rd = yarn_freqs(spec), spec.rope_dim
+        rot = lambda a: partial_rope(a, positions, spec.rope_base_global, rd,
+                                     freqs)
+        q = heads(rms_norm(x @ blk["wqa"], blk["qn"], spec.norm_eps)
+                  @ blk["wqb"], spec.nope_dim + rd)
+        q = jnp.concatenate([q[..., :-rd], rot(q[..., -rd:])], -1)
+        row = heads(x @ blk["wkva"], spec.latent_row)
+        c = rms_norm(row[..., :-rd], blk["kvn"], spec.norm_eps)
+        return q, jnp.concatenate([c, rot(row[..., -rd:])], -1), None
+    base = spec.rope_base_window if kind == WINDOW else spec.rope_base_global
     q = partial_rope(heads(x @ blk["wq"], spec.head_dim), positions, base,
                      spec.rotary_dim)
     k = partial_rope(heads(x @ blk["wk"], spec.head_dim), positions, base,
                      spec.rotary_dim)
     v = heads(x @ blk["wv"], spec.v_head_dim) * jnp.asarray(
         spec.value_scale, x.dtype)
-    a = mix(layer, q, k, v)
+    return q, k, v
+
+
+def latent_heads(blk, spec: HybridSpec):
+    """``W_kvb`` read a head: ``(W_uk [kv_lora, H, nope], W_uv [kv_lora,
+    H, v])``, the up-projections of ``c`` to a head's K and V."""
+    w = blk["wkvb"].reshape(spec.kv_lora_rank, spec.num_heads, -1)
+    return w[..., :spec.nope_dim], w[..., spec.nope_dim:]
+
+
+def latent_kv(rows, blk, spec: HybridSpec):
+    """The published form's K and V of cached rows ``rows [B, C, kv_lora
+    + rope]``: ``c`` up-projected a head, ``k_r`` shared by all of them:
+    ``(k [B, C, H, nope + rope], v [B, C, H, v])``."""
+    w_uk, w_uv = latent_heads(blk, spec)
+    c, k_r = rows[..., :spec.kv_lora_rank], rows[..., None, spec.kv_lora_rank:]
+    k_n = jnp.einsum("bkc,chd->bkhd", c, w_uk)
+    k = jnp.concatenate([k_n, jnp.broadcast_to(
+        k_r, (*k_n.shape[:3], spec.rope_dim))], -1)
+    return k, jnp.einsum("bkc,chd->bkhd", c, w_uv)
+
+
+def latent_absorbed(q, blk, spec: HybridSpec, width: int, attend):
+    """The absorbed form of a latent layer's attention: ``q [B, T, Hq,
+    nope + rope]`` -> ``[B, T, Hq, v]``, with ``W_uk`` folded into the
+    query and ``W_uv`` applied to the output, so every head reads the
+    cached rows ``[c | k_r]`` as they lie: ONE K/V head whose V is the
+    first ``kv_lora`` values of K. ``attend(qa [B, T, Hq, width]) -> [B,
+    T, Hq, kv_lora]`` is that attention over rows ``width`` wide (a
+    pool's, padded with zeros to whole lane tiles: the query is padded
+    likewise), scores times ``spec.latent_scale``. The same attention as
+    ``attend_grouped`` over :func:`latent_kv`'s K and V, and the cheaper
+    one where the rows outnumber the queries by far."""
+    w_uk, w_uv = latent_heads(blk, spec)
+    qt = jnp.einsum("bthd,chd->bthc", q[..., :spec.nope_dim], w_uk)
+    pad = jnp.zeros((*q.shape[:3], width - spec.latent_row), q.dtype)
+    ot = attend(jnp.concatenate([qt, q[..., spec.nope_dim:], pad], -1))
+    return jnp.einsum("bthc,chd->bthd", ot, w_uv)
+
+
+def apply_block(h, blk, spec: HybridSpec, layer: int, positions, real, mix):
+    """One layer on ``h [B, T, E]`` at ``positions [B, T]`` (``real [B,
+    T]``: not padding). ``mix(layer, q, k, v)`` is the cache view: it
+    keeps what :func:`project` hands it where it keeps it and returns
+    the attention output ``[B, T, Hq, Dv]``. Returns ``(h, counts)``,
+    ``counts`` the routed FFN's ``(assigned, touched)`` or ``None``."""
+    b, t, _ = h.shape
+    x = rms_norm(h, blk["ln1"], spec.norm_eps)
+    a = mix(layer, *project(x, blk, spec, layer, positions))
     h = h + a.reshape(b, t, -1) @ blk["wo"]
     x = rms_norm(h, blk["ln2"], spec.norm_eps)
     if spec.ffn_kinds[layer] == DENSE:
@@ -218,12 +373,15 @@ def apply_block(h, blk, spec: HybridSpec, layer: int, positions, real, mix):
         return h + up @ blk["wd"], None
     flat = x.reshape(b * t, -1)
     experts, weights = moe.route(flat, blk["wr"], blk["rc"],
-                                 spec.experts_per_token)
+                                 spec.experts_per_token, spec.route_scale)
     out, counts = moe.routed_ffn(
         flat, blk["eg"], blk["eu"], blk["ed"], experts, weights,
         real.reshape(-1), first=spec.experts_held[0],
         tile=moe.tile_rows(b * t, spec.experts_per_token, spec.num_experts))
-    return h + out.reshape(h.shape).astype(h.dtype), counts
+    out = out.reshape(h.shape).astype(h.dtype)
+    if spec.shared_ff:  # every token, whole, on every chip of the group
+        out = out + (jax.nn.silu(x @ blk["sg"]) * (x @ blk["su"])) @ blk["sd"]
+    return h + out, counts
 
 
 def apply_layers(params, tokens, spec: HybridSpec, positions, real, mix,
@@ -252,6 +410,10 @@ def apply_hybrid(params, tokens, spec: HybridSpec, compute_dtype=None):
     positions = jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32), (b, t))
 
     def mix(layer, q, k, v):
+        if spec.layer_kinds[layer] == LATENT:
+            k, v = latent_kv(k[:, :, 0], params["blocks"][layer], spec)
+            return kv_cache.attend_grouped(q, k, v, positions, positions,
+                                           scale=spec.latent_scale)
         window = spec.layer_kinds[layer] == WINDOW
         return kv_cache.attend_grouped(
             q, k, v, positions, positions,
@@ -267,7 +429,9 @@ def apply_hybrid(params, tokens, spec: HybridSpec, compute_dtype=None):
 #
 # Two page groups side by side. The GLOBAL group keeps every row: a slot's
 # block table maps logical page j to a pool page, as the dense family's
-# does, and a row's position is its logical row. The WINDOW group keeps
+# does, and a row's position is its logical row; a LATENT layer's pool is
+# of this group, ONE pool of ``[c | k_r]`` rows (``serve.cache.
+# latent_pool_width``). The WINDOW group keeps
 # the last `window` rows: a slot's table is a ring of R columns, logical
 # page j lives in column j % R, and the host frees a column's page once
 # the page lies wholly behind the window. No position travels with a row:
@@ -280,13 +444,16 @@ GLOBAL_QUERY_BLOCK = 256
 
 class PagedMixer:
     """The cache view of one prefill or decode call. ``pools`` maps a
-    layer to its ``(k, v)`` pool; after the layers have run it holds the
-    updated pools."""
+    layer to its ``(k, v)`` pool (a latent layer's: ``(rows, None)``);
+    after the layers have run it holds the updated pools. ``w_table`` is
+    ``None`` where the pattern has no window layer."""
 
     def __init__(self, spec: HybridSpec, params, pools: dict, *, page_size,
-                 g_table, w_table, positions, real, last, base=None):
+                 g_table, w_table, positions, real, last, base=None,
+                 platform=None):
         self.spec, self.params, self.pools = spec, params, dict(pools)
-        self.ps = page_size
+        self.ps, self.real = page_size, real
+        self.platform = platform or jax.default_backend()
         self.g_table, self.w_table = g_table, w_table
         self.positions = positions          # [B, T]; -1 where padding
         self.base = base                    # prefill: the block's first
@@ -295,7 +462,9 @@ class PagedMixer:
                                -1)
         self.g_rows = kv_cache.table_rows(
             g_table, jnp.where(real, positions, -1), page_size,
-            self._pages(GLOBAL))
+            self._pages(GLOBAL, LATENT))
+        if w_table is None:
+            return
         keep = real & (positions > last[:, None] - spec.window)
         self.w_rows = kv_cache.ring_rows(
             w_table, jnp.where(keep, positions, -1), page_size,
@@ -303,10 +472,11 @@ class PagedMixer:
         self.w_pos = kv_cache.ring_positions(last, w_table.shape[1],
                                              page_size)
 
-    def _pages(self, kind: int) -> int:
-        """Pages of one group's pools (0 where the pattern has no such
-        layer: its rows then never resolve to a write)."""
-        layers = self.spec.layers_of(kind)
+    def _pages(self, *kinds: int) -> int:
+        """Pages of one group's pools, the group the layers of ``kinds``
+        share (0 where the pattern has no such layer: its rows then never
+        resolve to a write)."""
+        layers = [i for kind in kinds for i in self.spec.layers_of(kind)]
         return self.pools[layers[0]][0].shape[0] if layers else 0
 
     def _write(self, layer, k, v, rows):
@@ -329,6 +499,8 @@ class PagedMixer:
     def __call__(self, layer, q, k, v):
         if self.spec.layer_kinds[layer] == GLOBAL:
             return self._global(layer, q, k, v)
+        if self.spec.layer_kinds[layer] == LATENT:
+            return self._latent(layer, q, k[:, :, 0])
         if self.base is None:
             return self._window_decode(layer, q, k, v)
         return self._window_prefill(layer, q, k, v)
@@ -339,16 +511,69 @@ class PagedMixer:
         pk, pv = self._write(layer, k, v, self.g_rows)
         kv = self._view(pk, self.g_table, k)
         vv = self._view(pv, self.g_table, v)
+        return self._in_blocks(q, lambda a, at: kv_cache.attend_grouped(
+            a, kv, vv, at, self.g_pos))
+
+    def _in_blocks(self, q, attend):
+        """``attend(q, q_pos)`` over the call's queries; a prefill's go
+        in blocks."""
         b, t = self.positions.shape
         blk = min(t, GLOBAL_QUERY_BLOCK)
         if b != 1 or t == blk:
-            return kv_cache.attend_grouped(q, kv, vv, self.positions,
-                                           self.g_pos)
+            return attend(q, self.positions)
         qb = q.reshape(t // blk, 1, blk, *q.shape[2:])
         pb = self.positions.reshape(t // blk, 1, blk)
-        out = lax.map(lambda a: kv_cache.attend_grouped(
-            a[0], kv, vv, a[1], self.g_pos), (qb, pb))
+        out = lax.map(lambda a: attend(a[0], a[1]), (qb, pb))
         return out.reshape(1, t, *out.shape[3:])
+
+    def _latent(self, layer, q, rows):
+        """Write the rows ``[c | k_r]`` (padded to the pool's width, whole
+        lane tiles), then attend. A decode tick reads each slot's table
+        in the absorbed form, the rows as they lie: on a TPU, at widths
+        ``ops.paged_attention.latent_kernel_accepts``, the pages where
+        they are in the pool (``latent_decode_attention``, which moves
+        the bytes of the pages resident); else a view gathered at the
+        widest slot's bucket. A prefill block takes the published form,
+        K and V up-projected once and the queries in blocks: from
+        position 0 (a whole prompt, a first chunk) over its own rows, a
+        later chunk over the slot's table, which holds them and the
+        earlier chunks'."""
+        pool, _ = self.pools[layer]
+        spec, blk = self.spec, self.params["blocks"][layer]
+        rows = rows.astype(pool.dtype)
+        pool = kv_cache.write_rows_flat(pool, jnp.pad(rows, (
+            (0, 0), (0, 0), (0, pool.shape[-1] - spec.latent_row))),
+            self.g_rows)
+        self.pools[layer] = (pool, None)
+        if self.base is None:
+            return latent_absorbed(q, blk, spec, pool.shape[-1],
+                                   functools.partial(self._rows_as_they_lie,
+                                                     pool))
+
+        def over(rows, k_pos):
+            k, v = latent_kv(rows, blk, spec)
+            return self._in_blocks(q, lambda a, at: kv_cache.attend_grouped(
+                a, k, v, at, k_pos, scale=spec.latent_scale))
+
+        return lax.cond(self.base == 0,
+                        lambda: over(rows, self.positions),
+                        lambda: over(kv_cache.gather_pages(
+                            pool, self.g_table)[..., :spec.latent_row],
+                            self.g_pos))
+
+    def _rows_as_they_lie(self, pool, qa):
+        """A decode tick's queries ``qa [B, 1, Hq, W]`` over ONE K/V head
+        of pool rows, V their first ``kv_lora`` values."""
+        spec = self.spec
+        kl, scale = spec.kv_lora_rank, spec.latent_scale
+        if self.platform == "tpu" and paged_attention.latent_kernel_accepts(
+                spec.num_heads, pool.shape[-1], kl, self.ps):
+            return paged_attention.latent_decode_attention(
+                qa[:, 0], pool, jnp.where(self.real, self.g_table, -1),
+                self.positions[:, 0], scale=scale, v_width=kl)[:, None]
+        kv = kv_cache.gather_pages(pool, self.g_table)[:, :, None, :]
+        return kv_cache.attend_grouped(qa, kv, kv[..., :kl], self.positions,
+                                       self.g_pos, scale=scale)
 
     def _window_decode(self, layer, q, k, v):
         pk, pv = self._write(layer, k, v, self.w_rows)
@@ -392,15 +617,19 @@ class PagedMixer:
 
 def apply_hybrid_paged(params, pools: dict, tokens, spec: HybridSpec, *,
                        page_size: int, g_table, w_table, positions, real,
-                       last, base=None, compute_dtype=None):
+                       last, base=None, compute_dtype=None, platform=None):
     """The serving forward of one call through :class:`PagedMixer`:
     ``tokens [B, T]`` at ``positions [B, T]`` -> ``(h [B, T, E], pools,
     counts)``. ``base`` (a traced scalar) marks a prefill of one slot
     from that position; ``None`` a decode of one row a slot. ``last [B]``
-    is the last position this call writes (behind 0: nothing)."""
+    is the last position this call writes (behind 0: nothing).
+    ``w_table`` is ``None`` for a pattern without window layers.
+    ``platform`` is the platform of the devices the program will run on
+    (the default backend's when not given): a latent layer's decode
+    reads its pages in place on a TPU."""
     mix = PagedMixer(spec, params, pools, page_size=page_size,
                      g_table=g_table, w_table=w_table, positions=positions,
-                     real=real, last=last, base=base)
+                     real=real, last=last, base=base, platform=platform)
     h, counts = apply_layers(params, tokens, spec,
                              jnp.maximum(positions, 0), real, mix,
                              compute_dtype)

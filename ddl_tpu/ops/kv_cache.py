@@ -277,6 +277,7 @@ def attend_grouped(
     *,
     window: int | None = None,
     sink: jax.Array | None = None,
+    scale: float | None = None,
 ) -> jax.Array:
     """Attention of fresh queries against a cache view whose K/V heads
     are fewer than the query heads and whose K and V rows differ in
@@ -288,13 +289,14 @@ def attend_grouped(
     when ``0 <= k_pos <= q_pos`` and, with ``window``, ``k_pos > q_pos -
     window`` (the query's own position is one of the ``window``).
     ``sink [Hq]`` adds one learned logit a query head to the softmax's
-    denominator only, so a row's weights sum to less than one. fp32
-    scores and softmax, the same mask constant as :func:`attend`."""
+    denominator only, so a row's weights sum to less than one. Scores
+    are times ``scale``, over ``sqrt(Dk)`` without one. fp32 scores and
+    softmax, the same mask constant as :func:`attend`."""
     b, t, hq, dk = q.shape
     hkv = k_view.shape[2]
     qg = q.reshape(b, t, hkv, hq // hkv, dk)
     s = jnp.einsum("bthgd,bchd->bhgtc", qg, k_view).astype(jnp.float32)
-    s = s / math.sqrt(dk)
+    s = s / math.sqrt(dk) if scale is None else s * scale
     kp, qp = k_pos[:, None, :], q_pos[:, :, None]             # [B, T, C]
     mask = (kp >= 0) & (kp <= qp)
     if window is not None:

@@ -7,7 +7,8 @@ nothing stands in for them or for their exchange.
 
 - :func:`route`: fp32 sigmoid scores, the top ``k`` of ``score +
   correction`` (the correction selects, it never weighs), weights the
-  selected scores normalised to sum to one.
+  selected scores normalised to sum to one, times the model's scaling
+  factor where it has one.
 - :func:`routed_ffn`: the assignments to held experts, sorted by expert,
   each expert's run padded to whole row tiles, and one gated-SiLU product
   chain a tile against that tile's expert. The loop runs over the tiles
@@ -23,15 +24,21 @@ import jax.numpy as jnp
 from jax import lax
 
 
-def route(x, wr, correction, top_k: int):
+def route(x, wr, correction, top_k: int, scale: float | None = None):
     """``x [T, E]``, ``wr [E, N]``, ``correction [N]`` -> ``(experts
     int32 [T, k], weights fp32 [T, k])``. All in fp32 at the highest
-    matmul precision: a score that rounding moves swaps an expert."""
+    matmul precision: a score that rounding moves swaps an expert. With
+    ``scale`` the weights are ``scale x score / (sum of the chosen
+    scores + 1e-20)``, as the models that publish a scaling factor
+    write them."""
     sc = jax.nn.sigmoid(jnp.dot(x.astype(jnp.float32), wr.astype(jnp.float32),
                                 precision=lax.Precision.HIGHEST))
     _, experts = lax.top_k(sc + correction.astype(jnp.float32), top_k)
     w = jnp.take_along_axis(sc, experts, axis=-1)
-    return experts.astype(jnp.int32), w / jnp.sum(w, axis=-1, keepdims=True)
+    total = jnp.sum(w, axis=-1, keepdims=True)
+    if scale is None:
+        return experts.astype(jnp.int32), w / total
+    return experts.astype(jnp.int32), scale * w / (total + 1e-20)
 
 
 def tile_rows(tokens: int, top_k: int, num_experts: int) -> int:

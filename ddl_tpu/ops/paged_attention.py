@@ -1,4 +1,7 @@
-"""Decode attention that reads the dense paged pool's pages in place.
+"""Decode attention that reads a paged pool's pages in place: the dense
+family's stacked K and V pools (:func:`paged_decode_attention`) and the
+second family's latent pools (:func:`latent_decode_attention`, at the
+end).
 
 A dense paged DECODE program (one query a slot) used to gather every
 slot's pages into ``[B, TP * page_size, H, D]`` views of K and of V, a
@@ -59,6 +62,22 @@ def kernel_accepts(num_heads: int, head_dim: int, page_size: int) -> bool:
     scores."""
     return (head_dim % 128 == 0 and num_heads % 8 == 0
             and (page_size * num_heads) % 128 == 0)
+
+
+def _fetch_plan(table: jax.Array, group: int):
+    """What each of the ``group`` page inputs of a kernel here fetches at
+    each grid step of ``table [B, TP]``, flat ``[B * TP]``: the table's
+    page where it is mapped, else the page that input fetched last (an
+    unchanged block index issues no copy); and whether it is mapped,
+    ``[B * steps, group]``."""
+    b, tp = table.shape
+    steps = tp // group
+    at = jnp.arange(b * tp, dtype=jnp.int32).reshape(b * steps, group)
+    mapped = (table >= 0).reshape(b * steps, group)
+    last = jax.lax.cummax(jnp.where(mapped, at, -1), axis=0)
+    fetch = jnp.where(
+        last >= 0, table.reshape(-1)[jnp.maximum(last, 0)], 0).reshape(-1)
+    return fetch, mapped
 
 
 def _kernel(fetch_ref, mapped_ref, qpos_ref, layer_ref, q_ref, *refs,
@@ -160,14 +179,7 @@ def paged_decode_attention(
         scale = 1.0 / math.sqrt(d)
     cols = page_size * h
 
-    # What each of the ``group`` page inputs fetches at each step: the
-    # table's page where it is mapped, else the page that input fetched
-    # last (an unchanged block index issues no copy).
-    at = jnp.arange(b * tp, dtype=jnp.int32).reshape(b * steps, group)
-    mapped = (table >= 0).reshape(b * steps, group)
-    last = jax.lax.cummax(jnp.where(mapped, at, -1), axis=0)
-    fetch = jnp.where(
-        last >= 0, table.reshape(-1)[jnp.maximum(last, 0)], 0).reshape(-1)
+    fetch, mapped = _fetch_plan(table, group)
     layer_off = (jnp.asarray(layer, jnp.int32) * num_pages).reshape(1)
 
     stack = lambda pool: pool.reshape(num_layers * num_pages, cols, d)
@@ -204,3 +216,143 @@ def paged_decode_attention(
     )(fetch, mapped.reshape(-1).astype(jnp.int32), q_pos.astype(jnp.int32),
       layer_off, q, *[pos_rows] * group, *[stack(pool_k)] * group,
       *[stack(pool_v)] * group)
+
+
+# -- latent rows: one K/V head, V inside K ------------------------------------
+#
+# A latent layer's pool (``serve.cache.hybrid_cache``) is ``[pages,
+# page_size, W]``: a row ``[c | k_r | zeros]`` a token, ``W`` whole lane
+# tiles. In the absorbed form (``models.hybrid.latent_absorbed``) every
+# query head reads that row as it lies: a page IS the ``[page_size, W]``
+# K matrix of all heads, and its first ``v_width`` lanes are V. So the MXU
+# form needs no head mask, one copy of a page serves both products, and
+# no position is stored: row ``r`` of table column ``c`` holds position
+# ``c * page_size + r``.
+
+# Pages a grid step reads (80 KB each at the published widths: 64 rows of
+# 640 bf16), stacked in VMEM into one ``[pages * page_size, W]`` K so that
+# the scores of a step are whole lane tiles and the MXU sees one product.
+# Timed on one v5e at ``serve-k2-closed64-long``'s shape (64 slots, 3,399
+# of 17,408 table entries mapped, 215,839 rows; PERF.md section 6, PR
+# 32): a layer's call took 1.925 / 1.441 / 1.223 / 1.162 / 1.141 ms at 2
+# / 4 / 8 / 16 / 34 pages a step; the gathered view and ``attend_grouped``
+# 6.138.
+LATENT_PAGES_PER_STEP = 16
+
+
+def latent_kernel_accepts(num_heads: int, row_width: int, v_width: int,
+                          page_size: int) -> bool:
+    """Whether :func:`latent_decode_attention`'s tiles fit: rows and
+    their V part whole 128-lane tiles, heads whole 8-row tiles, a page
+    whole 16-row tiles (bf16's) so that pages stack for free."""
+    return (row_width % 128 == 0 and v_width % 128 == 0
+            and 0 < v_width <= row_width and num_heads % 8 == 0
+            and page_size % 16 == 0)
+
+
+def _latent_kernel(fetch_ref, mapped_ref, qpos_ref, q_ref, *refs, group: int,
+                   page_size: int, v_width: int, scale: float):
+    del fetch_ref  # the index maps' operand
+    k_refs = refs[:group]
+    o_ref, m_ref, l_ref, acc_ref = refs[group:]
+    b, j = pl.program_id(0), pl.program_id(1)
+
+    @pl.when(j == 0)
+    def _():
+        m_ref[...] = jnp.full_like(m_ref, _MASKED)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    at = (b * pl.num_programs(1) + j) * group
+    mapped = [mapped_ref[at + g] > 0 for g in range(group)]
+
+    @pl.when(functools.reduce(jnp.logical_or, mapped))
+    def _():
+        q = q_ref[0]                                        # [H, W]
+        k = jnp.concatenate([r[0] for r in k_refs], axis=0).astype(q.dtype)
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale     # [H, G * S]
+        lane = jax.lax.broadcasted_iota(jnp.int32, (1, group * page_size), 1)
+        ok = j * (group * page_size) + lane <= qpos_ref[b]
+        for g in range(group):  # an unmapped page holds the last fetched
+            ok &= mapped[g] | (lane // page_size != g)
+        s = jnp.where(ok, s, _MASKED)
+        m_prev = m_ref[...]
+        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.where(ok, jnp.exp(s - m_new), 0.0)
+        l_ref[...] = alpha * l_ref[...] + p.sum(axis=-1, keepdims=True)
+        acc_ref[...] = alpha * acc_ref[...] + jnp.dot(
+            p.astype(q.dtype), k[:, :v_width],
+            preferred_element_type=jnp.float32)
+        m_ref[...] = m_new
+
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _():
+        l = l_ref[...]
+        o_ref[0] = jnp.where(l > 0, acc_ref[...] / l, 0.0).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "v_width",
+                                             "pages_per_step", "interpret"))
+def latent_decode_attention(
+    q: jax.Array,
+    pool: jax.Array,
+    table: jax.Array,
+    q_pos: jax.Array,
+    *,
+    scale: float,
+    v_width: int,
+    pages_per_step: int = LATENT_PAGES_PER_STEP,
+    interpret: bool = False,
+) -> jax.Array:
+    """Causal attention of one query a slot, ``q [B, H, W]`` at positions
+    ``q_pos [B]`` (negative: the slot attends nothing and gets zeros),
+    over the rows of ``pool [P, S, W]`` that ``table [B, TP]`` maps (page
+    ids, ``-1`` = unmapped; column ``c``'s rows hold positions ``c * S
+    ..``), all heads against ONE K/V head: K a row, V its first
+    ``v_width`` values. Returns ``[B, H, v_width]`` in ``q``'s dtype: what
+    ``attend_grouped(q[:, None], view[:, :, None], view[:, :, None,
+    :v_width], q_pos[:, None], positions, scale=scale)[:, 0]`` gives over
+    ``view = gather_pages(pool, table)``, to rounding (online softmax).
+
+    Jitted, so the layers of one program, each with a pool of its own,
+    are one traced function lowered once. A grid step reads
+    ``pages_per_step`` pages of a slot; a step or a page past the slot's
+    last mapped one names the page fetched last (no copy is issued) and a
+    step with no mapped page skips its compute."""
+    b, h, w = q.shape
+    page_size = pool.shape[1]
+    tp = table.shape[1]
+    group = math.gcd(pages_per_step, tp)
+    steps = tp // group
+    fetch, mapped = _fetch_plan(table, group)
+
+    def page(g):
+        def index(bi, ji, fetch_ref, mapped_ref, qpos_ref):
+            return (fetch_ref[(bi * steps + ji) * group + g], 0, 0)
+        return index
+
+    slot = lambda bi, ji, *_: (bi, 0, 0)
+    return pl.pallas_call(
+        functools.partial(_latent_kernel, group=group, page_size=page_size,
+                          v_width=v_width, scale=scale),
+        name="latent_decode_attention",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(b, steps),
+            in_specs=[pl.BlockSpec((1, h, w), slot)] + [
+                pl.BlockSpec((1, page_size, w), page(g))
+                for g in range(group)],
+            out_specs=pl.BlockSpec((1, h, v_width), slot),
+            scratch_shapes=[pltpu.VMEM((h, 1), jnp.float32),
+                            pltpu.VMEM((h, 1), jnp.float32),
+                            pltpu.VMEM((h, v_width), jnp.float32)],
+        ),
+        out_shape=jax.ShapeDtypeStruct((b, h, v_width), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
+        interpret=interpret,
+    )(fetch, mapped.reshape(-1).astype(jnp.int32), q_pos.astype(jnp.int32),
+      q, *[pool] * group)

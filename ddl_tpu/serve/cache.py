@@ -430,7 +430,7 @@ class PagePool:
         return False
 
 
-# -- two page groups side by side (models.hybrid) -----------------------------
+# -- page groups side by side (models.hybrid) ---------------------------------
 
 
 @jax.tree_util.register_dataclass
@@ -444,6 +444,10 @@ class HybridKVCache:
     whose page count and head count are its group's (global layers: every row
     kept, ``num_pages`` pages; window layers: the last ``window`` rows,
     ``num_window_pages`` pages) and whose K and V rows differ in width.
+    A latent layer keeps ONE pool, in ``k``, of the global group's
+    pages: ``[pages, page_size, latent_pool_width]``, a token's
+    compressed row ``[c | k_r]`` and zeros up to whole lane tiles; its
+    ``v`` is ``None``.
     A leaf a layer, so a layer's write is in place on its own donated
     buffer. No positions are stored: ``ops.kv_cache.ring_positions`` and
     a global row's logical index give them."""
@@ -452,20 +456,36 @@ class HybridKVCache:
     v: tuple
 
 
+def latent_pool_width(spec) -> int:
+    """A latent layer's pool row: ``kv_lora_rank + rope_dim`` values
+    rounded up to whole 128-lane tiles (576 -> 640 at the published
+    widths). The chip keeps a ``[pages, page_size, 576]`` array with
+    another axis minor-most (4.5 tiles would be padded), and a program
+    that scatters rows into it and gathers pages from it copies the
+    whole pool in and out to turn it, every tick; at 640 the pool is
+    used as it lies, and 576 of 640 on the chip are 640 either way."""
+    return -(-spec.latent_row // 128) * 128
+
+
 def hybrid_cache(spec, num_pages: int, num_window_pages: int,
                  page_size: int, dtype) -> HybridKVCache:
     """Fresh zero pools on the default device."""
-    from ..models.hybrid import WINDOW
+    from ..models.hybrid import LATENT, WINDOW
+
+    latent = lambda layer: spec.layer_kinds[layer] == LATENT
 
     def pool(layer: int, width: int):
         pages = (num_window_pages if spec.layer_kinds[layer] == WINDOW
                  else num_pages)
-        return jnp.zeros((pages, page_size, spec.kv_heads(layer) * width),
-                         dtype)
+        row = (latent_pool_width(spec) if latent(layer)
+               else spec.kv_heads(layer) * width)
+        return jnp.zeros((pages, page_size, row), dtype)
 
     layers = range(spec.num_layers)
-    return HybridKVCache(k=tuple(pool(i, spec.head_dim) for i in layers),
-                         v=tuple(pool(i, spec.v_head_dim) for i in layers))
+    return HybridKVCache(
+        k=tuple(pool(i, spec.head_dim) for i in layers),
+        v=tuple(None if latent(i) else pool(i, spec.v_head_dim)
+                for i in layers))
 
 
 def ring_columns(window: int, page_size: int) -> int:

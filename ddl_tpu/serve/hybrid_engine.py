@@ -1,5 +1,6 @@
-"""The engine of the ``models.hybrid`` family: two page groups, one
-block table a group a slot, the same host API.
+"""The engine of the ``models.hybrid`` family (global, window and latent
+attention layers): two page groups, one block table a group a slot, the
+same host API.
 
 ``serve.engine_cls(spec)`` is this class for a ``HybridSpec``; the
 constructor's checks, the host state, scheduler, buckets, warm-up,
@@ -11,9 +12,11 @@ the window group.
 
 - **Global group**: the base class's ``pages`` / ``tables`` /
   ``table_len`` / ``reserved_for``, every row kept, decode programs
-  bucketed on its page count.
-- **Window group**: ``win_pages`` (a second ``PagePool``), ``win_tables
-  [slots, R]`` a ring of columns (``ops.kv_cache.ring_rows``), logical
+  bucketed on its page count. A global layer keeps a K and a V pool
+  there, a latent layer one pool of compressed rows.
+- **Window group**, where the pattern has a window layer (without one:
+  no pool, no tables, no host work, ``ring`` 0): ``win_pages`` (a second
+  ``PagePool``), ``win_tables [slots, R]`` a ring of columns (``ops.kv_cache.ring_rows``), logical
   page ``j`` in column ``j % R``. Before a row is written the pages whose
   last row the window has left are freed and the row's own page is
   mapped; a prefill block keeps only its last ``window`` rows beyond the
@@ -25,7 +28,10 @@ Programs keep the names ``jit_run_prefill_b<bucket>`` and
 group's width is fixed). A prefill returns the last position's logits
 alone. Each program returns, behind the sampled tokens in the one small
 array the host waits for, the routed layers' ``(assigned, touched)``;
-:attr:`last_counters` holds them for the scheduler's spans.
+:attr:`last_counters` holds them for the scheduler's spans, beside the
+host's own counts of a decode tick: ``win_pages`` (a pattern with window
+layers) and ``latent_rows`` (one with latent layers: the cached rows the
+tick's active slots attend, summed over slots).
 
 Weights are kept as handed over, cast once to ``compute_dtype`` when
 the engine is built, never inside a call.
@@ -56,7 +62,7 @@ UNSUPPORTED = (
 )
 HANDOFF = ("the hybrid family does not support the disaggregated hand-off "
            "(dump/load/alias of a slot's pages, preemption, prefill and "
-           "decode roles): its two page groups have no serialised form yet")
+           "decode roles): its page groups have no serialised form yet")
 
 
 class HybridEngine(InferenceEngine):
@@ -73,6 +79,10 @@ class HybridEngine(InferenceEngine):
 
     def _layout(self) -> None:
         cfg = self.config
+        self.ring = self.num_window_pages = 0
+        self.counts_latent_rows = bool(cfg.spec.layers_of(hybrid.LATENT))
+        if not cfg.spec.layers_of(hybrid.WINDOW):
+            return
         window = cfg.spec.window
         if cfg.capacity % window:
             raise ValueError(
@@ -92,23 +102,27 @@ class HybridEngine(InferenceEngine):
         self.cache = hybrid_cache(
             cfg.spec, self.num_pages, self.num_window_pages, self.page_size,
             np.dtype(cfg.compute_dtype or np.float32))
+        self.last_counters = {}
+        if not self.ring:
+            return
         self.win_pages = PagePool(self.num_window_pages)
         self.win_tables = np.full((cfg.slots, self.ring), -1, np.int32)
         # The logical page a column holds (-1: none).
         self.win_logical = np.full((cfg.slots, self.ring), -1, np.int64)
         self.win_reserved = np.zeros(cfg.slots, np.int64)
         self.win_held = np.zeros(cfg.slots, bool)  # admitted by reservation
-        self.last_counters = {}
 
     # -- page groups (host half) -------------------------------------------
 
     def can_admit(self, need: int) -> bool:
-        """Both groups have what a new slot reserves."""
-        return super().can_admit(need) \
-            and self.win_pages.available >= self.ring
+        """Each group the pattern has holds what a new slot reserves."""
+        return super().can_admit(need) and (
+            not self.ring or self.win_pages.available >= self.ring)
 
     def reserve_pages(self, slot: int, n: int) -> None:
         super().reserve_pages(slot, n)
+        if not self.ring:
+            return
         self.win_pages.reserve(self.ring)
         self.win_reserved[slot] += self.ring
         self.win_held[slot] = True
@@ -118,6 +132,8 @@ class HybridEngine(InferenceEngine):
 
     def release_slot(self, slot: int) -> None:
         super().release_slot(slot)
+        if not self.ring:
+            return
         for c in np.nonzero(self.win_tables[slot] >= 0)[0]:
             self._free_window(slot, int(c), keep=False)
         self.win_pages.unreserve(int(self.win_reserved[slot]))
@@ -172,12 +188,18 @@ class HybridEngine(InferenceEngine):
 
     # -- compiled programs -------------------------------------------------
 
+    def _win_table(self, rows=slice(None)):
+        """The window group's tables as a program's argument; ``None``
+        (no argument at all) without the group."""
+        return jnp.asarray(self.win_tables[rows]) if self.ring else None
+
     def _forward(self, params, cache: HybridKVCache, tokens, **kw):
         spec = self.config.spec
         pools = dict(enumerate(zip(cache.k, cache.v)))
         h, pools, counts = hybrid.apply_hybrid_paged(
             params, pools, tokens, spec, page_size=self.page_size,
-            compute_dtype=self.config.dtype(), **kw)
+            compute_dtype=self.config.dtype(),
+            platform=self.mesh.devices.flat[0].platform, **kw)
         layers = range(spec.num_layers)
         return h, counts, HybridKVCache(
             k=tuple(pools[i][0] for i in layers),
@@ -185,7 +207,7 @@ class HybridEngine(InferenceEngine):
 
     def _prefill_paged_fn(self, bucket: int):
         """``(params, cache, tokens [1, bucket], length, base, g_table [1,
-        max_pages], w_table [1, R], request_id) -> ([next, assigned,
+        max_pages], w_table [1, R] or None, request_id) -> ([next, assigned,
         touched], logits [1, vocab] of the last real position, cache)``."""
         if bucket in self._prefill_fns:
             return self._prefill_fns[bucket]
@@ -214,7 +236,7 @@ class HybridEngine(InferenceEngine):
 
     def _decode_paged(self, pages: int):
         """``(params, cache, last_tokens [S], lengths [S], request_ids [S],
-        active [S], g_table [S, pages], w_table [S, R]) -> ([next [S],
+        active [S], g_table [S, pages], w_table [S, R] or None) -> ([next [S],
         assigned, touched], logits [S, vocab], cache)``. A slot that is
         not active writes nothing and counts nothing."""
         if pages in self._decode_paged_fns:
@@ -250,11 +272,12 @@ class HybridEngine(InferenceEngine):
             tokens = np.zeros((1, bucket), np.int32)
             tokens[0, :t] = prompt
             self._ensure_rows(slot, base + t)
-            self._slide_window(slot, base, base + t - 1)
+            if self.ring:
+                self._slide_window(slot, base, base + t - 1)
             fn = self._prefill_paged_fn(bucket)
             args = (jnp.asarray(tokens), jnp.int32(t), jnp.int32(base),
                     jnp.asarray(self.tables[slot:slot + 1]),
-                    jnp.asarray(self.win_tables[slot:slot + 1]),
+                    self._win_table(slice(slot, slot + 1)),
                     jnp.int32(request_id))
         out, logits = self._call("prefill", fn, args)
         self.last_counters = {"moe_assigned": int(out[1])}
@@ -262,13 +285,14 @@ class HybridEngine(InferenceEngine):
 
     def decode(self, last_tokens, lengths, request_ids, active, *,
                _pages: int | None = None):
-        """The base class's contract. Each active slot's window slides
-        first: the pages it has left are freed, the new row's mapped."""
+        """The base class's contract. Each active slot's window (where
+        the pattern has one) slides first: the pages it has left are
+        freed, the new row's mapped."""
         with span("engine.upload", kind="decode"):
             lengths_np = np.asarray(lengths, np.int32)
             active_np = np.asarray(active, bool)
             pb = self._decode_bucket(lengths_np, active_np, _pages)
-            if _pages is None:
+            if _pages is None and self.ring:
                 for s in np.nonzero(active_np)[0]:
                     at = int(lengths_np[s])
                     self._slide_window(int(s), at, at)
@@ -276,11 +300,16 @@ class HybridEngine(InferenceEngine):
             args = tuple(jnp.asarray(a) for a in (
                 np.asarray(last_tokens, np.int32), lengths_np,
                 np.asarray(request_ids, np.int32), active_np,
-                self.tables[:, :pb], self.win_tables))
+                self.tables[:, :pb])) + (self._win_table(),)
         out, logits = self._call("decode", fn, args)
         self.last_counters = {"moe_assigned": int(out[-2]),
-                              "moe_touched": int(out[-1]),
-                              "win_pages": self.window_pages_in_use}
+                              "moe_touched": int(out[-1])}
+        if self.ring:
+            self.last_counters["win_pages"] = self.window_pages_in_use
+        if self.counts_latent_rows:
+            # each active slot's query attends rows 0 .. its length
+            self.last_counters["latent_rows"] = int(
+                lengths_np[active_np].sum() + active_np.sum())
         return out[:-2], logits
 
 

@@ -367,7 +367,7 @@ class Scheduler:
         if role != "mixed" and not engine.handoff:
             raise ValueError(
                 f"role={role!r} needs an engine whose pages can be handed "
-                "off; this family's two page groups cannot yet"
+                "off; this family's page groups cannot yet"
             )
         if role == "prefill" and not engine.paged:
             raise ValueError(

@@ -10,11 +10,14 @@
   T = 8192, where the unhalved blocks are refused; the config gate
   that keeps a sequence the kernel would refuse from ever reaching it;
   and the paged decode-attention kernel at the serving cell's shape and
-  at an fp32 pool under a bucket no page group divides (ISSUE 31).
+  at an fp32 pool under a bucket no page group divides (ISSUE 31), and
+  the latent pool's at its cell's shape (ISSUE 32).
   One to four seconds each; skipped where the TPU compiler cannot
   describe the topology. Beside them the dense paged forward at the
   serving cell's widths, decode and prefill: no layer's pool is copied
-  out of the stacked pool or back (ISSUE 29). The persistent compile
+  out of the stacked pool or back (ISSUE 29); and the second family's
+  decode over latent layers at its cell's widths: no pool is copied, no
+  view gathered (ISSUE 32). The persistent compile
   cache is off for the whole suite (conftest) — a described-device
   compile can be written to it but never read back without a chip.
   Whole-step compiles
@@ -98,11 +101,25 @@ def _compile_paged_decode(chip, dtype, slots, bucket, pool=(16, 512, 64),
         on((slots,), jnp.int32), on((), jnp.int32)).compile()
 
 
+def _compile_latent_decode(chip, slots, bucket, pool=(8192, 64, 640),
+                           heads=64, v_width=512):
+    from ddl_tpu.ops.paged_attention import (latent_decode_attention,
+                                             latent_kernel_accepts)
+
+    assert latent_kernel_accepts(heads, pool[2], v_width, pool[1])
+    on = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=chip)
+    return jax.jit(lambda *a: latent_decode_attention(
+        *a, scale=0.13, v_width=v_width)).lower(
+        on((slots, heads, pool[2]), jnp.bfloat16), on(pool, jnp.bfloat16),
+        on((slots, bucket), jnp.int32), on((slots,), jnp.int32)).compile()
+
+
 @pytest.mark.parametrize("case", [
     "adam_full_vector", "adam_quarter_shard", "flash_lm_shape",
     "flash_t4096", "flash_refused_below_block", "flash_cell_shape",
     "flash_t384", "flash_t1152", "flash_head_dim_512",
     "paged_decode_cell_shape", "paged_decode_fp32_odd_bucket",
+    "latent_decode_cell_shape",
 ])
 def test_kernels_compile_for_v5e(v5e, case):
     if case == "flash_refused_below_block":
@@ -136,10 +153,13 @@ def test_kernels_compile_for_v5e(v5e, case):
         "paged_decode_fp32_odd_bucket": lambda: _compile_paged_decode(
             v5e, jnp.float32, 4, 5, pool=(2, 16, 16), heads=16,
             head_dim=128),
+        # 64 heads of 640 over 8,192 pages of 64 rows, the widest bucket.
+        "latent_decode_cell_shape": lambda: _compile_latent_decode(
+            v5e, 64, 272),
     }[case]()
     # The kernel is in the program, not a reference twin.
     assert "tpu_custom_call" in compiled.as_text()
-    if case.startswith("paged_decode"):
+    if "_decode_" in case:
         # The kernel's view of the stack is the stack's own bytes.
         assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 20
 
@@ -249,6 +269,57 @@ def test_paged_forward_writes_the_stacked_pool_in_place(v5e, program):
         assert compiled.memory_analysis().temp_size_in_bytes < 250e6
     else:
         assert "paged_decode_attention" not in hlo
+
+
+def test_latent_decode_reads_its_pool_in_place(v5e):
+    """A decode tick of ``models.hybrid`` over latent layers at the
+    widths of ``serve-k2-closed64-long`` (64 heads, rows of 576 in pools
+    of 8,192 pages x 64 rows x 640, bf16; the dense layer and one routed
+    layer; 64 slots at the widest bucket, 272 pages), pools donated,
+    compiled for the described v5e: no pool (671 MB) is copied, each
+    layer's attention is one ``latent_decode_attention`` kernel, no view
+    ``[64, 17408, 640]`` (1.43 GB a layer) is gathered, and the program
+    holds under 100 MB of temporaries (51 MB at five layers). A pool of
+    576-value rows, 4.5 lane tiles, is kept by the chip with the pages
+    minor-most and was copied in and out, whole, for every layer
+    (``serve.cache.latent_pool_width``)."""
+    import dataclasses
+
+    from ddl_tpu.models import hybrid
+    from ddl_tpu.serve.cache import latent_pool_width
+
+    spec = dataclasses.replace(
+        hybrid.NAMED_SPECS["kimi-k2-ep32"],
+        layer_kinds=(hybrid.LATENT,) * 2,
+        ffn_kinds=(hybrid.DENSE, hybrid.MOE))
+    pages, page, slots, bucket = 8192, 64, 64, 272
+    on = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=v5e)
+    params = jax.tree.map(
+        lambda a: on(a.shape, jnp.bfloat16), jax.eval_shape(
+            lambda: hybrid.init_hybrid_params(jax.random.PRNGKey(0), spec)))
+    width = latent_pool_width(spec)
+    assert width == 640
+    pools = {i: (on((pages, page, width), jnp.bfloat16), None)
+             for i in range(spec.num_layers)}
+
+    def run(params, pools, last_tokens, lengths, active, g_table):
+        positions = jnp.where(active, lengths, -1)
+        h, pools, counts = hybrid.apply_hybrid_paged(
+            params, pools, last_tokens[:, None], spec, page_size=page,
+            g_table=g_table, w_table=None, positions=positions[:, None],
+            real=active[:, None], last=positions,
+            compute_dtype=jnp.bfloat16, platform="tpu")
+        return hybrid.head_logits(params, h[:, 0]), pools, counts
+
+    compiled = jax.jit(run, donate_argnums=(1,)).lower(
+        params, pools, on((slots,), jnp.int32), on((slots,), jnp.int32),
+        on((slots,), jnp.bool_), on((slots, bucket), jnp.int32)).compile()
+    hlo = compiled.as_text()
+    assert _pool_sized_copies(hlo, pages * page * width) == []
+    assert sum("custom-call(" in line and "latent_decode_attention" in line
+               for line in hlo.splitlines()) == spec.num_layers
+    assert f"[{slots},{bucket * page},{width}]" not in hlo
+    assert compiled.memory_analysis().temp_size_in_bytes < 100e6
 
 
 @pytest.mark.slow

@@ -191,3 +191,151 @@ def test_apply_lm_paged_reads_pages_in_place_where_the_kernel_fits():
 ])
 def test_kernel_accepts_whole_tiles_only(heads, head_dim, page, fits):
     assert kernel_accepts(heads, head_dim, page) is fits
+
+
+# -- the latent pool: one K/V head, V inside K (ISSUE 32) ----------------------
+#
+# ``latent_decode_attention`` against ``gather_pages`` + ``attend_grouped``
+# over ONE K/V head whose V is the row's first ``v_width`` values: the path
+# it replaces on the chip in ``models.hybrid``'s decode.
+
+LP, LS, LW, LV, LH, LB, LTP = 24, 16, 256, 128, 8, 4, 6
+
+
+def _latent_case(name):
+    """``(table [LB, LTP], q_pos [LB], slots that read nothing)``; the
+    last slot is always free."""
+    table = np.full((LB, LTP), -1, np.int32)
+    q_pos = np.full(LB, -1, np.int32)
+
+    def fill(slot, pages, length):
+        table[slot, :len(pages)] = pages
+        q_pos[slot] = length - 1
+
+    fill(1, [1], LS)
+    if name == "partly_filled_last_page":
+        fill(0, [3, 5, 7], 2 * LS + 9)
+        fill(2, [9], 1)
+    elif name == "slot_without_pages":
+        fill(0, [3, 5], 2 * LS)
+    elif name == "widest_slot_fills_the_bucket":
+        fill(0, [2, 4, 6, 8, 10, 12], 6 * LS)
+        fill(2, [9, 11], LS + 1)
+    elif name == "inactive_slot_keeps_its_pages":
+        fill(0, [3, 5, 7], 2 * LS + 9)
+        table[2, :2] = [9, 11]   # mid-prefill: mapped, q_pos -1
+    elif name == "hole_in_the_table":
+        fill(0, [3, 5, 7], 2 * LS + 9)
+        table[0, 1] = -1
+        fill(2, [9], 2)
+    else:
+        raise KeyError(name)
+    return table, q_pos, [b for b in range(LB) if q_pos[b] < 0]
+
+
+@pytest.mark.parametrize("pages_per_step", [4, 3])
+@pytest.mark.parametrize("dtype,tol", [("float32", 1e-5), ("bfloat16", 2e-2)])
+@pytest.mark.parametrize("case", [
+    "partly_filled_last_page", "slot_without_pages",
+    "widest_slot_fills_the_bucket", "inactive_slot_keeps_its_pages",
+    "hole_in_the_table",
+])
+def test_latent_kernel_matches_gather_and_attend(case, dtype, tol,
+                                                 pages_per_step):
+    from ddl_tpu.ops.paged_attention import latent_decode_attention
+
+    table, q_pos, empty = _latent_case(case)
+    keys = jax.random.split(jax.random.PRNGKey(32), 2)
+    pool = jax.random.normal(keys[0], (LP, LS, LW), dtype)
+    q = jax.random.normal(keys[1], (LB, LH, LW), dtype)
+    got = latent_decode_attention(
+        q, pool, jnp.asarray(table), jnp.asarray(q_pos), scale=0.11,
+        v_width=LV, pages_per_step=pages_per_step, interpret=True)
+    assert got.dtype == q.dtype and got.shape == (LB, LH, LV)
+    f32 = lambda a: a.astype(jnp.float32)
+    view = f32(kv_cache.gather_pages(pool, jnp.asarray(table)))[:, :, None]
+    cols = np.arange(LTP * LS)[None]
+    k_pos = np.where((cols <= q_pos[:, None])
+                     & (np.repeat(table, LS, axis=1) >= 0), cols, -1)
+    want = kv_cache.attend_grouped(
+        f32(q)[:, None], view, view[..., :LV], jnp.asarray(q_pos)[:, None],
+        jnp.asarray(k_pos), scale=0.11)[:, 0]
+    got = np.asarray(got, np.float32)
+    live = [b for b in range(LB) if b not in empty]
+    np.testing.assert_allclose(got[live], np.asarray(want)[live], atol=tol,
+                               rtol=0)
+    assert (got[empty] == 0).all()
+
+
+@pytest.mark.parametrize("heads,row,v,page,fits", [
+    (64, 640, 512, 64, True), (8, 256, 128, 16, True),
+    (64, 576, 512, 64, False),   # 4.5 lane tiles: the pool is 640 wide
+    (4, 256, 128, 16, False),    # half a sublane tile of heads
+    (8, 128, 16, 16, False),     # the toys' kv_lora: V under a lane tile
+    (8, 256, 128, 8, False),     # a page under bf16's 16-row tile
+])
+def test_latent_kernel_accepts_whole_tiles_only(heads, row, v, page, fits):
+    from ddl_tpu.ops.paged_attention import latent_kernel_accepts
+
+    assert latent_kernel_accepts(heads, row, v, page) is fits
+
+
+def _latent_decode(platform, monkeypatch=None):
+    """One decode tick of ``models.hybrid``'s paged forward over latent
+    layers whose widths the kernel takes (8 heads, rows of 144 in a pool
+    of 256, V of 128, pages of 16), 3 slots of which one is inactive."""
+    import functools
+
+    from ddl_tpu.models import hybrid
+    from ddl_tpu.ops import paged_attention
+    from ddl_tpu.serve.cache import hybrid_cache
+
+    spec = hybrid.HybridSpec(
+        vocab=32, d_model=32, num_heads=8, head_dim=24, v_head_dim=8,
+        q_lora_rank=16, kv_lora_rank=128, nope_dim=8, rope_dim=16,
+        rope_factor=4.0, rope_original=64, rope_beta_fast=8.0,
+        rope_mscale_all_dim=1.0, d_ff=32, layer_kinds=(hybrid.LATENT,) * 2,
+        ffn_kinds=(hybrid.DENSE,) * 2)
+    params = hybrid.init_hybrid_params(jax.random.PRNGKey(3), spec)
+    cache = hybrid_cache(spec, 12, 0, 16, jnp.float32)
+    pools = {i: (jax.random.normal(jax.random.PRNGKey(i), p.shape)
+                 .at[..., spec.latent_row:].set(0.0), None)
+             for i, p in enumerate(cache.k)}
+    table = jnp.asarray([[3, 5, 7, -1], [1, -1, -1, -1], [9, 11, -1, -1]])
+    active = jnp.asarray([True, True, False])
+    positions = jnp.where(active, jnp.asarray([40, 9, 20]), -1)
+    if monkeypatch is not None:
+        monkeypatch.setattr(
+            paged_attention, "latent_decode_attention", functools.partial(
+                paged_attention.latent_decode_attention, interpret=True))
+
+    def forward(params, pools, tokens):
+        return hybrid.apply_hybrid_paged(
+            params, pools, tokens, spec, page_size=16, g_table=table,
+            w_table=None, positions=positions[:, None],
+            real=active[:, None], last=positions, platform=platform)
+
+    return forward, (params, pools, jnp.asarray([[1], [2], [3]]))
+
+
+def test_hybrid_decode_reads_latent_pages_in_place_on_a_tpu(monkeypatch):
+    """The rule ``PagedMixer`` chooses by: on a TPU, at widths the kernel
+    takes, a latent layer's decode is one ``latent_decode_attention`` a
+    layer (one traced kernel) and no gathered view; the CPU gathers. Both
+    give the same rows, pools and hidden state."""
+    forward, args = _latent_decode("tpu", monkeypatch)
+    trace = str(jax.make_jaxpr(forward)(*args))
+    assert len(re.findall(r"jit\[\s*name=latent_decode_attention",
+                          trace)) == 2
+    assert trace.count("pallas_call[") == 1
+    assert "f32[3,64,256]" not in trace        # the gathered view
+    plain, _ = _latent_decode("cpu")
+    assert "f32[3,64,256]" in str(jax.make_jaxpr(plain)(*args))
+    assert "latent_decode_attention" not in str(jax.make_jaxpr(plain)(*args))
+    h, pools, _ = forward(*args)
+    want_h, want_pools, _ = plain(*args)
+    live = np.asarray([0, 1])
+    np.testing.assert_allclose(np.asarray(h)[live], np.asarray(want_h)[live],
+                               atol=2e-5)
+    np.testing.assert_array_equal(pools[0][0], want_pools[0][0])
+    np.testing.assert_allclose(pools[1][0], want_pools[1][0], atol=2e-5)
