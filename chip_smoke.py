@@ -528,7 +528,8 @@ def phase_serve(sz: Sizes, seed: int) -> dict:
         rows = []
         for eng in (paged, flat):
             eng.reset()
-            rows.append(eng.prefill(prefix, slot=0, request_id=rid)[1][-1])
+            rows.append(eng.prefill(prefix, slot=0, request_id=rid,
+                                    want_logits=True)[1][-1])
         gap = float(np.max(np.abs(rows[0] - rows[1])))
         out["fp32_paged"]["first_split"] = {
             "request": rid, "position": pos,

@@ -262,12 +262,17 @@ def apply_lm_cached(
     rows: jax.Array | None = None,
     compute_dtype=None,
     row_reduce=None,
+    last_row: jax.Array | None = None,
 ) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
     """Incremental (KV-cached) forward — the serving twin of
     :func:`apply_lm`: int tokens ``[B, T]`` -> fp32 logits
     ``[B, T, vocab]`` plus the updated cache. ``T`` is the number of NEW
     sequence elements per slot (a whole prompt at prefill, one token per
     decode step); everything already processed lives in the cache.
+    With ``last_row`` (a traced scalar) the head is applied to that one
+    of the ``T`` rows alone and the logits are ``[B, 1, vocab]``: a
+    prefill samples from its last real row, so the ``[T, vocab]``
+    product is never formed.
 
     ``cache_k``/``cache_v [num_layers, B, C, H, D]`` are the per-layer
     ring buffers and ``cache_pos [B, C]`` the absolute position each row
@@ -333,9 +338,16 @@ def apply_lm_cached(
             jax.nn.gelu(x @ blk["w1"] + blk["b1"]) @ blk["w2"]
         ) + blk["b2"]
 
+    return _head_logits(params, h, last_row), cache_k, cache_v, cache_pos
+
+
+def _head_logits(params, h, last_row=None):
+    """The final norm and the head of the cached forwards: fp32 logits
+    of every row of ``h [B, T, E]``, or of row ``last_row`` alone."""
+    if last_row is not None:
+        h = jax.lax.dynamic_slice_in_dim(h, last_row, 1, axis=1)
     h = _layernorm(h, params["lnf_g"], params["lnf_b"])
-    logits = (h @ params["head"]).astype(jnp.float32)
-    return logits, cache_k, cache_v, cache_pos
+    return (h @ params["head"]).astype(jnp.float32)
 
 
 def apply_lm_paged(
@@ -354,9 +366,11 @@ def apply_lm_paged(
     pool_k_scale: jax.Array | None = None,
     pool_v_scale: jax.Array | None = None,
     platform: str | None = None,
+    last_row: jax.Array | None = None,
 ) -> tuple[jax.Array, ...]:
     """Incremental forward against the PAGED (block-table) KV pool — the
-    same layer math as :func:`apply_lm_cached`, with the per-slot ring
+    same layer math as :func:`apply_lm_cached` (``last_row`` likewise:
+    logits ``[B, 1, vocab]`` of that row alone), with the per-slot ring
     replaced by one shared pool read/written through a block table:
 
     ``pool_k``/``pool_v [num_layers, pages, page_size, H, D]`` and
@@ -494,8 +508,7 @@ def apply_lm_paged(
             jax.nn.gelu(x @ blk["w1"] + blk["b1"]) @ blk["w2"]
         ) + blk["b2"]
 
-    h = _layernorm(h, params["lnf_g"], params["lnf_b"])
-    logits = (h @ params["head"]).astype(jnp.float32)
+    logits = _head_logits(params, h, last_row)
     if quantized:
         return (logits, pool_k, pool_v, pool_pos,
                 pool_k_scale, pool_v_scale)

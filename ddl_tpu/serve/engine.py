@@ -389,6 +389,9 @@ class InferenceEngine(EngineHost):
             self.params = self._place(params)
         self._row_reduce = coll.tp_allreduce(TP_AXIS) if tp > 1 else None
         self._prefill_fns: dict[int, object] = {}
+        # The all-rows prefill form (a caller asked for logits): built
+        # on the first such call of a bucket, never by warmup.
+        self._prefill_rows_fns: dict[int, object] = {}
         self._decode_fn = None
         self._decode_paged_fns: dict[int, object] = {}
         self._copy_in = None  # pool slot -> cache slot (prefix hit)
@@ -532,27 +535,33 @@ class InferenceEngine(EngineHost):
         slot slice, decode the ``[slots, 1]`` batch."""
         cfg = self.config
 
-        def body(params, cache: KVCache, tokens, start, positions, rows=None):
+        def body(params, cache: KVCache, tokens, start, positions, rows=None,
+                 last_row=None):
             logits, k, v, pos = transformer.apply_lm_cached(
                 params, tokens, cache.k, cache.v, cache.pos, cfg.spec,
                 start=start, positions=positions, rows=rows,
                 compute_dtype=cfg.dtype(), row_reduce=self._row_reduce,
+                last_row=last_row,
             )
             return logits, KVCache(k=k, v=v, pos=pos)
 
         return body
 
-    def _prefill_fn(self, bucket: int):
+    def _prefill_fn(self, bucket: int, all_rows: bool = False):
         """Compiled prefill for prompt blocks padded to ``bucket``
         tokens: ``(params, cache, tokens [1, bucket], length, base,
-        slot, request_id) -> (next_token, logits [bucket, vocab],
-        cache)``. ``base`` is the slot's position offset — 0 for a whole
+        slot, request_id) -> (next_token, logits [1, vocab], cache)``:
+        the head is applied to the last real row alone, the one the
+        token is sampled from. ``all_rows`` is the other form, for a
+        caller that asked for logits: ``logits [bucket, vocab]`` of
+        every row, a program of its own (``run_prefill_rows_c<bucket>``).
+        ``base`` is the slot's position offset — 0 for a whole
         prompt, the copied-prefix length after a prefix-cache hit, the
         running offset for chunk 2+ of a chunked prefill. One program
-        per bucket covers every ``(length, base)``."""
-        if bucket in self._prefill_fns:
-            return self._prefill_fns[bucket]
-        cfg = self.config
+        per bucket and form covers every ``(length, base)``."""
+        fns = self._prefill_rows_fns if all_rows else self._prefill_fns
+        if bucket in fns:
+            return fns[bucket]
         fwd = self._shard_forward()
 
         def shard_body(params, cache: KVCache, tokens, length, base, slot):
@@ -580,7 +589,8 @@ class InferenceEngine(EngineHost):
             positions = jnp.where(real, base + t, PAD_POS)[None, :]
             rows = jnp.where(real, (base + t) % C, C)[None, :]
             logits, sl = fwd(params, sl, tokens,
-                             jnp.zeros((1,), jnp.int32), positions, rows)
+                             jnp.zeros((1,), jnp.int32), positions, rows,
+                             None if all_rows else length - 1)
             cache = KVCache(
                 k=lax.dynamic_update_slice_in_dim(cache.k, sl.k, slot, axis=1),
                 v=lax.dynamic_update_slice_in_dim(cache.v, sl.v, slot, axis=1),
@@ -597,12 +607,22 @@ class InferenceEngine(EngineHost):
             out_specs=(P_(), self._cspecs),
             check_vma=False,
         )
+        fns[bucket] = self._prefill_program(shard, bucket, all_rows, "c")
+        return fns[bucket]
 
-        def run(params, cache, tokens, length, base, slot, request_id):
-            logits, cache = shard(params, cache, tokens, length, base, slot)
-            last = lax.dynamic_index_in_dim(
-                logits, length - 1, axis=0, keepdims=False
-            )
+    def _prefill_program(self, shard, bucket: int, all_rows: bool,
+                         layout: str):
+        """Either layout's prefill program around its ``shard``: sample
+        from the last real row's logits, name, jit and ledger it.
+        ``where`` is the slot (contiguous) or its table (paged)."""
+
+        def run(params, cache, tokens, length, base, where, request_id):
+            logits, cache = shard(params, cache, tokens, length, base, where)
+            last = logits[0]
+            if all_rows:
+                last = lax.dynamic_index_in_dim(
+                    logits, length - 1, axis=0, keepdims=False
+                )
             # The sampled token is sequence element `base + length` of
             # this request — the token_index the PRNG key folds in (only
             # the block ending at the prompt's last token uses it; the
@@ -610,13 +630,13 @@ class InferenceEngine(EngineHost):
             nxt = self._sample(last, request_id, base + length)
             return nxt, logits, cache
 
+        kind, form = ("prefill_rows", "rows_") if all_rows else ("prefill", "")
         fn = self._ledgered(
-            "prefill", bucket,
-            jax.jit(_named(run, f"run_prefill_c{bucket}"),
+            kind, bucket,
+            jax.jit(_named(run, f"run_prefill_{form}{layout}{bucket}"),
                     donate_argnums=donation_for(self.mesh, 1)),
         )
-        self._prefill_fns[bucket] = fn
-        self._note_compile("prefill", bucket)
+        self._note_compile(kind, bucket)
         return fn
 
     def _decode(self):
@@ -662,7 +682,7 @@ class InferenceEngine(EngineHost):
     # -- paged compiled programs -------------------------------------------
 
     def _paged_forward(self, params, pool: PagedKVCache, tokens, table,
-                       *, positions, flat_rows):
+                       *, positions, flat_rows, last_row=None):
         """The one ``apply_lm_paged`` call both paged programs trace:
         routes the pool's scale planes in (and the updated planes back
         out) when the pool is int8 — a STATIC branch on
@@ -676,7 +696,7 @@ class InferenceEngine(EngineHost):
                 cfg.spec, positions=positions, flat_rows=flat_rows,
                 compute_dtype=cfg.dtype(), row_reduce=self._row_reduce,
                 pool_k_scale=pool.k_scale, pool_v_scale=pool.v_scale,
-                platform=platform,
+                platform=platform, last_row=last_row,
             )
             return logits, PagedKVCache(k=k, v=v, pos=pos,
                                         k_scale=ks, v_scale=vs)
@@ -684,23 +704,26 @@ class InferenceEngine(EngineHost):
             params, tokens, pool.k, pool.v, pool.pos, table, cfg.spec,
             positions=positions, flat_rows=flat_rows,
             compute_dtype=cfg.dtype(), row_reduce=self._row_reduce,
-            platform=platform,
+            platform=platform, last_row=last_row,
         )
         return logits, PagedKVCache(k=k, v=v, pos=pos)
 
-    def _prefill_paged_fn(self, bucket: int):
+    def _prefill_paged_fn(self, bucket: int, all_rows: bool = False):
         """Paged prefill for prompt blocks padded to ``bucket`` tokens:
         ``(params, pool, tokens [1, bucket], length, base,
         table [1, max_pages], request_id) -> (next_token,
-        logits [bucket, vocab], pool)``. Same sampling/offset contract
+        logits [1, vocab], pool)``, or ``logits [bucket, vocab]`` in
+        the ``all_rows`` form (``run_prefill_rows_b<bucket>``). Same
+        sampling/offset/form contract
         as the contiguous ``_prefill_fn`` — writes route through the
         slot's block table instead of a slot slice, padded tails map
         OUT OF BOUNDS (dropped), and the table is passed at its FULL
         width (prefill is matmul-bound; the page-count bucket ladder is
         the DECODE program's lever, where attend length is the per-token
         cost)."""
-        if bucket in self._prefill_fns:
-            return self._prefill_fns[bucket]
+        fns = self._prefill_rows_fns if all_rows else self._prefill_fns
+        if bucket in fns:
+            return fns[bucket]
         ps, num_pages = self.page_size, self.num_pages
         reach = self.max_pages * ps
         from ..ops import kv_cache as kvc
@@ -717,7 +740,7 @@ class InferenceEngine(EngineHost):
             flat = kvc.table_rows(table, logical, ps, num_pages)
             logits, pool = self._paged_forward(
                 params, pool, tokens, table, positions=positions,
-                flat_rows=flat,
+                flat_rows=flat, last_row=None if all_rows else length - 1,
             )
             return logits[0], pool
 
@@ -728,23 +751,8 @@ class InferenceEngine(EngineHost):
             out_specs=(P_(), self._pcspecs),
             check_vma=False,
         )
-
-        def run(params, pool, tokens, length, base, table, request_id):
-            logits, pool = shard(params, pool, tokens, length, base, table)
-            last = lax.dynamic_index_in_dim(
-                logits, length - 1, axis=0, keepdims=False
-            )
-            nxt = self._sample(last, request_id, base + length)
-            return nxt, logits, pool
-
-        fn = self._ledgered(
-            "prefill", bucket,
-            jax.jit(_named(run, f"run_prefill_b{bucket}"),
-                    donate_argnums=donation_for(self.mesh, 1)),
-        )
-        self._prefill_fns[bucket] = fn
-        self._note_compile("prefill", bucket)
-        return fn
+        fns[bucket] = self._prefill_program(shard, bucket, all_rows, "b")
+        return fns[bucket]
 
     def _decode_paged(self, pages: int):
         """Paged decode at page-count bucket ``pages`` — THE paged perf
@@ -1020,15 +1028,19 @@ class InferenceEngine(EngineHost):
     # -- host API ----------------------------------------------------------
 
     def prefill(self, prompt, *, slot: int, request_id: int, base: int = 0,
-                _bucket: int | None = None):
+                _bucket: int | None = None, want_logits: bool = False):
         """Prefill one prompt BLOCK into ``slot``: writes rows
         ``base..base+t-1`` (positions likewise), samples sequence
         element ``base + t``. ``base == 0`` with the whole prompt is
         classic admission; ``base > 0`` resumes after a prefix-cache
         copy or an earlier chunk — the sampled token is only meaningful
         when the block ends at the prompt's last token. Returns
-        ``(next_token int, logits np [t, vocab])`` — the logits of
-        every position in the block, for parity pinning and scoring.
+        ``(next_token int, None)``: the program applies the head to the
+        block's last real row alone and nothing but the sampled id
+        leaves the device. With ``want_logits`` the second member is
+        ``logits np [t, vocab]`` — every position in the block, for
+        parity pinning and scoring — from the all-rows program, built
+        on the first such call of a bucket.
         ``_bucket`` forces a larger bucket than ``t`` needs — the
         warmup ladder's compile trigger, so compiling a big bucket
         costs one real row (and, paged, one page) instead of a full
@@ -1037,22 +1049,21 @@ class InferenceEngine(EngineHost):
         with span("engine.upload", kind="prefill"):
             tokens = np.zeros((1, bucket), np.int32)
             tokens[0, :t] = prompt
-            if self.paged:
-                self._ensure_rows(slot, base + t)
-                fn = self._prefill_paged_fn(bucket)
-                where = jnp.asarray(self.tables[slot:slot + 1])
-            else:
-                fn = self._prefill_fn(bucket)
-                where = jnp.int32(slot)
+            where = self._prefill_where(slot, base, t)
+            fn = (self._prefill_paged_fn if self.paged
+                  else self._prefill_fn)(bucket, want_logits)
             args = (jnp.asarray(tokens), jnp.int32(t), jnp.int32(base),
-                    where, jnp.int32(request_id))
-        nxt, logits = self._call("prefill", fn, args)
-        return int(nxt), logits[:t]
+                    *where, jnp.int32(request_id))
+        out, logits = self._call("prefill", fn, args, want_logits)
+        self._counted("prefill", out[1:])
+        return int(out[0]), logits[:t] if want_logits else None
 
     def decode(self, last_tokens, lengths, request_ids, active, *,
-               _pages: int | None = None):
+               _pages: int | None = None, want_logits: bool = False):
         """One batched decode step over all slots. Host arrays in,
-        ``(next_tokens np [S], logits np [S, vocab])`` out; the fetch is
+        ``(next_tokens np [S], None)`` out, or ``(next_tokens, logits np
+        [S, vocab])`` with ``want_logits`` (the same program: its logits
+        stay on the device unless asked for); the fetch of the tokens is
         the step's true barrier (latency timing hangs off it).
 
         Paged mode runs the program of ``EngineHost._decode_bucket``:
@@ -1061,29 +1072,49 @@ class InferenceEngine(EngineHost):
         with span("engine.upload", kind="decode"):
             lengths_np = np.asarray(lengths, np.int32)
             active_np = np.asarray(active, bool)
-            tables = ()
-            if self.paged:
-                pb = self._decode_bucket(lengths_np, active_np, _pages)
-                fn = self._decode_paged(pb)
-                tables = (self.tables[:, :pb],)
-            else:
-                fn = self._decode()
+            fn, where = self._decode_where(lengths_np, active_np, _pages)
             args = tuple(jnp.asarray(a) for a in (
                 np.asarray(last_tokens, np.int32), lengths_np,
-                np.asarray(request_ids, np.int32), active_np, *tables))
-        return self._call("decode", fn, args)
+                np.asarray(request_ids, np.int32), active_np)) + where
+        out, logits = self._call("decode", fn, args, want_logits)
+        slots = self.config.slots
+        self._counted("decode", out[slots:], lengths_np, active_np)
+        return out[:slots], logits
 
-    def _call(self, kind: str, fn, args):
+    def _prefill_where(self, slot: int, base: int, t: int) -> tuple:
+        """Make room for rows ``base..base+t-1`` of ``slot``; the
+        prefill program's arguments that say where they go."""
+        if not self.paged:
+            return (jnp.int32(slot),)
+        self._ensure_rows(slot, base + t)
+        return (jnp.asarray(self.tables[slot:slot + 1]),)
+
+    def _decode_where(self, lengths, active, _pages) -> tuple:
+        """Make room for each active slot's next row; the decode program
+        of the bucket that covers them and its table arguments."""
+        if not self.paged:
+            return self._decode(), ()
+        pb = self._decode_bucket(lengths, active, _pages)
+        return self._decode_paged(pb), (jnp.asarray(self.tables[:, :pb]),)
+
+    def _counted(self, kind: str, counts, lengths=None, active=None) -> None:
+        """What a program returned behind its sampled ids (``counts``);
+        the dense family's programs count nothing."""
+
+    def _call(self, kind: str, fn, args, want_logits: bool):
         """Run one compiled program on the placed params and the cache
-        and bring back what it returns, ``(next np, logits np)``, as
-        three phases: the dispatch returns before the device ends, the
-        fetch of ``next`` blocks until it has, the fetch of the logits
-        is then a plain transfer. The ``engine.*`` spans have no tracer:
+        and bring back what the host reads of it, ``(out np [n], logits
+        np or None)``: ``out`` is the small array that starts with the
+        sampled ids, the logits come only if the caller asked in this
+        call. Three phases: the dispatch returns before the device
+        ends, the fetch of ``out`` blocks until it has, the fetch of
+        the logits, if any, is then a plain transfer; its span is
+        entered on every call. The ``engine.*`` spans have no tracer:
         they reach the profiler alone and read no clock."""
         with span("engine.dispatch", kind=kind):
-            nxt, logits, self.cache = fn(self.params, self.cache, *args)
+            out, logits, self.cache = fn(self.params, self.cache, *args)
         with span("engine.wait", kind=kind):
-            nxt = np.asarray(nxt)
+            out = np.asarray(out).reshape(-1)
         with span("engine.fetch_logits", kind=kind):
-            logits = np.asarray(logits)
-        return nxt, logits
+            logits = np.asarray(logits) if want_logits else None
+        return out, logits

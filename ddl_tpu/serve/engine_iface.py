@@ -61,11 +61,20 @@ class ServeEngine(Protocol):
     kind: str
 
     # -- compute ticks ------------------------------------------------------
+    # Both return a pair ``(sampled, logits)``: ``sampled`` is the next
+    # token (prefill: an int; decode: ``np [slots]``), all that leaves
+    # the device and all the control plane reads. ``logits`` is ``None``
+    # unless the caller passed ``want_logits=True`` IN THAT CALL (parity
+    # tests, scoring; never the scheduler, never warmup): then prefill
+    # gives ``np [t, vocab]`` of the block's rows (the hybrid family:
+    # ``[1, vocab]``, its last row) and decode ``np [slots, vocab]``.
+    # What nobody asked for is neither fetched nor, in a prefill,
+    # computed beyond the row the token is sampled from.
     def prefill(self, prompt, *, slot: int, request_id: int, base: int = 0,
-                _bucket: int | None = None): ...
+                _bucket: int | None = None, want_logits: bool = False): ...
 
     def decode(self, last_tokens, lengths, request_ids, active, *,
-               _pages: int | None = None): ...
+               _pages: int | None = None, want_logits: bool = False): ...
 
     # -- shape/bucket ladders ----------------------------------------------
     def prefill_bucket(self, prompt_len: int) -> int: ...

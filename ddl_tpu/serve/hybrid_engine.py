@@ -25,9 +25,10 @@ the window group.
 
 Programs keep the names ``jit_run_prefill_b<bucket>`` and
 ``jit_run_decode_p<pages>`` (the global group's bucket; the window
-group's width is fixed). A prefill returns the last position's logits
-alone. Each program returns, behind the sampled tokens in the one small
-array the host waits for, the routed layers' ``(assigned, touched)``;
+group's width is fixed). A prefill computes the last position's logits
+alone, and a caller that asks for logits gets that row. Each program
+returns, behind the sampled tokens in the one small array the host
+waits for, the routed layers' ``(assigned, touched)``;
 :attr:`last_counters` holds them for the scheduler's spans, beside the
 host's own counts of a decode tick: ``win_pages`` (a pattern with window
 layers) and ``latent_rows`` (one with latent layers: the cached rows the
@@ -45,7 +46,6 @@ import numpy as np
 from jax import lax
 
 from ..models import hybrid
-from ..obs.trace import span
 from ..parallel.mesh import donation_for
 from .cache import HybridKVCache, PagePool, hybrid_cache, ring_columns
 from .engine import InferenceEngine, _named
@@ -205,10 +205,12 @@ class HybridEngine(InferenceEngine):
             k=tuple(pools[i][0] for i in layers),
             v=tuple(pools[i][1] for i in layers))
 
-    def _prefill_paged_fn(self, bucket: int):
+    def _prefill_paged_fn(self, bucket: int, all_rows: bool = False):
         """``(params, cache, tokens [1, bucket], length, base, g_table [1,
         max_pages], w_table [1, R] or None, request_id) -> ([next, assigned,
-        touched], logits [1, vocab] of the last real position, cache)``."""
+        touched], logits [1, vocab] of the last real position, cache)``.
+        The family's one form: a caller that asks for logits
+        (``all_rows``) gets that row."""
         if bucket in self._prefill_fns:
             return self._prefill_fns[bucket]
 
@@ -261,56 +263,35 @@ class HybridEngine(InferenceEngine):
         self._note_compile("decode", pages)
         return fn
 
-    # -- host API ----------------------------------------------------------
+    # -- host API: the base class's bracket, through its three hooks -------
 
-    def prefill(self, prompt, *, slot: int, request_id: int, base: int = 0,
-                _bucket: int | None = None):
-        """The base class's contract, except the logits: ``(next_token,
-        logits np [1, vocab])`` of the block's last position."""
-        prompt, t, bucket = self._prefill_block(prompt, base, _bucket)
-        with span("engine.upload", kind="prefill"):
-            tokens = np.zeros((1, bucket), np.int32)
-            tokens[0, :t] = prompt
-            self._ensure_rows(slot, base + t)
-            if self.ring:
-                self._slide_window(slot, base, base + t - 1)
-            fn = self._prefill_paged_fn(bucket)
-            args = (jnp.asarray(tokens), jnp.int32(t), jnp.int32(base),
-                    jnp.asarray(self.tables[slot:slot + 1]),
-                    self._win_table(slice(slot, slot + 1)),
-                    jnp.int32(request_id))
-        out, logits = self._call("prefill", fn, args)
-        self.last_counters = {"moe_assigned": int(out[1])}
-        return int(out[0]), logits
+    def _prefill_where(self, slot: int, base: int, t: int) -> tuple:
+        where = super()._prefill_where(slot, base, t)
+        if self.ring:
+            self._slide_window(slot, base, base + t - 1)
+        return where + (self._win_table(slice(slot, slot + 1)),)
 
-    def decode(self, last_tokens, lengths, request_ids, active, *,
-               _pages: int | None = None):
-        """The base class's contract. Each active slot's window (where
-        the pattern has one) slides first: the pages it has left are
-        freed, the new row's mapped."""
-        with span("engine.upload", kind="decode"):
-            lengths_np = np.asarray(lengths, np.int32)
-            active_np = np.asarray(active, bool)
-            pb = self._decode_bucket(lengths_np, active_np, _pages)
-            if _pages is None and self.ring:
-                for s in np.nonzero(active_np)[0]:
-                    at = int(lengths_np[s])
-                    self._slide_window(int(s), at, at)
-            fn = self._decode_paged(pb)
-            args = tuple(jnp.asarray(a) for a in (
-                np.asarray(last_tokens, np.int32), lengths_np,
-                np.asarray(request_ids, np.int32), active_np,
-                self.tables[:, :pb])) + (self._win_table(),)
-        out, logits = self._call("decode", fn, args)
-        self.last_counters = {"moe_assigned": int(out[-2]),
-                              "moe_touched": int(out[-1])}
+    def _decode_where(self, lengths, active, _pages) -> tuple:
+        """Each active slot's window (where the pattern has one) slides
+        too: the pages it has left are freed, the new row's mapped."""
+        fn, where = super()._decode_where(lengths, active, _pages)
+        if _pages is None and self.ring:
+            for s in np.nonzero(active)[0]:
+                at = int(lengths[s])
+                self._slide_window(int(s), at, at)
+        return fn, where + (self._win_table(),)
+
+    def _counted(self, kind: str, counts, lengths=None, active=None) -> None:
+        self.last_counters = {"moe_assigned": int(counts[0])}
+        if kind == "prefill":
+            return
+        self.last_counters["moe_touched"] = int(counts[1])
         if self.ring:
             self.last_counters["win_pages"] = self.window_pages_in_use
         if self.counts_latent_rows:
             # each active slot's query attends rows 0 .. its length
             self.last_counters["latent_rows"] = int(
-                lengths_np[active_np].sum() + active_np.sum())
-        return out[:-2], logits
+                lengths[active].sum() + active.sum())
 
 
 def engine_cls(spec) -> type[InferenceEngine]:

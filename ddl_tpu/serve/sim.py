@@ -210,7 +210,7 @@ class CostModelEngine(EngineHost):
     # -- host API ----------------------------------------------------------
 
     def prefill(self, prompt, *, slot: int, request_id: int, base: int = 0,
-                _bucket: int | None = None):
+                _bucket: int | None = None, want_logits: bool = False):
         _, t, _ = self._prefill_block(prompt, base, _bucket)
         if self.paged:
             self._ensure_rows(slot, base + t)
@@ -218,10 +218,11 @@ class CostModelEngine(EngineHost):
         self.virtual["prefill"] += t * self.cost.prefill_s_per_token
         cfg = self.config
         nxt = _sim_token(cfg.seed, request_id, base + t, cfg.spec.vocab)
-        return nxt, np.zeros((t, cfg.spec.vocab), np.float32)
+        return nxt, (np.zeros((t, cfg.spec.vocab), np.float32)
+                     if want_logits else None)
 
     def decode(self, last_tokens, lengths, request_ids, active, *,
-               _pages: int | None = None):
+               _pages: int | None = None, want_logits: bool = False):
         cfg = self.config
         S = cfg.slots
         lengths_np = np.asarray(lengths, np.int64)
@@ -242,7 +243,8 @@ class CostModelEngine(EngineHost):
                                    int(lengths_np[s]) + 1)
             nxt[s] = _sim_token(cfg.seed, int(rids[s]),
                                 int(lengths_np[s]) + 1, cfg.spec.vocab)
-        return nxt, np.zeros((S, cfg.spec.vocab), np.float32)
+        return nxt, (np.zeros((S, cfg.spec.vocab), np.float32)
+                     if want_logits else None)
 
 
 def sim_engine_factory(cost: CostModel | None = None):

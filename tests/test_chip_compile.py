@@ -208,7 +208,8 @@ def _pool_sized_copies(hlo: str, at_least: int) -> list[str]:
 
 
 @pytest.mark.parametrize("program", ["decode_32x1_p32", "prefill_1x512",
-                                     "decode_32x1_p32_int8"])
+                                     "decode_32x1_p32_int8",
+                                     "prefill_1x512_one_row"])
 def test_paged_forward_writes_the_stacked_pool_in_place(v5e, program):
     """``apply_lm_paged`` at the widths of ``serve-1b-closed32`` (8 heads
     of 256, a bf16 pool of 512 pages x 64 rows, fp32 weights computed in
@@ -227,7 +228,12 @@ def test_paged_forward_writes_the_stacked_pool_in_place(v5e, program):
     to be the same bytes: a view the chip's tiled layout does not share
     (``[..., 64, 8 x 256]``) would show here as a copy of the whole
     pool. Prefill (512 queries a slot) and the int8 pool keep the
-    gathered path, and no kernel."""
+    gathered path, and no kernel.
+
+    The prefill the scheduler runs applies the head to its last real
+    row alone (ISSUE 33, ``last_row``): no ``[512, 50304]`` value, which
+    the all-rows form holds in fp32 (103 MB; 412 MB at the 2048 bucket)
+    for a host that dropped it."""
     from ddl_tpu.models.transformer import (LMSpec, apply_lm_paged,
                                             init_lm_params)
 
@@ -244,13 +250,16 @@ def test_paged_forward_writes_the_stacked_pool_in_place(v5e, program):
     layer_pool_bytes = pool.dtype.itemsize * math.prod(pool.shape[1:])
     assert layer_pool_bytes == (67_108_864 if int8 else 134_217_728)
 
+    one_row = program.endswith("one_row")
+
     def forward(params, pool_k, pool_v, pool_pos, k_scale, v_scale, tokens,
                 table, positions, flat_rows):
         return apply_lm_paged(
             params, tokens, pool_k, pool_v, pool_pos, table, spec,
             positions=positions, flat_rows=flat_rows,
             compute_dtype=jnp.bfloat16, pool_k_scale=k_scale,
-            pool_v_scale=v_scale, platform="tpu")
+            pool_v_scale=v_scale, platform="tpu",
+            last_row=positions[0, 0] if one_row else None)
 
     compiled = jax.jit(forward, donate_argnums=(1, 2, 3, 4, 5)).lower(
         _shapes_on(jax.eval_shape(
@@ -269,6 +278,9 @@ def test_paged_forward_writes_the_stacked_pool_in_place(v5e, program):
         assert compiled.memory_analysis().temp_size_in_bytes < 250e6
     else:
         assert "paged_decode_attention" not in hlo
+    if program.startswith("prefill"):
+        assert (f"f32[1,{t},{spec.vocab}]" in hlo) != one_row
+        assert (f"[{t},{spec.vocab}]" in hlo) != one_row
 
 
 def test_latent_decode_reads_its_pool_in_place(v5e):

@@ -61,7 +61,7 @@ def _record_decodes(eng, log):
     d0 = eng.decode
 
     def dec(*a, **k):
-        nxt, lg = d0(*a, **k)
+        nxt, lg = d0(*a, **k, want_logits=True)
         log.append(np.asarray(lg).copy())
         return nxt, lg
 

@@ -70,7 +70,8 @@ def decode_one(eng, slot, seq, request_id=7):
     active = np.zeros(slots, bool)
     last[slot], lengths[slot], active[slot] = seq[-1], len(seq) - 1, True
     nxt, logits = eng.decode(last, lengths,
-                             np.full(slots, request_id, np.int32), active)
+                             np.full(slots, request_id, np.int32), active,
+                             want_logits=True)
     return int(nxt[slot]), logits[slot]
 
 
@@ -89,7 +90,7 @@ def test_prefill_then_decode_agrees_with_reference(weights, chunks):
     base = 0
     for n in chunks:
         tok, logits = eng.prefill(prompt[base:base + n], slot=1,
-                                  request_id=7, base=base)
+                                  request_id=7, base=base, want_logits=True)
         base += n
         want = reference_logits(weights, prompt[:base])[-1]
         assert logits.shape == (1, SPEC.vocab)

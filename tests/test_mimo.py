@@ -82,7 +82,7 @@ def test_prefill_then_decode_agrees_with_reference(weights, chunks):
     base = 0
     for n in chunks:
         tok, logits = eng.prefill(prompt[base:base + n], slot=1,
-                                  request_id=7, base=base)
+                                  request_id=7, base=base, want_logits=True)
         base += n
         want = reference_logits(weights, prompt[:base])[-1]
         assert logits.shape == (1, SPEC.vocab)
@@ -94,7 +94,7 @@ def test_prefill_then_decode_agrees_with_reference(weights, chunks):
         active = np.zeros(slots, bool)
         last[1], lengths[1], active[1] = seq[-1], len(seq) - 1, True
         nxt, logits = eng.decode(last, lengths, np.full(slots, 7, np.int32),
-                                 active)
+                                 active, want_logits=True)
         want = reference_logits(weights, seq)[-1]
         np.testing.assert_allclose(logits[1], want, atol=TOL)
         assert int(nxt[1]) == int(np.argmax(logits[1]))

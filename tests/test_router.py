@@ -45,12 +45,12 @@ def _record_device_calls(eng, log):
     d0, p0 = eng.decode, eng.prefill
 
     def dec(*a, **k):
-        nxt, lg = d0(*a, **k)
+        nxt, lg = d0(*a, **k, want_logits=True)
         log.append(("decode", np.asarray(lg).copy()))
         return nxt, lg
 
     def pre(*a, **k):
-        nxt, lg = p0(*a, **k)
+        nxt, lg = p0(*a, **k, want_logits=True)
         log.append(("prefill", np.asarray(lg).copy()))
         return nxt, lg
 

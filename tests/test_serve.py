@@ -203,7 +203,8 @@ def test_engine_decode_parity(tp):
     prompt = synthesize_prompts(num=1, min_len=11, max_len=11,
                                 vocab=SPEC.vocab, seed=5)[0]
     p = len(prompt)
-    tok, prefill_logits = eng.prefill(prompt, slot=1, request_id=7)
+    tok, prefill_logits = eng.prefill(prompt, slot=1, request_id=7,
+                                      want_logits=True)
     seq = list(prompt) + [tok]
     logits_inc = [prefill_logits]
     last = np.zeros(2, np.int32)
@@ -212,7 +213,7 @@ def test_engine_decode_parity(tp):
     active = np.zeros(2, bool)
     for step in range(6):
         last[1], lengths[1], ids[1], active[1] = seq[-1], len(seq) - 1, 7, True
-        nxt, lg = eng.decode(last, lengths, ids, active)
+        nxt, lg = eng.decode(last, lengths, ids, active, want_logits=True)
         logits_inc.append(lg[1:2])
         seq.append(int(nxt[1]))
     inc = np.concatenate(logits_inc, axis=0)  # [p + 6, V]
@@ -340,7 +341,8 @@ def test_params_only_checkpoint_load_from_zero1_tp(tmp_path):
         eng = InferenceEngine(ServeConfig(spec=SPEC, slots=1, capacity=16,
                                           tensor_parallel=tp))
         eng.load_params(path)
-        _, logits = eng.prefill(prompt, slot=0, request_id=0)
+        _, logits = eng.prefill(prompt, slot=0, request_id=0,
+                                want_logits=True)
         np.testing.assert_allclose(logits, np.asarray(full),
                                    atol=2e-5, rtol=1e-4, err_msg=f"tp={tp}")
 
@@ -471,7 +473,8 @@ def test_chunked_prefill_logits_exactly_equal_one_shot(chunk):
     eng = InferenceEngine(ServeConfig(spec=SPEC, slots=1, capacity=64))
     prompt = synthesize_prompts(num=1, min_len=21, max_len=21,
                                 vocab=SPEC.vocab, seed=14)[0]
-    tok_full, logits_full = eng.prefill(prompt, slot=0, request_id=3)
+    tok_full, logits_full = eng.prefill(prompt, slot=0, request_id=3,
+                                        want_logits=True)
 
     def chunked(bucket):
         eng.reset()
@@ -479,7 +482,7 @@ def test_chunked_prefill_logits_exactly_equal_one_shot(chunk):
         for base in range(0, len(prompt), chunk):
             tok_last, lg = eng.prefill(prompt[base:base + chunk], slot=0,
                                        request_id=3, base=base,
-                                       _bucket=bucket)
+                                       _bucket=bucket, want_logits=True)
             got.append(lg)
         assert tok_last == tok_full  # same sampled element p
         return np.concatenate(got, axis=0)
@@ -509,10 +512,12 @@ def test_prefix_copy_then_tail_prefill_matches_full_prefill():
     hit = min(hit, len(b) - 1)
     # Reference: full prefill of b on a FRESH engine state.
     ref_eng = InferenceEngine(ServeConfig(spec=SPEC, slots=2, capacity=64))
-    tok_ref, logits_ref = ref_eng.prefill(b, slot=1, request_id=7)
+    tok_ref, logits_ref = ref_eng.prefill(b, slot=1, request_id=7,
+                                          want_logits=True)
     # Reused path: copy the hit rows into slot 1, prefill only the tail.
     eng.prefix_fetch(entry, hit, 1)
-    tok, tail_logits = eng.prefill(b[hit:], slot=1, request_id=7, base=hit)
+    tok, tail_logits = eng.prefill(b[hit:], slot=1, request_id=7, base=hit,
+                                   want_logits=True)
     np.testing.assert_array_equal(tail_logits, logits_ref[hit:])
     assert tok == tok_ref
     eng.prefix_release(entry)

@@ -57,7 +57,8 @@ def _record_decode_rows(router, rows):
         d0 = eng.decode
 
         def dec(last, lengths, rids, act, *, _d0=d0, **kw):
-            nxt, lg = _d0(last, lengths, rids, act, **kw)
+            nxt, lg = _d0(last, lengths, rids, act, **kw,
+                          want_logits=True)
             lg = np.asarray(lg)
             for s in range(len(act)):
                 if act[s]:

@@ -296,14 +296,15 @@ def _capture_logits(eng):
     orig_prefill, orig_decode = eng.prefill, eng.decode
 
     def prefill(prompt, **kw):
-        tok, lg = orig_prefill(prompt, **kw)
+        tok, lg = orig_prefill(prompt, **kw, want_logits=True)
         base = kw.get("base", 0)
         for j in range(np.asarray(lg).shape[0]):
             rows[(kw["request_id"], base + j)] = np.asarray(lg)[j].copy()
         return tok, lg
 
     def decode(last, lengths, ids, active, **kw):
-        nxt, lg = orig_decode(last, lengths, ids, active, **kw)
+        nxt, lg = orig_decode(last, lengths, ids, active, **kw,
+                              want_logits=True)
         for s in np.nonzero(np.asarray(active, bool))[0]:
             key = (int(ids[s]), int(lengths[s]))
             rows[key] = np.asarray(lg)[s].copy()

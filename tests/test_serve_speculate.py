@@ -42,7 +42,7 @@ def _record_decode_rows(eng, rows):
     d0 = eng.decode
 
     def dec(last, lengths, rids, act, **kw):
-        nxt, lg = d0(last, lengths, rids, act, **kw)
+        nxt, lg = d0(last, lengths, rids, act, **kw, want_logits=True)
         lg = np.asarray(lg)
         for s in range(len(act)):
             if act[s]:
