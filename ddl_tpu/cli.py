@@ -420,8 +420,9 @@ def build_parser() -> argparse.ArgumentParser:
     sv.add_argument("--model-spec", default=None, metavar="NAME",
                     help="serve a named spec of the second decoder "
                          "family (models.hybrid.NAMED_SPECS: layers of "
-                         "three kinds, global, window and latent "
-                         "attention, with routed experts) in place of "
+                         "five kinds, global, window, latent, linear and "
+                         "block-sparse attention, with routed experts "
+                         "where the spec has them) in place of "
                          "the dense decoder the --d-model/--heads/"
                          "--layers flags describe; needs --page-size > 0 "
                          "(page groups)")

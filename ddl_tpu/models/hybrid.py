@@ -1,6 +1,7 @@
-"""The second decoder family: global, window and latent attention layers
-mixed by a per-layer pattern, routed experts (beside a shared one where
-the spec has it), written once over a cache view.
+"""The second decoder family: global, window, latent, linear and
+block-sparse attention layers mixed by a per-layer pattern, routed
+experts (beside a shared one where the spec has it), written once over
+a cache view.
 
 Where ``models.transformer`` writes its dense block three times (full,
 contiguous cache, paged cache), this family has ONE block function,
@@ -38,6 +39,27 @@ and an untied head with fp32 logits.
   query and the output instead (the absorbed form: ``num_heads`` query
   heads of ``kv_lora_rank + rope_dim`` over ONE K/V head whose V is the
   first ``kv_lora_rank`` values of K). Both are the same attention.
+- **Linear attention** (kind ``LINEAR``; ``ops.linear_attention``).
+  ``num_heads`` heads of ``head_dim``, as many K/V heads: ``q = RMS(x
+  W_q)``, ``k = RMS(x W_k)`` a head (gains ``qn``, ``kn`` of
+  ``head_dim``, shared by the heads), ``v = x W_v``; rotary on the whole
+  head of q and k (base ``rope_base_global``, pairs ``(i, i + head_dim
+  / 2)``); ``q`` times ``head_dim ** -0.5``. A head keeps a state ``S
+  [head_dim, v_head_dim]`` in fp32, ``S_t = lambda S_(t-1) + k_t^T
+  v_t``, ``o_t = q_t S_t``, and nothing else of a token. The mixer's
+  output is ``(sigmoid(x W_gate) * RMS(concat o)) W_o``: a norm over the
+  concatenated heads (gain ``on``) and a gate a value.
+- **Block-sparse attention** (kind ``SPARSE``; ``ops.sparse_attention``,
+  the sizes ``HybridSpec.selector``). ``kv_heads_global`` K/V heads, no
+  rotary, the same q/k norms, the output ``(sigmoid(x W_gate) * a)
+  W_o``. ``a`` is causal softmax attention over every earlier row up to
+  ``sparse_dense_len`` rows of context, and past it over the
+  ``sparse_topk`` blocks a selector without parameters picks for the
+  query from means of the K rows. It keeps K and V rows in one pool of
+  the global page group and the selector's means beside them.
+- **Scalings** (1 where a spec gives none): the embedding times
+  ``embed_scale``, each residual branch times ``residual_scale``, the
+  head's input times ``logit_scale``.
 - **FFN.** Dense gated SiLU of width ``d_ff``, or ``num_experts`` routed
   experts of width ``expert_ff``, ``experts_per_token`` a token
   (``ops.moe``), their weights times ``route_scale`` where the spec
@@ -56,10 +78,14 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..ops import kv_cache, moe, paged_attention
+from ..ops import (kv_cache, linear_attention, moe, paged_attention,
+                   sparse_attention)
 
 GLOBAL, WINDOW = 0, 1   # layer_kinds, as the published pattern counts them
 LATENT = 2              # a compressed row a token in the global page group
+LINEAR = 3              # no rows: a state a slot, [heads, head_dim, v_head_dim]
+SPARSE = 4              # rows in the global group, a selector's means beside
+GATED = (LINEAR, SPARSE)  # q/k norms a head, an output gate
 DENSE, MOE = 0, 1       # ffn_kinds
 
 
@@ -102,6 +128,19 @@ class HybridSpec:
     rope_beta_fast: float = 32.0
     rope_beta_slow: float = 1.0
     rope_mscale_all_dim: float = 0.0
+    # Scalings of the residual stream (1: none).
+    embed_scale: float = 1.0
+    residual_scale: float = 1.0
+    logit_scale: float = 1.0
+    # Sparse layers' selector (``ops.sparse_attention.Selector``): a
+    # block is the pool's page, a compressed key the mean of two
+    # neighbouring groups of ``sparse_stride`` rows.
+    sparse_block: int = 64
+    sparse_stride: int = 16
+    sparse_topk: int = 64
+    sparse_init: int = 1
+    sparse_local: int = 32
+    sparse_dense_len: int = 8192
 
     def __post_init__(self):
         if len(self.layer_kinds) != len(self.ffn_kinds):
@@ -129,6 +168,26 @@ class HybridSpec:
                     raise ValueError(f"a latent layer needs {name} >= 1")
             if self.rope_dim % 2:
                 raise ValueError(f"rope_dim ({self.rope_dim}) must be even")
+        if LINEAR in self.layer_kinds and self.head_dim % 2:
+            raise ValueError(f"a linear layer rotates the whole head: "
+                             f"head_dim ({self.head_dim}) must be even")
+        if SPARSE in self.layer_kinds:
+            sel = self.selector
+            if self.head_dim != self.v_head_dim:
+                raise ValueError("a sparse layer keeps K and V rows in one "
+                                 "pool: head_dim must equal v_head_dim")
+            if sel.block % sel.stride or sel.init + sel.local > sel.topk \
+                    or sel.dense_len < sel.topk * sel.block:
+                raise ValueError(
+                    f"{sel}: a block must be whole groups, the blocks "
+                    "always taken within topk, and dense_len at least "
+                    "topk blocks (past it a query has topk to choose)")
+
+    @property
+    def selector(self) -> sparse_attention.Selector:
+        return sparse_attention.Selector(
+            self.sparse_block, self.sparse_stride, self.sparse_topk,
+            self.sparse_init, self.sparse_local, self.sparse_dense_len)
 
     @property
     def num_layers(self) -> int:
@@ -139,8 +198,9 @@ class HybridSpec:
         return self.experts_held[1] - self.experts_held[0]
 
     def kv_heads(self, layer: int) -> int:
-        return (self.kv_heads_window if self.layer_kinds[layer] == WINDOW
-                else self.kv_heads_global)
+        kind = self.layer_kinds[layer]
+        return {WINDOW: self.kv_heads_window,
+                LINEAR: self.num_heads}.get(kind, self.kv_heads_global)
 
     @property
     def latent_row(self) -> int:
@@ -174,6 +234,11 @@ class HybridSpec:
             out.update(wq=(e, hq * self.head_dim),
                        wk=(e, hkv * self.head_dim),
                        wv=(e, hkv * self.v_head_dim))
+        if self.layer_kinds[layer] in GATED:
+            out.update(qn=(self.head_dim,), kn=(self.head_dim,),
+                       wgate=(e, hq * self.v_head_dim))
+        if self.layer_kinds[layer] == LINEAR:
+            out["on"] = (hq * self.v_head_dim,)
         out["wo"] = (hq * self.v_head_dim, e)
         if self.layer_kinds[layer] == WINDOW:
             out["sink"] = (hq,)
@@ -217,6 +282,15 @@ NAMED_SPECS = {
         experts_per_token=8, experts_held=(0, 12), route_scale=2.827,
         layer_kinds=(LATENT,) * 5, ffn_kinds=(DENSE, MOE, MOE, MOE, MOE),
         norm_eps=1e-6),
+    # Published layers 9-16 of 32; the residual scale keeps the 32.
+    "minicpm-sala-l8": HybridSpec(
+        vocab=73448, d_model=4096, num_heads=32, head_dim=128,
+        v_head_dim=128, kv_heads_global=2, rope_base_global=10_000.0,
+        d_ff=16384, layer_kinds=(SPARSE,) + (LINEAR,) * 6 + (SPARSE,),
+        ffn_kinds=(DENSE,) * 8, norm_eps=1e-6, embed_scale=12.0,
+        residual_scale=1.4 / math.sqrt(32), logit_scale=256 / 4096,
+        sparse_block=64, sparse_stride=16, sparse_topk=64, sparse_init=1,
+        sparse_local=32, sparse_dense_len=8192),
 }
 
 
@@ -227,7 +301,7 @@ def init_hybrid_params(key: jax.Array, spec: HybridSpec) -> dict:
     to the context's mean, every token then chooses the same few experts,
     and most of them are never chosen."""
     def leaf(k, name, shape):
-        if name in ("ln1", "ln2", "qn", "kvn"):
+        if name in ("ln1", "ln2", "qn", "kn", "on", "kvn"):
             return jnp.ones(shape, jnp.float32)
         if name == "embed":
             return jax.random.normal(k, shape, jnp.float32)
@@ -310,6 +384,17 @@ def project(x, blk, spec: HybridSpec, layer: int, positions):
         row = heads(x @ blk["wkva"], spec.latent_row)
         c = rms_norm(row[..., :-rd], blk["kvn"], spec.norm_eps)
         return q, jnp.concatenate([c, rot(row[..., -rd:])], -1), None
+    if kind in GATED:
+        q = rms_norm(heads(x @ blk["wq"], spec.head_dim), blk["qn"],
+                     spec.norm_eps)
+        k = rms_norm(heads(x @ blk["wk"], spec.head_dim), blk["kn"],
+                     spec.norm_eps)
+        if kind == LINEAR:
+            rot = lambda a: partial_rope(a, positions, spec.rope_base_global,
+                                         spec.head_dim)
+            q = rot(q) * jnp.asarray(spec.head_dim ** -0.5, x.dtype)
+            k = rot(k)
+        return q, k, heads(x @ blk["wv"], spec.v_head_dim)
     base = spec.rope_base_window if kind == WINDOW else spec.rope_base_global
     q = partial_rope(heads(x @ blk["wq"], spec.head_dim), positions, base,
                      spec.rotary_dim)
@@ -365,12 +450,19 @@ def apply_block(h, blk, spec: HybridSpec, layer: int, positions, real, mix):
     ``counts`` the routed FFN's ``(assigned, touched)`` or ``None``."""
     b, t, _ = h.shape
     x = rms_norm(h, blk["ln1"], spec.norm_eps)
-    a = mix(layer, *project(x, blk, spec, layer, positions))
-    h = h + a.reshape(b, t, -1) @ blk["wo"]
+    a = mix(layer, *project(x, blk, spec, layer, positions)).reshape(b, t, -1)
+    if spec.layer_kinds[layer] == LINEAR:
+        a = rms_norm(a, blk["on"], spec.norm_eps)
+    if spec.layer_kinds[layer] in GATED:
+        a = a * jax.nn.sigmoid(x @ blk["wgate"])
+    # a branch joins the stream times ``residual_scale``
+    join = (lambda y: y) if spec.residual_scale == 1.0 else (
+        lambda y: y * jnp.asarray(spec.residual_scale, y.dtype))
+    h = h + join(a @ blk["wo"])
     x = rms_norm(h, blk["ln2"], spec.norm_eps)
     if spec.ffn_kinds[layer] == DENSE:
         up = jax.nn.silu(x @ blk["wg"]) * (x @ blk["wu"])
-        return h + up @ blk["wd"], None
+        return h + join(up @ blk["wd"]), None
     flat = x.reshape(b * t, -1)
     experts, weights = moe.route(flat, blk["wr"], blk["rc"],
                                  spec.experts_per_token, spec.route_scale)
@@ -381,7 +473,7 @@ def apply_block(h, blk, spec: HybridSpec, layer: int, positions, real, mix):
     out = out.reshape(h.shape).astype(h.dtype)
     if spec.shared_ff:  # every token, whole, on every chip of the group
         out = out + (jax.nn.silu(x @ blk["sg"]) * (x @ blk["su"])) @ blk["sd"]
-    return h + out, counts
+    return h + join(out), counts
 
 
 def apply_layers(params, tokens, spec: HybridSpec, positions, real, mix,
@@ -391,12 +483,17 @@ def apply_layers(params, tokens, spec: HybridSpec, positions, real, mix,
     h = params["embed"][tokens]
     if compute_dtype is not None:
         h = h.astype(compute_dtype)
+    if spec.embed_scale != 1.0:
+        h = h * jnp.asarray(spec.embed_scale, h.dtype)
     counts = jnp.zeros(2, jnp.int32)
     for i, blk in enumerate(params["blocks"]):
         h, c = apply_block(h, blk, spec, i, positions, real, mix)
         if c is not None:
             counts = counts + c
-    return rms_norm(h, params["lnf"], spec.norm_eps), counts
+    h = rms_norm(h, params["lnf"], spec.norm_eps)
+    if spec.logit_scale != 1.0:
+        h = h * jnp.asarray(spec.logit_scale, h.dtype)
+    return h, counts
 
 
 def head_logits(params, h):
@@ -410,10 +507,19 @@ def apply_hybrid(params, tokens, spec: HybridSpec, compute_dtype=None):
     positions = jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32), (b, t))
 
     def mix(layer, q, k, v):
-        if spec.layer_kinds[layer] == LATENT:
+        kind = spec.layer_kinds[layer]
+        if kind == LATENT:
             k, v = latent_kv(k[:, :, 0], params["blocks"][layer], spec)
             return kv_cache.attend_grouped(q, k, v, positions, positions,
                                            scale=spec.latent_scale)
+        if kind == LINEAR:
+            zero = jnp.zeros((spec.num_heads, spec.head_dim, spec.v_head_dim),
+                             jnp.float32)
+            rates = linear_attention.decay_rates(spec.num_heads)
+            return jax.vmap(lambda a, b_, c: linear_attention.scan_chunks(
+                zero, a, b_, c, rates, t)[0])(q, k, v)
+        if kind == SPARSE:
+            return sparse_over_own_rows(q, k, v, positions, spec)
         window = spec.layer_kinds[layer] == WINDOW
         return kv_cache.attend_grouped(
             q, k, v, positions, positions,
@@ -423,6 +529,23 @@ def apply_hybrid(params, tokens, spec: HybridSpec, compute_dtype=None):
     h, counts = apply_layers(params, tokens, spec, positions,
                              jnp.ones((b, t), bool), mix, compute_dtype)
     return head_logits(params, h), counts
+
+
+def sparse_over_own_rows(q, k, v, positions, spec: HybridSpec):
+    """A sparse layer's attention over the call's own rows ``0 .. T -
+    1``: the selector on the means of ``k``, then the masked form."""
+    sel, scale = spec.selector, spec.head_dim ** -0.5
+    blocks = -(-k.shape[1] // sel.block)
+    means = sparse_attention.group_means(k, sel.stride)
+    means = jnp.pad(means, ((0, 0), (0, blocks * sel.groups - means.shape[1]),
+                            (0, 0), (0, 0)))
+    allowed = sparse_attention.allowed_blocks(
+        sparse_attention.select_blocks(q, means, positions, sel, scale),
+        sparse_attention.attends_all(positions, sel))
+    heads_first = lambda a: a.transpose(0, 2, 1, 3)
+    return sparse_attention.attend_blocks(
+        q, heads_first(k), heads_first(v), positions, allowed, sel.block,
+        scale)
 
 
 # -- the paged cache view -----------------------------------------------------
@@ -444,15 +567,18 @@ GLOBAL_QUERY_BLOCK = 256
 
 class PagedMixer:
     """The cache view of one prefill or decode call. ``pools`` maps a
-    layer to its ``(k, v)`` pool (a latent layer's: ``(rows, None)``);
-    after the layers have run it holds the updated pools. ``w_table`` is
-    ``None`` where the pattern has no window layer."""
+    layer to what it keeps: its ``(k, v)`` pools (a latent layer's:
+    ``(rows, None)``), a sparse layer's ``(rows, None, means)``, a linear
+    layer's ``(None, None, state)``; after the layers have run it holds
+    the updated ones. ``w_table`` is ``None`` where the pattern has no
+    window layer; ``slot`` is the slot a prefill fills (a pattern with
+    linear layers: its state is that slot's)."""
 
     def __init__(self, spec: HybridSpec, params, pools: dict, *, page_size,
                  g_table, w_table, positions, real, last, base=None,
-                 platform=None):
+                 slot=None, platform=None):
         self.spec, self.params, self.pools = spec, params, dict(pools)
-        self.ps, self.real = page_size, real
+        self.ps, self.real, self.slot, self.last = page_size, real, slot, last
         self.platform = platform or jax.default_backend()
         self.g_table, self.w_table = g_table, w_table
         self.positions = positions          # [B, T]; -1 where padding
@@ -463,6 +589,12 @@ class PagedMixer:
         self.g_rows = kv_cache.table_rows(
             g_table, jnp.where(real, positions, -1), page_size,
             self._pages(GLOBAL, LATENT))
+        sparse = spec.layers_of(SPARSE)
+        if sparse:  # the same pages; a head's K rows, then its V rows
+            pages, heads, rows, _ = pools[sparse[0]][0].shape
+            self.s_rows = [sparse_attention.head_major_rows(
+                g_table, jnp.where(real, positions, -1), page_size, pages,
+                heads, rows, first) for first in (0, page_size)]
         if w_table is None:
             return
         keep = real & (positions > last[:, None] - spec.window)
@@ -501,6 +633,10 @@ class PagedMixer:
             return self._global(layer, q, k, v)
         if self.spec.layer_kinds[layer] == LATENT:
             return self._latent(layer, q, k[:, :, 0])
+        if self.spec.layer_kinds[layer] == LINEAR:
+            return self._linear(layer, q, k, v)
+        if self.spec.layer_kinds[layer] == SPARSE:
+            return self._sparse(layer, q, k, v)
         if self.base is None:
             return self._window_decode(layer, q, k, v)
         return self._window_prefill(layer, q, k, v)
@@ -575,6 +711,112 @@ class PagedMixer:
         return kv_cache.attend_grouped(qa, kv, kv[..., :kl], self.positions,
                                        self.g_pos, scale=scale)
 
+    def _linear(self, layer, q, k, v):
+        """The layer's state ``[slots, H, Dk, Dv]`` (fp32), updated in
+        place. A decode tick moves every active slot's one token on; a
+        prefill block starts from zeros at position 0 and from the
+        slot's own state past it (an earlier chunk's), and leaves the
+        state after its last real row."""
+        state = self.pools[layer][2]
+        rates = linear_attention.decay_rates(self.spec.num_heads)
+        if self.base is None:
+            out, state = linear_attention.step(
+                state, q[:, 0], k[:, 0], v[:, 0], rates, self.real[:, 0])
+            out = out[:, None]
+        else:
+            start = lax.dynamic_index_in_dim(state, self.slot, keepdims=False)
+            start = jnp.where(self.base == 0, 0.0, start)
+            out, end = linear_attention.scan_chunks(
+                start, q[0], k[0], v[0], rates, self.last[0] - self.base + 1)
+            state = lax.dynamic_update_index_in_dim(state, end, self.slot, 0)
+            out = out[None]
+        self.pools[layer] = (None, None, state)
+        return out
+
+    def _sparse(self, layer, q, k, v):
+        """Write the K and V rows (one pool: a head's K rows of a page,
+        then its V rows) and the means of the groups they fill, then
+        attend: a query within ``dense_len`` every row, one past it
+        the blocks the selector picks from the slot's means. A decode
+        tick on a TPU, at widths ``ops.sparse_attention.kernel_accepts``,
+        reads the listed pages where they lie (``sparse_decode_
+        attention``); else a view gathered at the widest slot's bucket
+        and a block mask. A prefill block takes the masked form over the
+        slot's pages (``attend_paged``)."""
+        sel = self.spec.selector
+        pool, _, means = self.pools[layer]
+        for rows, new in zip(self.s_rows, (k, v)):
+            pool = sparse_attention.write_rows(pool, new, rows)
+        pos = self.positions
+        if self.base is None:
+            new = sparse_attention.pool_group_means(pool, self.g_table,
+                                                    pos[:, 0], sel)
+            first = jnp.where(pos >= 0, pos // sel.stride * sel.stride, -1)
+        else:  # the block starts on a group's first row
+            new = sparse_attention.group_means(k, sel.stride)
+            first = jnp.where(self.real, pos, -1)[:, ::sel.stride]
+        means = sparse_attention.write_means(means, new, first, self.g_table,
+                                             sel, pool.shape[1])
+        self.pools[layer] = (pool, None, means)
+        attend = self._sparse_decode if self.base is None \
+            else self._sparse_prefill
+        return attend(q, pool, means)
+
+    def _chosen(self, q, positions, seen):
+        """The blocks each query picks from the slot's means as its table
+        shows them (``seen``: ``table_means``)."""
+        return sparse_attention.select_blocks(
+            q, seen, positions, self.spec.selector,
+            self.spec.head_dim ** -0.5)
+
+    def _sparse_decode(self, q, pool, means):
+        """One query a slot over a page list a (slot, K/V head): all of a
+        slot's pages up to ``dense_len``, the chosen ones past it (no
+        slot of a table within ``dense_len`` chooses)."""
+        spec, sel, pos = self.spec, self.spec.selector, self.positions
+        hkv, scale = pool.shape[1], spec.head_dim ** -0.5
+        pages = self.g_table.shape[1]
+        chosen = None if pages * sel.block <= sel.dense_len else self._chosen(
+            q, pos, sparse_attention.table_means(means, self.g_table, sel,
+                                                 hkv))[:, 0]
+        listed = sparse_attention.listed_blocks(chosen, pos[:, 0], sel, pages,
+                                                hkv)
+        if self.platform == "tpu" and sparse_attention.kernel_accepts(
+                spec.num_heads // hkv, spec.head_dim, self.ps):
+            out = sparse_attention.sparse_decode_attention(
+                q[:, 0].reshape(q.shape[0], hkv, -1, q.shape[-1]), pool,
+                self.g_table, sparse_attention.pack_blocks(
+                    listed, sel.list_width(pages)), pos[:, 0], scale=scale)
+            return out.reshape(q.shape[0], 1, spec.num_heads, -1)
+        kv, vv = sparse_attention.gather_heads(pool, self.g_table)
+        return sparse_attention.attend_blocks(
+            q, kv.astype(q.dtype), vv.astype(q.dtype), pos, listed[:, None],
+            sel.block, scale)
+
+    def _sparse_prefill(self, q, pool, means):
+        """One slot's block in the masked form; the selector runs, in
+        query blocks, only where the block's context passes
+        ``dense_len``."""
+        sel, (_, t) = self.spec.selector, self.positions.shape
+        pages, hkv = self.g_table.shape[1], pool.shape[1]
+        qb = math.gcd(t, GLOBAL_QUERY_BLOCK)
+        blocks = lambda a: a.reshape(t // qb, qb, *a.shape[1:])
+
+        def picked():
+            seen = sparse_attention.table_means(means, self.g_table, sel, hkv)
+            return lax.map(
+                lambda a: sparse_attention.allowed_blocks(
+                    self._chosen(a[0][None], a[1][None], seen)[0],
+                    sparse_attention.attends_all(a[1], sel)),
+                (blocks(q[0]), blocks(self.positions[0])))
+
+        allowed = lax.cond(self.last[0] >= sel.dense_len, picked,
+                           lambda: jnp.ones((t // qb, qb, hkv, pages), bool))
+        return sparse_attention.attend_paged(
+            q[0], pool, self.g_table[0], self.positions[0],
+            allowed.reshape(t, hkv, pages), sel.block,
+            self.spec.head_dim ** -0.5)[None]
+
     def _window_decode(self, layer, q, k, v):
         pk, pv = self._write(layer, k, v, self.w_rows)
         return kv_cache.attend_grouped(
@@ -617,19 +859,23 @@ class PagedMixer:
 
 def apply_hybrid_paged(params, pools: dict, tokens, spec: HybridSpec, *,
                        page_size: int, g_table, w_table, positions, real,
-                       last, base=None, compute_dtype=None, platform=None):
+                       last, base=None, slot=None, compute_dtype=None,
+                       platform=None):
     """The serving forward of one call through :class:`PagedMixer`:
     ``tokens [B, T]`` at ``positions [B, T]`` -> ``(h [B, T, E], pools,
     counts)``. ``base`` (a traced scalar) marks a prefill of one slot
     from that position; ``None`` a decode of one row a slot. ``last [B]``
     is the last position this call writes (behind 0: nothing).
-    ``w_table`` is ``None`` for a pattern without window layers.
+    ``w_table`` is ``None`` for a pattern without window layers;
+    ``slot`` (a traced scalar) is the slot a prefill fills, read by
+    linear layers alone.
     ``platform`` is the platform of the devices the program will run on
     (the default backend's when not given): a latent layer's decode
     reads its pages in place on a TPU."""
     mix = PagedMixer(spec, params, pools, page_size=page_size,
                      g_table=g_table, w_table=w_table, positions=positions,
-                     real=real, last=last, base=base, platform=platform)
+                     real=real, last=last, base=base, slot=slot,
+                     platform=platform)
     h, counts = apply_layers(params, tokens, spec,
                              jnp.maximum(positions, 0), real, mix,
                              compute_dtype)

@@ -448,12 +448,26 @@ class HybridKVCache:
     pages: ``[pages, page_size, latent_pool_width]``, a token's
     compressed row ``[c | k_r]`` and zeros up to whole lane tiles; its
     ``v`` is ``None``.
+    A sparse layer keeps K and V in ONE pool of the global group's pages,
+    in ``k`` (its ``v`` is ``None``), the head before the row: ``[pages,
+    kv_heads, 2 x page_size, width]``, a head's K rows of a page followed
+    by its V rows (one block of whole tiles whatever the head count, one
+    copy for a kernel that reads both), and beside it, through the same
+    block table, ``extra`` holds the
+    selector's cache ``[pages x kv_heads x groups, width]``: the mean of
+    each group of ``sparse_stride`` K rows (``ops.sparse_attention``). A
+    linear layer keeps no rows: its ``k`` and ``v`` are ``None`` and its
+    ``extra`` is the STATE GROUP's leaf, ``[slots, heads, head_dim,
+    v_head_dim]`` in fp32 whatever the compute dtype, not paged: zeroed
+    by the prefill program of a slot's first chunk, carried from chunk to
+    chunk and into decode (``ops.linear_attention``).
     A leaf a layer, so a layer's write is in place on its own donated
     buffer. No positions are stored: ``ops.kv_cache.ring_positions`` and
     a global row's logical index give them."""
 
     k: tuple
     v: tuple
+    extra: tuple    # a layer's selector cache or state; else ``None``
 
 
 def latent_pool_width(spec) -> int:
@@ -468,24 +482,39 @@ def latent_pool_width(spec) -> int:
 
 
 def hybrid_cache(spec, num_pages: int, num_window_pages: int,
-                 page_size: int, dtype) -> HybridKVCache:
-    """Fresh zero pools on the default device."""
-    from ..models.hybrid import LATENT, WINDOW
+                 page_size: int, dtype, slots: int = 0) -> HybridKVCache:
+    """Fresh zero pools (and states: ``slots`` of them a linear layer)
+    on the default device."""
+    from ..models.hybrid import LATENT, LINEAR, SPARSE, WINDOW
 
-    latent = lambda layer: spec.layer_kinds[layer] == LATENT
+    kind = lambda layer: spec.layer_kinds[layer]
 
     def pool(layer: int, width: int):
-        pages = (num_window_pages if spec.layer_kinds[layer] == WINDOW
-                 else num_pages)
-        row = (latent_pool_width(spec) if latent(layer)
+        if kind(layer) == LINEAR:
+            return None
+        if kind(layer) == SPARSE:
+            return jnp.zeros((num_pages, spec.kv_heads(layer), 2 * page_size,
+                              width), dtype)
+        pages = num_window_pages if kind(layer) == WINDOW else num_pages
+        row = (latent_pool_width(spec) if kind(layer) == LATENT
                else spec.kv_heads(layer) * width)
         return jnp.zeros((pages, page_size, row), dtype)
+
+    def extra(layer: int):
+        if kind(layer) == LINEAR:
+            return jnp.zeros((slots, spec.num_heads, spec.head_dim,
+                              spec.v_head_dim), jnp.float32)
+        if kind(layer) == SPARSE:
+            rows = num_pages * spec.kv_heads(layer) * spec.selector.groups
+            return jnp.zeros((rows, spec.head_dim), dtype)
+        return None
 
     layers = range(spec.num_layers)
     return HybridKVCache(
         k=tuple(pool(i, spec.head_dim) for i in layers),
-        v=tuple(None if latent(i) else pool(i, spec.v_head_dim)
-                for i in layers))
+        v=tuple(None if kind(i) in (LATENT, SPARSE)
+                else pool(i, spec.v_head_dim) for i in layers),
+        extra=tuple(extra(i) for i in layers))
 
 
 def ring_columns(window: int, page_size: int) -> int:

@@ -1,6 +1,6 @@
-"""The engine of the ``models.hybrid`` family (global, window and latent
-attention layers): two page groups, one block table a group a slot, the
-same host API.
+"""The engine of the ``models.hybrid`` family (global, window, latent,
+linear and block-sparse attention layers): two page groups, one block
+table a group a slot, a state group without pages, the same host API.
 
 ``serve.engine_cls(spec)`` is this class for a ``HybridSpec``; the
 constructor's checks, the host state, scheduler, buckets, warm-up,
@@ -13,7 +13,17 @@ the window group.
 - **Global group**: the base class's ``pages`` / ``tables`` /
   ``table_len`` / ``reserved_for``, every row kept, decode programs
   bucketed on its page count. A global layer keeps a K and a V pool
-  there, a latent layer one pool of compressed rows.
+  there, a latent layer one pool of compressed rows, a sparse layer a K
+  and a V pool with the head before the row and the selector's means
+  beside them (``page_size`` is the selector's block).
+- **State group**, where the pattern has a linear layer: one ``[slots,
+  heads, head_dim, v_head_dim]`` fp32 array a layer, no pages, no host
+  bookkeeping. A prefill program is told its slot: it starts from zeros
+  at position 0 (so a readmitted slot starts clean, with no host write)
+  and from the slot's state past it, which is how ``prefill_chunk``
+  carries a prompt across chunks; a decode program moves the active
+  slots' states on and leaves the others' as they are (a slot between
+  two chunks of its prompt is one of them).
 - **Window group**, where the pattern has a window layer (without one:
   no pool, no tables, no host work, ``ring`` 0): ``win_pages`` (a second
   ``PagePool``), ``win_tables [slots, R]`` a ring of columns (``ops.kv_cache.ring_rows``), logical
@@ -31,8 +41,16 @@ returns, behind the sampled tokens in the one small array the host
 waits for, the routed layers' ``(assigned, touched)``;
 :attr:`last_counters` holds them for the scheduler's spans, beside the
 host's own counts of a decode tick: ``win_pages`` (a pattern with window
-layers) and ``latent_rows`` (one with latent layers: the cached rows the
-tick's active slots attend, summed over slots).
+layers), ``latent_rows`` (one with latent layers: the cached rows the
+tick's active slots attend, summed over slots), and for a pattern with
+sparse or linear layers ``kv_pages`` (pages that hold the active slots'
+rows, a K/V head), ``sparse_pages`` (those a sparse layer attends: a
+slot's own up to ``sparse_dense_len`` rows of context, ``sparse_topk``
+past it) and ``state_slots`` (slots whose state the tick reads and
+writes); a prefill of such a pattern counts ``sparse`` (1 where the
+block's context passes ``sparse_dense_len``) and ``chunk`` (the block's
+index in its prompt). The routed layers' counts are written where the
+pattern has routed layers.
 
 Weights are kept as handed over, cast once to ``compute_dtype`` when
 the engine is built, never inside a call.
@@ -62,7 +80,9 @@ UNSUPPORTED = (
 )
 HANDOFF = ("the hybrid family does not support the disaggregated hand-off "
            "(dump/load/alias of a slot's pages, preemption, prefill and "
-           "decode roles): its page groups have no serialised form yet")
+           "decode roles): its page groups, a sparse layer's selector rows "
+           "and a linear layer's recurrent state have no serialised form "
+           "yet")
 
 
 class HybridEngine(InferenceEngine):
@@ -74,13 +94,28 @@ class HybridEngine(InferenceEngine):
     refuses = tuple(
         (asked, f"the hybrid family does not support the {feature} "
                 f"({field}) yet; it serves paged, tp 1, full-precision "
-                "pools, no prefix cache, no speculation")
+                "pools, no prefix cache, no speculation (a linear layer's "
+                "state is of one slot's whole context: no prefix of it "
+                "can be shared, no drafted row taken back)")
         for feature, asked, field in UNSUPPORTED)
 
     def _layout(self) -> None:
         cfg = self.config
         self.ring = self.num_window_pages = 0
         self.counts_latent_rows = bool(cfg.spec.layers_of(hybrid.LATENT))
+        self.counts_moe = hybrid.MOE in cfg.spec.ffn_kinds
+        self.has_state = bool(cfg.spec.layers_of(hybrid.LINEAR))
+        self.sparse = cfg.spec.selector \
+            if cfg.spec.layers_of(hybrid.SPARSE) else None
+        if self.sparse and (
+                self.page_size != self.sparse.block
+                or cfg.prefill_chunk % self.sparse.stride):
+            raise ValueError(
+                f"a sparse layer's block is the pool's page: page_size "
+                f"({self.page_size}) must be sparse_block "
+                f"({self.sparse.block}), and a prefill chunk "
+                f"({cfg.prefill_chunk}) whole groups of "
+                f"{self.sparse.stride} rows")
         if not cfg.spec.layers_of(hybrid.WINDOW):
             return
         window = cfg.spec.window
@@ -101,7 +136,7 @@ class HybridEngine(InferenceEngine):
         self._reset_host()  # the global group
         self.cache = hybrid_cache(
             cfg.spec, self.num_pages, self.num_window_pages, self.page_size,
-            np.dtype(cfg.compute_dtype or np.float32))
+            np.dtype(cfg.compute_dtype or np.float32), cfg.slots)
         self.last_counters = {}
         if not self.ring:
             return
@@ -195,7 +230,8 @@ class HybridEngine(InferenceEngine):
 
     def _forward(self, params, cache: HybridKVCache, tokens, **kw):
         spec = self.config.spec
-        pools = dict(enumerate(zip(cache.k, cache.v)))
+        pools = {i: kept if kept[2] is not None else kept[:2]
+                 for i, kept in enumerate(zip(cache.k, cache.v, cache.extra))}
         h, pools, counts = hybrid.apply_hybrid_paged(
             params, pools, tokens, spec, page_size=self.page_size,
             compute_dtype=self.config.dtype(),
@@ -203,18 +239,21 @@ class HybridEngine(InferenceEngine):
         layers = range(spec.num_layers)
         return h, counts, HybridKVCache(
             k=tuple(pools[i][0] for i in layers),
-            v=tuple(pools[i][1] for i in layers))
+            v=tuple(pools[i][1] for i in layers),
+            extra=tuple(pools[i][2] if len(pools[i]) > 2 else None
+                        for i in layers))
 
     def _prefill_paged_fn(self, bucket: int, all_rows: bool = False):
         """``(params, cache, tokens [1, bucket], length, base, g_table [1,
-        max_pages], w_table [1, R] or None, request_id) -> ([next, assigned,
+        max_pages], w_table [1, R] or None, slot or None (a pattern with
+        linear layers: whose state), request_id) -> ([next, assigned,
         touched], logits [1, vocab] of the last real position, cache)``.
         The family's one form: a caller that asks for logits
         (``all_rows``) gets that row."""
         if bucket in self._prefill_fns:
             return self._prefill_fns[bucket]
 
-        def run(params, cache, tokens, length, base, g_table, w_table,
+        def run(params, cache, tokens, length, base, g_table, w_table, slot,
                 request_id):
             t = jnp.arange(bucket, dtype=jnp.int32)
             real = (t < length)[None, :]
@@ -222,7 +261,7 @@ class HybridEngine(InferenceEngine):
             h, counts, cache = self._forward(
                 params, cache, tokens, g_table=g_table, w_table=w_table,
                 positions=positions, real=real,
-                last=(base + length - 1)[None], base=base)
+                last=(base + length - 1)[None], base=base, slot=slot)
             last = lax.dynamic_slice_in_dim(h[0], length - 1, 1, axis=0)
             logits = hybrid.head_logits(params, last)
             nxt = self._sample(logits[0], request_id, base + length)
@@ -269,7 +308,9 @@ class HybridEngine(InferenceEngine):
         where = super()._prefill_where(slot, base, t)
         if self.ring:
             self._slide_window(slot, base, base + t - 1)
-        return where + (self._win_table(slice(slot, slot + 1)),)
+        self._block = (base, t)
+        return where + (self._win_table(slice(slot, slot + 1)),
+                        jnp.int32(slot) if self.has_state else None)
 
     def _decode_where(self, lengths, active, _pages) -> tuple:
         """Each active slot's window (where the pattern has one) slides
@@ -282,16 +323,40 @@ class HybridEngine(InferenceEngine):
         return fn, where + (self._win_table(),)
 
     def _counted(self, kind: str, counts, lengths=None, active=None) -> None:
-        self.last_counters = {"moe_assigned": int(counts[0])}
+        self.last_counters = {"moe_assigned": int(counts[0])} \
+            if self.counts_moe else {}
         if kind == "prefill":
+            if self.sparse or self.has_state:
+                base, t = self._block
+                chunk = self.config.prefill_chunk
+                self.last_counters.update(
+                    chunk=base // chunk if chunk else 0,
+                    sparse=int(bool(self.sparse)
+                               and base + t > self.sparse.dense_len))
             return
-        self.last_counters["moe_touched"] = int(counts[1])
+        if self.counts_moe:
+            self.last_counters["moe_touched"] = int(counts[1])
+        if self.sparse or self.has_state:
+            self.last_counters.update(self._tick_pages(lengths[active]))
         if self.ring:
             self.last_counters["win_pages"] = self.window_pages_in_use
         if self.counts_latent_rows:
             # each active slot's query attends rows 0 .. its length
             self.last_counters["latent_rows"] = int(
                 lengths[active].sum() + active.sum())
+
+    def _tick_pages(self, lengths) -> dict:
+        """A decode tick's page counts from its active slots' lengths
+        (each attends rows ``0 .. length``), a K/V head of one sparse
+        layer: the pages that hold them, and those attended."""
+        out = {"state_slots": len(lengths)} if self.has_state else {}
+        if not self.sparse:
+            return out
+        sel, heads = self.sparse, self.config.spec.kv_heads_global
+        held = lengths.astype(np.int64) // sel.block + 1
+        read = np.where(lengths < sel.dense_len, held, sel.topk)
+        return dict(out, kv_pages=int(held.sum()) * heads,
+                    sparse_pages=int(read.sum()) * heads)
 
 
 def engine_cls(spec) -> type[InferenceEngine]:

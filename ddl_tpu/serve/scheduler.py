@@ -586,9 +586,13 @@ class Scheduler:
                 # pool could otherwise exhaust mid-warmup). Warmup
                 # discards all engine state at the end regardless.
                 eng.reset()
-            max_bucket = eng.prefill_bucket(max(
-                int(np.asarray(r.prompt).shape[0]) for r in requests
-            ))
+            # A chunked run never prefills a block past its chunk: the
+            # buckets above it would be compiled (and run, at the top
+            # bucket's temporaries) for nothing.
+            longest = max(int(np.asarray(r.prompt).shape[0])
+                          for r in requests)
+            max_bucket = eng.prefill_bucket(
+                min(longest, eng.config.prefill_chunk or longest))
             b = 8
             while True:
                 # min() also covers a capacity-capped (non-power-of-two)

@@ -17,7 +17,9 @@
   serving cell's widths, decode and prefill: no layer's pool is copied
   out of the stacked pool or back (ISSUE 29); and the second family's
   decode over latent layers at its cell's widths: no pool is copied, no
-  view gathered (ISSUE 32). The persistent compile
+  view gathered (ISSUE 32); and its sparse and linear layers' decode and
+  prefill at their cell's widths: neither the head-major pools nor the
+  state group is copied (ISSUE 34). The persistent compile
   cache is off for the whole suite (conftest) — a described-device
   compile can be written to it but never read back without a chip.
   Whole-step compiles
@@ -30,6 +32,7 @@
   placement rule, and ``chip_smoke.py`` refusing to report from a CPU.
 """
 
+import dataclasses
 import json
 import math
 import os
@@ -332,6 +335,96 @@ def test_latent_decode_reads_its_pool_in_place(v5e):
                for line in hlo.splitlines()) == spec.num_layers
     assert f"[{slots},{bucket * page},{width}]" not in hlo
     assert compiled.memory_analysis().temp_size_in_bytes < 100e6
+
+
+@pytest.mark.parametrize("program", ["decode_p560", "decode_p64",
+                                     "prefill_b2048"])
+def test_sparse_and_linear_layers_keep_their_caches_in_place(v5e, program):
+    """``models.hybrid`` over a sparse and a linear layer at the widths of
+    ``serve-sala-closed64-32k`` (32 query heads of 128 over 2 K/V heads,
+    a pool of 24,576 pages ``[2, 128, 128]`` bf16, the head before the
+    row and a head's K rows before its V rows, 64 slots of ``[32, 128, 128]`` fp32 state), caches donated,
+    compiled for the described v5e: no pool (1.61 GB) and no layer's state
+    (134 MB) is copied, whatever the program reads or writes of them; a
+    decode tick's sparse attention is one ``sparse_decode_attention``
+    kernel and no view ``[64, 2, 35840, 128]`` is gathered (the widest
+    bucket, past ``dense_len``: the selector runs; the 64-page bucket:
+    every page listed); a prefill chunk runs no kernel."""
+    from ddl_tpu.models import hybrid
+    from ddl_tpu.serve.cache import HybridKVCache, hybrid_cache
+
+    spec = dataclasses.replace(
+        hybrid.NAMED_SPECS["minicpm-sala-l8"],
+        layer_kinds=(hybrid.SPARSE, hybrid.LINEAR),
+        ffn_kinds=(hybrid.DENSE,) * 2)
+    pages, page, slots = 24576, 64, 64
+    on = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=v5e)
+    params = jax.tree.map(
+        lambda a: on(a.shape, jnp.bfloat16), jax.eval_shape(
+            lambda: hybrid.init_hybrid_params(jax.random.PRNGKey(0), spec)))
+    cache = jax.tree.map(lambda a: on(a.shape, a.dtype), jax.eval_shape(
+        lambda: hybrid_cache(spec, pages, 0, page, jnp.bfloat16, slots)))
+    assert cache.k[0].shape == (pages, 2, 2 * page, 128)
+    assert cache.v[0] is None
+    assert cache.extra[0].shape == (pages * 2 * 4, 128)
+    assert cache.extra[1].shape == (slots, 32, 128, 128)
+    assert cache.extra[1].dtype == jnp.float32 and cache.k[1] is None
+
+    def forward(params, cache, tokens, **kw):
+        pools = {i: kept if kept[2] is not None else kept[:2] for i, kept
+                 in enumerate(zip(cache.k, cache.v, cache.extra))}
+        h, pools, _ = hybrid.apply_hybrid_paged(
+            params, pools, tokens, spec, page_size=page, w_table=None,
+            compute_dtype=jnp.bfloat16, platform="tpu", **kw)
+        both = range(2)
+        return h, HybridKVCache(
+            k=tuple(pools[i][0] for i in both),
+            v=tuple(pools[i][1] for i in both),
+            extra=tuple(pools[i][2] for i in both))
+
+    i32 = on((), jnp.int32)
+    if program.startswith("decode"):
+        bucket = int(program.split("_p")[1])
+
+        def run(params, cache, last_tokens, lengths, active, g_table):
+            positions = jnp.where(active, lengths, -1)
+            h, cache = forward(
+                params, cache, last_tokens[:, None], g_table=g_table,
+                positions=positions[:, None], real=active[:, None],
+                last=positions)
+            return hybrid.head_logits(params, h[:, 0]), cache
+
+        args = (on((slots,), jnp.int32), on((slots,), jnp.int32),
+                on((slots,), jnp.bool_), on((slots, bucket), jnp.int32))
+    else:
+        bucket = 2048
+
+        def run(params, cache, tokens, length, base, g_table, slot):
+            t = jnp.arange(bucket, dtype=jnp.int32)
+            real = (t < length)[None, :]
+            h, cache = forward(
+                params, cache, tokens, g_table=g_table,
+                positions=jnp.where(real, base + t, -1), real=real,
+                last=(base + length - 1)[None], base=base, slot=slot)
+            return h[0, -1], cache
+
+        args = (on((1, bucket), jnp.int32), i32, i32,
+                on((1, 560), jnp.int32), i32)
+    compiled = jax.jit(run, donate_argnums=(1,)).lower(
+        params, cache, *args).compile()
+    hlo = compiled.as_text()
+    # A prefill updates ONE slot's 2 MiB of the state where it lies (a
+    # dynamic-update-slice whose result names the whole array).
+    state, pool = slots * 32 * 128 * 128 * 4, pages * 2 * 2 * page * 128 * 2
+    assert _pool_sized_copies(
+        hlo, state if program.startswith("decode") else pool) == []
+    kernels = sum("tpu_custom_call" in line
+                  and "sparse_decode_attention" in line
+                  for line in hlo.splitlines())
+    assert kernels == (1 if program.startswith("decode") else 0)
+    assert "[64,2,35840,128]" not in hlo and "[64,2,4096,128]" not in hlo
+    limit = 700e6 if program.startswith("prefill") else 200e6
+    assert compiled.memory_analysis().temp_size_in_bytes < limit
 
 
 @pytest.mark.slow
