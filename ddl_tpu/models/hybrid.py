@@ -552,9 +552,12 @@ def sparse_over_own_rows(q, k, v, positions, spec: HybridSpec):
 #
 # Two page groups side by side. The GLOBAL group keeps every row: a slot's
 # block table maps logical page j to a pool page, as the dense family's
-# does, and a row's position is its logical row; a LATENT layer's pool is
-# of this group, ONE pool of ``[c | k_r]`` rows (``serve.cache.
-# latent_pool_width``). The WINDOW group keeps
+# does, and a row's position is its logical row. A GLOBAL layer's pool is
+# ONE array with the head before the row, ``[pages, Hkv, page_size, W]``, a
+# row ``[k | zeros | v | zeros]`` (``ops.paged_attention.
+# grouped_row_widths``); a LATENT layer's pool is of this group too, ONE
+# pool of ``[c | k_r]`` rows (``serve.cache.latent_pool_width``). The
+# WINDOW group keeps
 # the last `window` rows: a slot's table is a ring of R columns, logical
 # page j lives in column j % R, and the host frees a column's page once
 # the page lies wholly behind the window. No position travels with a row:
@@ -567,8 +570,9 @@ GLOBAL_QUERY_BLOCK = 256
 
 class PagedMixer:
     """The cache view of one prefill or decode call. ``pools`` maps a
-    layer to what it keeps: its ``(k, v)`` pools (a latent layer's:
-    ``(rows, None)``), a sparse layer's ``(rows, None, means)``, a linear
+    layer to what it keeps: a window layer's ``(k, v)`` pools, a global
+    or a latent layer's ``(rows, None)``, a sparse layer's ``(rows, None,
+    means)``, a linear
     layer's ``(None, None, state)``; after the layers have run it holds
     the updated ones. ``w_table`` is ``None`` where the pattern has no
     window layer; ``slot`` is the slot a prefill fills (a pattern with
@@ -588,13 +592,19 @@ class PagedMixer:
                                -1)
         self.g_rows = kv_cache.table_rows(
             g_table, jnp.where(real, positions, -1), page_size,
-            self._pages(GLOBAL, LATENT))
+            self._pages(LATENT))
         sparse = spec.layers_of(SPARSE)
         if sparse:  # the same pages; a head's K rows, then its V rows
             pages, heads, rows, _ = pools[sparse[0]][0].shape
             self.s_rows = [sparse_attention.head_major_rows(
                 g_table, jnp.where(real, positions, -1), page_size, pages,
                 heads, rows, first) for first in (0, page_size)]
+        glob = spec.layers_of(GLOBAL)
+        if glob:  # the same pages, a row a (head, token)
+            pages, heads = pools[glob[0]][0].shape[:2]
+            self.h_rows = sparse_attention.head_major_rows(
+                g_table, jnp.where(real, positions, -1), page_size, pages,
+                heads)
         if w_table is None:
             return
         keep = real & (positions > last[:, None] - spec.window)
@@ -642,11 +652,35 @@ class PagedMixer:
         return self._window_prefill(layer, q, k, v)
 
     def _global(self, layer, q, k, v):
-        """Write, then attend the slot's whole table; a prefill's
-        queries go in blocks so no ``[H, T, T]`` array exists."""
-        pk, pv = self._write(layer, k, v, self.g_rows)
-        kv = self._view(pk, self.g_table, k)
-        vv = self._view(pv, self.g_table, v)
+        """Write the rows ``[k | zeros | v | zeros]`` into the layer's ONE
+        pool, the head before the row, then attend the slot's whole
+        table. Which form attends is chosen here, at trace time, from
+        what the call shows: a decode tick (``base is None``) on a TPU,
+        at widths ``ops.paged_attention.grouped_kernel_accepts``, reads
+        each slot's mapped pages where they lie in the pool
+        (``grouped_decode_attention``: nothing gathered, nothing re-laid
+        out, the bytes of the pages resident); every other call gathers
+        the table's pages into views and takes ``attend_grouped``, a
+        prefill's queries in blocks so no ``[H, T, T]`` array exists."""
+        spec = self.spec
+        pool, _ = self.pools[layer]
+        pool = sparse_attention.write_rows(
+            pool, paged_attention.grouped_rows(k, v), self.h_rows)
+        self.pools[layer] = (pool, None)
+        hkv = pool.shape[1]
+        if (self.base is None and self.platform == "tpu"
+                and paged_attention.grouped_kernel_accepts(
+                    spec.num_heads // hkv, spec.head_dim, spec.v_head_dim,
+                    self.ps)):
+            b = q.shape[0]
+            out = paged_attention.grouped_decode_attention(
+                q[:, 0].reshape(b, hkv, -1, spec.head_dim), pool,
+                jnp.where(self.real, self.g_table, -1), self.positions[:, 0],
+                v_head_dim=spec.v_head_dim)
+            return out.reshape(b, 1, spec.num_heads, -1).astype(v.dtype)
+        kv, vv = paged_attention.gather_grouped(
+            pool, self.g_table, spec.head_dim, spec.v_head_dim)
+        kv, vv = kv.astype(k.dtype), vv.astype(v.dtype)
         return self._in_blocks(q, lambda a, at: kv_cache.attend_grouped(
             a, kv, vv, at, self.g_pos))
 
@@ -871,7 +905,7 @@ def apply_hybrid_paged(params, pools: dict, tokens, spec: HybridSpec, *,
     linear layers alone.
     ``platform`` is the platform of the devices the program will run on
     (the default backend's when not given): a latent layer's decode
-    reads its pages in place on a TPU."""
+    and a global layer's decode read their pages in place on a TPU."""
     mix = PagedMixer(spec, params, pools, page_size=page_size,
                      g_table=g_table, w_table=w_table, positions=positions,
                      real=real, last=last, base=base, slot=slot,

@@ -436,31 +436,46 @@ class PagePool:
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass
 class HybridKVCache:
-    """The pools of a family whose layers keep different things: a K
-    and a V pool a LAYER, ``[pages, page_size, kv_heads * width]`` (a
-    token's heads side by side in one row: whole 128-lane tiles at the
-    published widths, where a ``[.., 4, 192]`` tail made the compiler
-    copy the whole pool six times a decode tick to scatter into it),
-    whose page count and head count are its group's (global layers: every row
-    kept, ``num_pages`` pages; window layers: the last ``window`` rows,
-    ``num_window_pages`` pages) and whose K and V rows differ in width.
-    A latent layer keeps ONE pool, in ``k``, of the global group's
-    pages: ``[pages, page_size, latent_pool_width]``, a token's
-    compressed row ``[c | k_r]`` and zeros up to whole lane tiles; its
-    ``v`` is ``None``.
-    A sparse layer keeps K and V in ONE pool of the global group's pages,
-    in ``k`` (its ``v`` is ``None``), the head before the row: ``[pages,
-    kv_heads, 2 x page_size, width]``, a head's K rows of a page followed
-    by its V rows (one block of whole tiles whatever the head count, one
-    copy for a kernel that reads both), and beside it, through the same
-    block table, ``extra`` holds the
-    selector's cache ``[pages x kv_heads x groups, width]``: the mean of
-    each group of ``sparse_stride`` K rows (``ops.sparse_attention``). A
-    linear layer keeps no rows: its ``k`` and ``v`` are ``None`` and its
-    ``extra`` is the STATE GROUP's leaf, ``[slots, heads, head_dim,
-    v_head_dim]`` in fp32 whatever the compute dtype, not paged: zeroed
-    by the prefill program of a slot's first chunk, carried from chunk to
-    chunk and into decode (``ops.linear_attention``).
+    """The pools of a family whose layers keep different things, a leaf
+    a LAYER in ``k`` (and ``v``, ``extra`` where the kind has them), of
+    its group's page count: the global group keeps every row
+    (``num_pages`` pages), the window group the last ``window`` rows
+    (``num_window_pages`` pages).
+
+    - A window layer keeps a K and a V pool, ``[pages, page_size,
+      kv_heads * width]`` (a token's heads side by side in one row: whole
+      128-lane tiles at the published widths, where a ``[.., 4, 192]``
+      tail made the compiler copy the whole pool six times a decode tick
+      to scatter into it), whose K and V rows differ in width.
+    - A global layer keeps K and V in ONE pool, in ``k`` (its ``v`` is
+      ``None``), the head before the row: ``[pages, kv_heads, page_size,
+      W]``, a row ``[k | zeros | v | zeros]`` with each part filled to
+      whole lane tiles (``ops.paged_attention.grouped_row_widths``: ``[k
+      192 | 64 zeros | v 128]``, 384 values, at the published widths). A
+      page is one contiguous block of whole tiles, a head's K and V lane
+      slices of its rows: what the decode kernel
+      ``grouped_decode_attention`` reads in place, one copy a page, and
+      what every other program gathers from
+      (``ops.paged_attention.gather_grouped``). One layout on every
+      platform.
+    - A latent layer keeps ONE pool, in ``k``, of the global group's
+      pages: ``[pages, page_size, latent_pool_width]``, a token's
+      compressed row ``[c | k_r]`` and zeros up to whole lane tiles; its
+      ``v`` is ``None``.
+    - A sparse layer keeps K and V in ONE pool of the global group's
+      pages, in ``k`` (its ``v`` is ``None``), the head before the row:
+      ``[pages, kv_heads, 2 x page_size, width]``, a head's K rows of a
+      page followed by its V rows (one block of whole tiles whatever the
+      head count, one copy for a kernel that reads both), and beside it,
+      through the same block table, ``extra`` holds the selector's cache
+      ``[pages x kv_heads x groups, width]``: the mean of each group of
+      ``sparse_stride`` K rows (``ops.sparse_attention``).
+    - A linear layer keeps no rows: its ``k`` and ``v`` are ``None`` and
+      its ``extra`` is the STATE GROUP's leaf, ``[slots, heads, head_dim,
+      v_head_dim]`` in fp32 whatever the compute dtype, not paged: zeroed
+      by the prefill program of a slot's first chunk, carried from chunk
+      to chunk and into decode (``ops.linear_attention``).
+
     A leaf a layer, so a layer's write is in place on its own donated
     buffer. No positions are stored: ``ops.kv_cache.ring_positions`` and
     a global row's logical index give them."""
@@ -485,7 +500,8 @@ def hybrid_cache(spec, num_pages: int, num_window_pages: int,
                  page_size: int, dtype, slots: int = 0) -> HybridKVCache:
     """Fresh zero pools (and states: ``slots`` of them a linear layer)
     on the default device."""
-    from ..models.hybrid import LATENT, LINEAR, SPARSE, WINDOW
+    from ..models.hybrid import GLOBAL, LATENT, LINEAR, SPARSE, WINDOW
+    from ..ops.paged_attention import grouped_row_widths
 
     kind = lambda layer: spec.layer_kinds[layer]
 
@@ -495,6 +511,10 @@ def hybrid_cache(spec, num_pages: int, num_window_pages: int,
         if kind(layer) == SPARSE:
             return jnp.zeros((num_pages, spec.kv_heads(layer), 2 * page_size,
                               width), dtype)
+        if kind(layer) == GLOBAL:
+            _, row = grouped_row_widths(spec.head_dim, spec.v_head_dim)
+            return jnp.zeros((num_pages, spec.kv_heads(layer), page_size,
+                              row), dtype)
         pages = num_window_pages if kind(layer) == WINDOW else num_pages
         row = (latent_pool_width(spec) if kind(layer) == LATENT
                else spec.kv_heads(layer) * width)
@@ -512,8 +532,8 @@ def hybrid_cache(spec, num_pages: int, num_window_pages: int,
     layers = range(spec.num_layers)
     return HybridKVCache(
         k=tuple(pool(i, spec.head_dim) for i in layers),
-        v=tuple(None if kind(i) in (LATENT, SPARSE)
-                else pool(i, spec.v_head_dim) for i in layers),
+        v=tuple(pool(i, spec.v_head_dim) if kind(i) == WINDOW else None
+                for i in layers),
         extra=tuple(extra(i) for i in layers))
 
 

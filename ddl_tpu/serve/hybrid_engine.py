@@ -12,10 +12,12 @@ the window group.
 
 - **Global group**: the base class's ``pages`` / ``tables`` /
   ``table_len`` / ``reserved_for``, every row kept, decode programs
-  bucketed on its page count. A global layer keeps a K and a V pool
-  there, a latent layer one pool of compressed rows, a sparse layer a K
-  and a V pool with the head before the row and the selector's means
-  beside them (``page_size`` is the selector's block).
+  bucketed on its page count. A global layer keeps K and V there in one
+  pool with the head before the row (a row ``[k | zeros | v | zeros]``),
+  a latent layer one pool of compressed rows, a sparse layer one pool
+  with the head before the row (a head's K rows, then its V rows) and
+  the selector's means beside it (``page_size`` is the selector's
+  block).
 - **State group**, where the pattern has a linear layer: one ``[slots,
   heads, head_dim, v_head_dim]`` fp32 array a layer, no pages, no host
   bookkeeping. A prefill program is told its slot: it starts from zeros
@@ -134,6 +136,7 @@ class HybridEngine(InferenceEngine):
     def reset(self) -> None:
         cfg = self.config
         self._reset_host()  # the global group
+        self.cache = None  # the old pools go before the new ones come
         self.cache = hybrid_cache(
             cfg.spec, self.num_pages, self.num_window_pages, self.page_size,
             np.dtype(cfg.compute_dtype or np.float32), cfg.slots)

@@ -11,7 +11,8 @@
   that keeps a sequence the kernel would refuse from ever reaching it;
   and the paged decode-attention kernel at the serving cell's shape and
   at an fp32 pool under a bucket no page group divides (ISSUE 31), and
-  the latent pool's at its cell's shape (ISSUE 32).
+  the latent pool's at its cell's shape (ISSUE 32), and the grouped
+  pool's at its cell's shape (ISSUE 35).
   One to four seconds each; skipped where the TPU compiler cannot
   describe the topology. Beside them the dense paged forward at the
   serving cell's widths, decode and prefill: no layer's pool is copied
@@ -19,7 +20,9 @@
   decode over latent layers at its cell's widths: no pool is copied, no
   view gathered (ISSUE 32); and its sparse and linear layers' decode and
   prefill at their cell's widths: neither the head-major pools nor the
-  state group is copied (ISSUE 34). The persistent compile
+  state group is copied (ISSUE 34); and its global layers' decode at
+  their cell's widths: no pool copied, no view gathered or re-laid out
+  (ISSUE 35). The persistent compile
   cache is off for the whole suite (conftest) — a described-device
   compile can be written to it but never read back without a chip.
   Whole-step compiles
@@ -117,12 +120,26 @@ def _compile_latent_decode(chip, slots, bucket, pool=(8192, 64, 640),
         on((slots, bucket), jnp.int32), on((slots,), jnp.int32)).compile()
 
 
+def _compile_grouped_decode(chip, slots, bucket, pool=(4096, 4, 64, 384),
+                            group_heads=16, head_dim=192, v_head_dim=128):
+    from ddl_tpu.ops.paged_attention import (grouped_decode_attention,
+                                             grouped_kernel_accepts)
+
+    assert grouped_kernel_accepts(group_heads, head_dim, v_head_dim, pool[2])
+    on = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=chip)
+    return jax.jit(lambda *a: grouped_decode_attention(
+        *a, v_head_dim=v_head_dim)).lower(
+        on((slots, pool[1], group_heads, head_dim), jnp.bfloat16),
+        on(pool, jnp.bfloat16), on((slots, bucket), jnp.int32),
+        on((slots,), jnp.int32)).compile()
+
+
 @pytest.mark.parametrize("case", [
     "adam_full_vector", "adam_quarter_shard", "flash_lm_shape",
     "flash_t4096", "flash_refused_below_block", "flash_cell_shape",
     "flash_t384", "flash_t1152", "flash_head_dim_512",
     "paged_decode_cell_shape", "paged_decode_fp32_odd_bucket",
-    "latent_decode_cell_shape",
+    "latent_decode_cell_shape", "grouped_decode_cell_shape",
 ])
 def test_kernels_compile_for_v5e(v5e, case):
     if case == "flash_refused_below_block":
@@ -159,6 +176,10 @@ def test_kernels_compile_for_v5e(v5e, case):
         # 64 heads of 640 over 8,192 pages of 64 rows, the widest bucket.
         "latent_decode_cell_shape": lambda: _compile_latent_decode(
             v5e, 64, 272),
+        # 4 x 16 heads of 192 / 128 over 4,096 pages of 64 rows of 384,
+        # the widest bucket.
+        "grouped_decode_cell_shape": lambda: _compile_grouped_decode(
+            v5e, 64, 144),
     }[case]()
     # The kernel is in the program, not a reference twin.
     assert "tpu_custom_call" in compiled.as_text()
@@ -335,6 +356,95 @@ def test_latent_decode_reads_its_pool_in_place(v5e):
                for line in hlo.splitlines()) == spec.num_layers
     assert f"[{slots},{bucket * page},{width}]" not in hlo
     assert compiled.memory_analysis().temp_size_in_bytes < 100e6
+
+
+@pytest.mark.parametrize("program", ["decode_p144", "decode_p8",
+                                     "prefill_b1024"])
+def test_global_layers_read_their_pool_in_place(v5e, program):
+    """``models.hybrid`` over a global and a window layer at the widths of
+    ``serve-mimo-closed64-mixed`` (64 query heads of 192 over 4 K/V heads
+    with V heads of 128; a global pool of 4,096 pages ``[4, 64, 384]``
+    bf16, the head before the row and a row ``[k 192 | 64 zeros | v
+    128]``; 64 slots), pools donated, compiled for the described v5e: no
+    pool (805 MB) is copied by the scatter that writes a tick's rows or a
+    block's, a decode tick's global attention is one
+    ``grouped_decode_attention`` kernel, and no view of the slots' tables
+    exists in any layout: neither the rows gathered (``[64, 9216, ...]``:
+    1.51 GB a layer at the widest bucket, re-laid out heads-major every
+    tick before ISSUE 35, a third of the cell's busy time as ``reshape``)
+    nor a ``[64, 144, 4, 64, 384]`` gather. A prefill block gathers ONE
+    slot's pages (28 MB) and runs no kernel."""
+    from ddl_tpu.models import hybrid
+    from ddl_tpu.serve.cache import hybrid_cache, ring_columns
+
+    spec = dataclasses.replace(
+        hybrid.NAMED_SPECS["mimo-v2-flash-ep16"],
+        layer_kinds=(hybrid.GLOBAL, hybrid.WINDOW),
+        ffn_kinds=(hybrid.DENSE, hybrid.DENSE))
+    pages, page, slots = 4096, 64, 64
+    ring = ring_columns(spec.window, page)
+    on = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=v5e)
+    params = jax.tree.map(
+        lambda a: on(a.shape, jnp.bfloat16), jax.eval_shape(
+            lambda: hybrid.init_hybrid_params(jax.random.PRNGKey(0), spec)))
+    cache = jax.tree.map(lambda a: on(a.shape, a.dtype), jax.eval_shape(
+        lambda: hybrid_cache(spec, pages, slots * ring, page, jnp.bfloat16)))
+    assert cache.k[0].shape == (pages, 4, page, 384) and cache.v[0] is None
+    assert cache.k[1].shape == (slots * ring, page, 8 * 192)
+    pools = {0: (cache.k[0], None), 1: (cache.k[1], cache.v[1])}
+
+    def forward(params, pools, tokens, **kw):
+        h, pools, _ = hybrid.apply_hybrid_paged(
+            params, pools, tokens, spec, page_size=page,
+            compute_dtype=jnp.bfloat16, platform="tpu", **kw)
+        return h, pools
+
+    i32 = on((), jnp.int32)
+    if program.startswith("decode"):
+        bucket = int(program.split("_p")[1])
+
+        def run(params, pools, last_tokens, lengths, active, g_table,
+                w_table):
+            positions = jnp.where(active, lengths, -1)
+            h, pools = forward(
+                params, pools, last_tokens[:, None], g_table=g_table,
+                w_table=w_table, positions=positions[:, None],
+                real=active[:, None], last=positions)
+            return hybrid.head_logits(params, h[:, 0]), pools
+
+        args = (on((slots,), jnp.int32), on((slots,), jnp.int32),
+                on((slots,), jnp.bool_), on((slots, bucket), jnp.int32),
+                on((slots, ring), jnp.int32))
+    else:
+        bucket, t = 144, 1024
+
+        def run(params, pools, tokens, length, base, g_table, w_table):
+            at = jnp.arange(t, dtype=jnp.int32)
+            real = (at < length)[None, :]
+            h, pools = forward(
+                params, pools, tokens, g_table=g_table, w_table=w_table,
+                positions=jnp.where(real, base + at, -1), real=real,
+                last=(base + length - 1)[None], base=base)
+            return h[0, -1], pools
+
+        args = (on((1, t), jnp.int32), i32, i32, on((1, bucket), jnp.int32),
+                on((1, ring), jnp.int32))
+    compiled = jax.jit(run, donate_argnums=(1,)).lower(
+        params, pools, *args).compile()
+    hlo = compiled.as_text()
+    assert _pool_sized_copies(hlo, pages * 4 * page * 384 * 2) == []
+    kernels = sum("tpu_custom_call" in line
+                  and "grouped_decode_attention" in line
+                  for line in hlo.splitlines())
+    assert kernels == (1 if program.startswith("decode") else 0)
+    for view in (f"[{slots},{bucket * page},", f"[{slots},{bucket},4,",
+                 f"[{slots},4,{bucket * page},",
+                 f"[{slots},{bucket * 4 * page},"):
+        assert view not in hlo, view
+    # A tick's temporaries are the window layer's ring views (63 MB) and
+    # the matmuls' (3.0 GB of global views a layer before ISSUE 35).
+    limit = 400e6 if program.startswith("prefill") else 150e6
+    assert compiled.memory_analysis().temp_size_in_bytes < limit
 
 
 @pytest.mark.parametrize("program", ["decode_p560", "decode_p64",

@@ -258,8 +258,12 @@ def test_kinds_have_their_own_kv_heads_and_pools(weights):
             else eng.num_pages
         assert weights["blocks"][i]["wk"].shape[1] == hkv * SPEC.head_dim
         assert weights["blocks"][i]["wv"].shape[1] == hkv * SPEC.v_head_dim
-        assert eng.cache.k[i].shape == (pages, 4, hkv * SPEC.head_dim)
-        assert eng.cache.v[i].shape == (pages, 4, hkv * SPEC.v_head_dim)
+        if kind == hybrid.GLOBAL:  # one pool, the head before the row
+            assert eng.cache.k[i].shape == (pages, hkv, 4, 128 + 128)
+            assert eng.cache.v[i] is None
+        else:
+            assert eng.cache.k[i].shape == (pages, 4, hkv * SPEC.head_dim)
+            assert eng.cache.v[i].shape == (pages, 4, hkv * SPEC.v_head_dim)
         assert ("sink" in weights["blocks"][i]) == (kind == hybrid.WINDOW)
 
 
@@ -292,6 +296,55 @@ def test_swapped_rotary_disagrees_with_the_reference(weights, swap):
 
 
 # -- (6) the two page groups ----------------------------------------------------
+
+
+def test_global_pool_keeps_a_heads_k_and_v_in_one_row(weights):
+    """A global layer's ONE pool, the head before the row: after a prefill
+    of 9 tokens into slot 1 the slot's pages hold, for each K/V head and
+    row, ``[k 12 | zeros to 128 | v 8 | zeros to 256]``, what ``project``
+    gives for that token; every other page is untouched; and the view
+    gathered through the table is those rows again."""
+    from ddl_tpu.ops.paged_attention import gather_grouped
+
+    eng = engine(weights)
+    prompt = (np.arange(9, dtype=np.int32) * 5 + 1) % SPEC.vocab
+    eng.prefill(prompt, slot=1, request_id=3)
+    layer = SPEC.layers_of(hybrid.GLOBAL)[0]
+    assert layer == 0  # its input is the embedding: project it here
+    x = hybrid.rms_norm(jnp.asarray(weights["embed"])[prompt][None],
+                        weights["blocks"][0]["ln1"], SPEC.norm_eps)
+    _, k, v = hybrid.project(x, weights["blocks"][0], SPEC, 0,
+                             jnp.arange(9)[None])
+    pool = np.asarray(eng.cache.k[layer])
+    pages = eng.tables[1][:3]
+    assert (pages >= 0).all() and (eng.tables[1][3:] < 0).all()
+    rows = pool[pages].transpose(0, 2, 1, 3).reshape(12, 1, 256)[:9]
+    np.testing.assert_allclose(rows[..., :12], np.asarray(k[0]), atol=1e-6)
+    np.testing.assert_allclose(rows[..., 128:136], np.asarray(v[0]),
+                               atol=1e-6)
+    assert (rows[..., 12:128] == 0).all() and (rows[..., 136:] == 0).all()
+    others = np.setdiff1d(np.arange(eng.num_pages), pages)
+    assert (pool[others] == 0).all()
+    kv, vv = gather_grouped(eng.cache.k[layer], jnp.asarray(eng.tables[1:2]),
+                            SPEC.head_dim, SPEC.v_head_dim)
+    np.testing.assert_array_equal(np.asarray(kv)[0, :9], rows[..., :12])
+    np.testing.assert_array_equal(np.asarray(vv)[0, :9], rows[..., 128:136])
+
+
+def test_reset_releases_the_old_pools_before_it_builds_the_new(
+        weights, monkeypatch):
+    """``Scheduler.warmup`` resets the engine twice; a reset that built
+    the new pools while it held the old ones was where the cell's
+    ``memory_peak_bytes`` was reached (PERF.md section 6, PR 35)."""
+    from ddl_tpu.serve import hybrid_engine
+
+    eng = engine(weights)
+    seen, build = [], hybrid_engine.hybrid_cache
+    monkeypatch.setattr(
+        hybrid_engine, "hybrid_cache",
+        lambda *a, **k: seen.append(eng.cache) or build(*a, **k))
+    eng.reset()
+    assert seen == [None] and eng.cache.k[0].shape[0] == eng.num_pages
 
 
 def test_window_page_is_freed_when_its_last_row_leaves_the_window(weights):

@@ -339,3 +339,179 @@ def test_hybrid_decode_reads_latent_pages_in_place_on_a_tpu(monkeypatch):
                                atol=2e-5)
     np.testing.assert_array_equal(pools[0][0], want_pools[0][0])
     np.testing.assert_allclose(pools[1][0], want_pools[1][0], atol=2e-5)
+
+
+# -- the grouped pool: a K/V head a block, V beside K (ISSUE 35) ---------------
+#
+# ``grouped_decode_attention`` against ``gather_grouped`` + ``attend_grouped``:
+# the path it replaces on the chip in ``models.hybrid``'s global layers.
+
+# The latent cases' tables (a slot with one row, a slot without pages, an
+# inactive slot that keeps its pages, a hole, a bucket the widest slot
+# fills) over a pool of the same pages and rows.
+GP, GS, GHKV, GGQ, GB, GTP = LP, LS, 2, 8, LB, LTP
+
+
+@pytest.mark.parametrize("pages_per_step", [4, 3])
+@pytest.mark.parametrize("dk,dv", [(192, 128), (128, 128)])
+@pytest.mark.parametrize("dtype,tol", [("float32", 1e-5), ("bfloat16", 2e-2)])
+@pytest.mark.parametrize("case", [
+    "partly_filled_last_page", "slot_without_pages",
+    "widest_slot_fills_the_bucket", "inactive_slot_keeps_its_pages",
+    "hole_in_the_table",
+])
+def test_grouped_kernel_matches_gather_and_attend(case, dtype, tol, dk, dv,
+                                                  pages_per_step):
+    """Rows ``[k | zeros | v]`` of K wider than V (192 / 128: 384) and of
+    equal widths (256), 4 pages a step (a table of 6 is widened to 8)
+    and 3, both K/V heads of a page in one block."""
+    from ddl_tpu.ops.paged_attention import (gather_grouped,
+                                             grouped_decode_attention,
+                                             grouped_row_widths,
+                                             grouped_rows)
+
+    table, q_pos, empty = _latent_case(case)
+    keys = jax.random.split(jax.random.PRNGKey(35), 3)
+    k_width, width = grouped_row_widths(dk, dv)
+    assert (k_width, width) == ((256, 384) if dk == 192 else (128, 256))
+    pool = grouped_rows(jax.random.normal(keys[0], (GP, GHKV, GS, dk), dtype),
+                        jax.random.normal(keys[1], (GP, GHKV, GS, dv), dtype))
+    assert pool.shape == (GP, GHKV, GS, width)
+    assert (np.asarray(pool[..., dk:k_width], np.float32) == 0).all()
+    q = jax.random.normal(keys[2], (GB, GHKV, GGQ, dk), dtype)
+    got = grouped_decode_attention(
+        q, pool, jnp.asarray(table), jnp.asarray(q_pos), v_head_dim=dv,
+        pages_per_step=pages_per_step, interpret=True)
+    assert got.dtype == q.dtype and got.shape == (GB, GHKV, GGQ, dv)
+    f32 = lambda a: a.astype(jnp.float32)
+    kv, vv = gather_grouped(f32(pool), jnp.asarray(table), dk, dv)
+    cols = np.arange(GTP * GS)[None]
+    k_pos = np.where((cols <= q_pos[:, None])
+                     & (np.repeat(table, GS, axis=1) >= 0), cols, -1)
+    want = kv_cache.attend_grouped(
+        f32(q).reshape(GB, 1, GHKV * GGQ, dk), kv, vv,
+        jnp.asarray(q_pos)[:, None], jnp.asarray(k_pos))[:, 0]
+    got = np.asarray(got, np.float32).reshape(GB, GHKV * GGQ, dv)
+    live = [b for b in range(GB) if b not in empty]
+    np.testing.assert_allclose(got[live], np.asarray(want)[live], atol=tol,
+                               rtol=0)
+    assert (got[empty] == 0).all()
+
+
+@pytest.mark.parametrize("group_heads,dk,dv,page,fits", [
+    (16, 192, 128, 64, True), (8, 128, 128, 16, True),
+    (16, 256, 192, 64, True),    # V filled to two lane tiles
+    (16, 12, 8, 64, False),      # the toys' heads: a row mostly filling
+    (16, 192, 64, 64, False),    # V under a lane tile
+    (4, 192, 128, 64, False),    # half a sublane tile of query heads
+    (16, 192, 128, 8, False),    # a page under bf16's 16-row tile
+])
+def test_grouped_kernel_accepts_whole_tiles_only(group_heads, dk, dv, page,
+                                                 fits):
+    from ddl_tpu.ops.paged_attention import grouped_kernel_accepts
+
+    assert grouped_kernel_accepts(group_heads, dk, dv, page) is fits
+
+
+def _global_decode(platform, monkeypatch=None):
+    """One decode tick of ``models.hybrid``'s paged forward over global
+    layers whose widths the kernel takes (8 query heads over one K/V
+    head, K of 136 in a part of 256, V of 128, pages of 16), 3 slots of
+    which one is inactive."""
+    import functools
+
+    from ddl_tpu.models import hybrid
+    from ddl_tpu.ops import paged_attention
+    from ddl_tpu.serve.cache import hybrid_cache
+
+    spec = hybrid.HybridSpec(
+        vocab=32, d_model=32, num_heads=8, head_dim=136, v_head_dim=128,
+        kv_heads_global=1, rotary_dim=8, d_ff=32,
+        layer_kinds=(hybrid.GLOBAL,) * 2, ffn_kinds=(hybrid.DENSE,) * 2)
+    params = hybrid.init_hybrid_params(jax.random.PRNGKey(3), spec)
+    cache = hybrid_cache(spec, 12, 0, 16, jnp.float32)
+    assert cache.k[0].shape == (12, 1, 16, 384) and cache.v[0] is None
+    pools = {i: (paged_attention.grouped_rows(
+        jax.random.normal(jax.random.PRNGKey(i), (12, 1, 16, 136)),
+        jax.random.normal(jax.random.PRNGKey(9 + i), (12, 1, 16, 128))), None)
+        for i in range(2)}
+    table = jnp.asarray([[3, 5, 7, -1], [1, -1, -1, -1], [9, 11, -1, -1]])
+    active = jnp.asarray([True, True, False])
+    positions = jnp.where(active, jnp.asarray([40, 9, 20]), -1)
+    if monkeypatch is not None:
+        monkeypatch.setattr(
+            paged_attention, "grouped_decode_attention", functools.partial(
+                paged_attention.grouped_decode_attention, interpret=True))
+
+    def forward(params, pools, tokens):
+        return hybrid.apply_hybrid_paged(
+            params, pools, tokens, spec, page_size=16, g_table=table,
+            w_table=None, positions=positions[:, None],
+            real=active[:, None], last=positions, platform=platform)
+
+    return forward, (params, pools, jnp.asarray([[1], [2], [3]]))
+
+
+def test_hybrid_decode_reads_global_pages_in_place_on_a_tpu(monkeypatch):
+    """The rule ``PagedMixer._global`` chooses by: on a TPU, at widths the
+    kernel takes, a global layer's decode is one
+    ``grouped_decode_attention`` a layer (one traced kernel) and no
+    gathered view; the CPU gathers from the same pool. Both give the same
+    rows, pools and hidden state."""
+    forward, args = _global_decode("tpu", monkeypatch)
+    trace = str(jax.make_jaxpr(forward)(*args))
+    assert len(re.findall(r"jit\[\s*name=grouped_decode_attention",
+                          trace)) == 2
+    assert trace.count("pallas_call[") == 1
+    assert "f32[3,64,384]" not in trace        # the gathered view
+    plain, _ = _global_decode("cpu")
+    assert "f32[3,64,384]" in str(jax.make_jaxpr(plain)(*args))
+    assert "grouped_decode_attention" not in str(
+        jax.make_jaxpr(plain)(*args))
+    h, pools, _ = forward(*args)
+    want_h, want_pools, _ = plain(*args)
+    live = np.asarray([0, 1])
+    np.testing.assert_allclose(np.asarray(h)[live], np.asarray(want_h)[live],
+                               atol=2e-5)
+    np.testing.assert_array_equal(pools[0][0], want_pools[0][0])
+    np.testing.assert_allclose(pools[1][0], want_pools[1][0], atol=2e-5)
+    # the tick's own rows went where the table says, the head before the row
+    assert float(jnp.abs(pools[0][0][7, 0, 40 % 16, :136]).max()) > 0
+    np.testing.assert_array_equal(pools[0][0][9], args[1][0][0][9])
+
+
+@pytest.mark.parametrize("why,t,platform,head_dim", [
+    ("prefill_block", 16, "tpu", 136),
+    ("widths_under_a_tile", 1, "tpu", 24),
+    ("platform_cpu", 1, "cpu", 136),
+])
+def test_hybrid_global_layers_gather_where_the_kernel_does_not_fit(
+        why, t, platform, head_dim):
+    """A prefill block (``base`` given), heads under a lane tile or a CPU:
+    short of any one the global layers gather their views."""
+    from ddl_tpu.models import hybrid
+    from ddl_tpu.serve.cache import hybrid_cache
+
+    spec = hybrid.HybridSpec(
+        vocab=32, d_model=32, num_heads=8, head_dim=head_dim,
+        v_head_dim=128, kv_heads_global=1, rotary_dim=8, d_ff=32,
+        layer_kinds=(hybrid.GLOBAL,), ffn_kinds=(hybrid.DENSE,))
+    params = jax.eval_shape(
+        lambda: hybrid.init_hybrid_params(jax.random.PRNGKey(0), spec))
+    cache = jax.eval_shape(lambda: hybrid_cache(spec, 12, 0, 16, jnp.float32))
+    b = 1 if t > 1 else 3
+    positions = jnp.arange(t, dtype=jnp.int32)[None] + jnp.zeros(
+        (b, 1), jnp.int32)
+
+    def forward(params, pool, tokens):
+        return hybrid.apply_hybrid_paged(
+            params, {0: (pool, None)}, tokens, spec, page_size=16,
+            g_table=jnp.zeros((b, 4), jnp.int32), w_table=None,
+            positions=positions, real=positions >= 0,
+            last=positions[:, -1], base=jnp.int32(0) if t > 1 else None,
+            platform=platform)[0]
+
+    trace = str(jax.make_jaxpr(forward)(
+        params, cache.k[0], jax.ShapeDtypeStruct((b, t), jnp.int32)))
+    assert "pallas_call" not in trace
+    assert "grouped_decode_attention" not in trace
