@@ -2,9 +2,11 @@
 
 A :func:`span` opens a ``jax.profiler.TraceAnnotation`` — so under a
 profiler session it lands on the ``/host:CPU`` plane of the same
-``.xplane.pb`` as the device's ``XLA Modules`` / ``XLA Ops`` lines, on
-THEIR clock (which the profiler aligns with the host's to about a
-millisecond, anew each session), its attributes as the event's stats —
+``.xplane.pb`` as the device's ``XLA Modules`` / ``XLA Ops`` lines and
+the runtime's own host events, on the host's clock (the profiler
+aligns the device's with it to about a millisecond, anew each session;
+``perf/clock_readers.py`` pins a serve session's to under 0.2 ms from
+the runtime's events), its attributes as the event's stats —
 and, where a truthy tracer is attached, also reports
 ``tracer.complete(name, t0, t1, **attrs)`` at exit. ``Tracer.span`` is
 that same code, so ``train/span`` or ``serve.tick`` read the same in

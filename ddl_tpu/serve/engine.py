@@ -1052,8 +1052,11 @@ class InferenceEngine(EngineHost):
             where = self._prefill_where(slot, base, t)
             fn = (self._prefill_paged_fn if self.paged
                   else self._prefill_fn)(bucket, want_logits)
-            args = (jnp.asarray(tokens), jnp.int32(t), jnp.int32(base),
-                    *where, jnp.int32(request_id))
+            # the tables first: the runtime sees the transfers in the
+            # order the hooks used to make them
+            *where, tokens, length, at, rid = self._h2d(
+                "prefill", (*where, tokens, t, base, request_id))
+            args = (tokens, length, at, *where, rid)
         out, logits = self._call("prefill", fn, args, want_logits)
         self._counted("prefill", out[1:])
         return int(out[0]), logits[:t] if want_logits else None
@@ -1073,9 +1076,10 @@ class InferenceEngine(EngineHost):
             lengths_np = np.asarray(lengths, np.int32)
             active_np = np.asarray(active, bool)
             fn, where = self._decode_where(lengths_np, active_np, _pages)
-            args = tuple(jnp.asarray(a) for a in (
-                np.asarray(last_tokens, np.int32), lengths_np,
-                np.asarray(request_ids, np.int32), active_np)) + where
+            *where, tokens, lens, rids, act = self._h2d("decode", (
+                *where, np.asarray(last_tokens, np.int32), lengths_np,
+                np.asarray(request_ids, np.int32), active_np))
+            args = (tokens, lens, rids, act, *where)
         out, logits = self._call("decode", fn, args, want_logits)
         slots = self.config.slots
         self._counted("decode", out[slots:], lengths_np, active_np)
@@ -1083,34 +1087,52 @@ class InferenceEngine(EngineHost):
 
     def _prefill_where(self, slot: int, base: int, t: int) -> tuple:
         """Make room for rows ``base..base+t-1`` of ``slot``; the
-        prefill program's arguments that say where they go."""
+        prefill program's arguments that say where they go, as host
+        values (:meth:`_h2d` uploads them)."""
         if not self.paged:
-            return (jnp.int32(slot),)
+            return (slot,)
         self._ensure_rows(slot, base + t)
-        return (jnp.asarray(self.tables[slot:slot + 1]),)
+        return (self.tables[slot:slot + 1],)
 
     def _decode_where(self, lengths, active, _pages) -> tuple:
         """Make room for each active slot's next row; the decode program
-        of the bucket that covers them and its table arguments."""
+        of the bucket that covers them and its table arguments, as host
+        values."""
         if not self.paged:
             return self._decode(), ()
         pb = self._decode_bucket(lengths, active, _pages)
-        return self._decode_paged(pb), (jnp.asarray(self.tables[:, :pb]),)
+        return self._decode_paged(pb), (self.tables[:, :pb],)
+
+    @staticmethod
+    def _h2d(kind: str, values: tuple) -> tuple:
+        """Every host->device transfer of one call's arguments, in the
+        order given, inside ``engine.h2d`` (``arrays``: how many,
+        ``bytes``: their host bytes): a numpy array by ``jnp.asarray``,
+        an int as an int32 scalar, ``None`` (an argument the program
+        does not take) as it is."""
+        sent = [v for v in values if v is not None]
+        nbytes = sum(v.nbytes if isinstance(v, np.ndarray) else 4
+                     for v in sent)
+        with span("engine.h2d", kind=kind, arrays=len(sent), bytes=nbytes):
+            return tuple(v if v is None
+                         else jnp.asarray(v) if isinstance(v, np.ndarray)
+                         else jnp.int32(v) for v in values)
 
     def _counted(self, kind: str, counts, lengths=None, active=None) -> None:
         """What a program returned behind its sampled ids (``counts``);
         the dense family's programs count nothing."""
 
     def _call(self, kind: str, fn, args, want_logits: bool):
-        """Run one compiled program on the placed params and the cache
-        and bring back what the host reads of it, ``(out np [n], logits
-        np or None)``: ``out`` is the small array that starts with the
-        sampled ids, the logits come only if the caller asked in this
-        call. Three phases: the dispatch returns before the device
-        ends, the fetch of ``out`` blocks until it has, the fetch of
-        the logits, if any, is then a plain transfer; its span is
-        entered on every call. The ``engine.*`` spans have no tracer:
-        they reach the profiler alone and read no clock."""
+        """Run one compiled program on the placed params, the cache
+        and ``args`` (already on the device: :meth:`_h2d`, inside
+        ``engine.upload``) and bring back what the host reads of it,
+        ``(out np [n], logits np or None)``: ``out`` is the small array
+        that starts with the sampled ids, the logits come only if the
+        caller asked in this call. Three phases: the dispatch returns
+        before the device ends, the fetch of ``out`` blocks until it
+        has, the fetch of the logits, if any, is then a plain transfer;
+        its span is entered on every call. The ``engine.*`` spans have
+        no tracer: they reach the profiler alone and read no clock."""
         with span("engine.dispatch", kind=kind):
             out, logits, self.cache = fn(self.params, self.cache, *args)
         with span("engine.wait", kind=kind):

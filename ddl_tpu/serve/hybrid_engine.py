@@ -227,9 +227,9 @@ class HybridEngine(InferenceEngine):
     # -- compiled programs -------------------------------------------------
 
     def _win_table(self, rows=slice(None)):
-        """The window group's tables as a program's argument; ``None``
-        (no argument at all) without the group."""
-        return jnp.asarray(self.win_tables[rows]) if self.ring else None
+        """The window group's tables as a program's argument, a host
+        array; ``None`` (no argument at all) without the group."""
+        return self.win_tables[rows] if self.ring else None
 
     def _forward(self, params, cache: HybridKVCache, tokens, **kw):
         spec = self.config.spec
@@ -313,7 +313,7 @@ class HybridEngine(InferenceEngine):
             self._slide_window(slot, base, base + t - 1)
         self._block = (base, t)
         return where + (self._win_table(slice(slot, slot + 1)),
-                        jnp.int32(slot) if self.has_state else None)
+                        slot if self.has_state else None)
 
     def _decode_where(self, lengths, active, _pages) -> tuple:
         """Each active slot's window (where the pattern has one) slides

@@ -1,6 +1,6 @@
 """The serve path's spans (ISSUE 26): one primitive, two sinks.
 
-A short paged ``Scheduler`` run, three ways — tracing off, a JSONL
+A short paged ``Scheduler`` run of each family, three ways — tracing off, a JSONL
 ``Tracer`` under a ``jax.profiler`` session, and a tracer shaped like
 the benchmark's recorder (``event`` / ``complete`` / truthiness, nothing
 else). The profiler's xplane must hold every span of the table with its
@@ -20,13 +20,19 @@ import jax
 import numpy as np
 import pytest
 
+from ddl_tpu.models import hybrid
 from ddl_tpu.models.transformer import TINY_SPEC
 from ddl_tpu.obs.trace import NULL_TRACER, Tracer, span
-from ddl_tpu.serve import InferenceEngine, Request, Scheduler, ServeConfig
+from ddl_tpu.serve import (InferenceEngine, Request, Scheduler, ServeConfig,
+                           engine_cls)
+from perf import sala_weights as sw
+from perf.serve_sala_runner import spec_of
 
 SPEC = TINY_SPEC
 PAGED = ServeConfig(spec=SPEC, slots=2, capacity=64, page_size=8,
                     num_pages=16)
+FAMILIES = {"dense": PAGED,
+            "hybrid": dataclasses.replace(PAGED, spec=hybrid.HybridSpec())}
 
 # span -> the attributes PERF.md's table gives it: each has a reader
 # in perf/span_readers.py, and no span carries any other
@@ -36,15 +42,28 @@ SPANS = {
     "serve.prefill": {"req", "n", "bucket"},
     "serve.decode": {"pages"},
     "engine.upload": {"kind"},
+    "engine.h2d": {"kind", "arrays", "bytes"},
     "engine.dispatch": {"kind"},
     "engine.wait": {"kind"},
     "engine.fetch_logits": {"kind"},
 }
+# what the second family's programs count, beside (PR 28: PERF.md's table)
+COUNTERS = {"serve.prefill": {"moe_assigned"},
+            "serve.decode": {"moe_assigned", "moe_touched", "win_pages"}}
+
+
+def attrs_of(runs, name):
+    """The attributes ``name`` carries in this run's family."""
+    extra = COUNTERS.get(name, set()) if runs["family"] == "hybrid" else set()
+    return SPANS[name] | extra
+
+
 # span -> the spans one of which must contain it on the thread
 PARENTS = {
     "serve.prefill": ("serve.tick",),
     "serve.decode": ("serve.tick",),
     "engine.upload": ("serve.prefill", "serve.decode"),
+    "engine.h2d": ("engine.upload",),
     "engine.dispatch": ("serve.prefill", "serve.decode"),
     "engine.wait": ("serve.prefill", "serve.decode"),
     "engine.fetch_logits": ("serve.prefill", "serve.decode"),
@@ -71,18 +90,18 @@ class RecorderShaped:
         self.spans.append((name, t0, t1, attrs))
 
 
-def _requests():
+def _requests(vocab=SPEC.vocab):
     rng = np.random.default_rng(3)
     lengths = (5, 12, 9, 20)  # buckets 8, 16, 16, 32
-    return [Request(id=i, prompt=rng.integers(1, SPEC.vocab, size=n,
+    return [Request(id=i, prompt=rng.integers(1, vocab, size=n,
                                               dtype=np.int32),
                     max_new_tokens=4 + i, arrival=i)
             for i, n in enumerate(lengths)]
 
 
-def _serve(tracer):
-    done, _ = Scheduler(InferenceEngine(PAGED), tracer=tracer).run(
-        _requests())
+def _serve(tracer, cfg=PAGED):
+    done, _ = Scheduler(engine_cls(cfg.spec)(cfg), tracer=tracer).run(
+        _requests(cfg.spec.vocab))
     return {i: list(c.tokens) for i, c in done.items()}
 
 
@@ -106,12 +125,13 @@ def _xplane_events(path):
     return events, modules
 
 
-@pytest.fixture(scope="module")
-def runs(tmp_path_factory):
-    out = {}
-    out["off"] = _serve(None)
+@pytest.fixture(scope="module", params=sorted(FAMILIES))
+def runs(request, tmp_path_factory):
+    cfg = FAMILIES[request.param]
+    out = {"family": request.param}
+    out["off"] = _serve(None, cfg)
     rec = RecorderShaped()
-    out["recorder"] = _serve(rec)
+    out["recorder"] = _serve(rec, cfg)
     out["rec"] = rec
     # The JSONL tracer under a profiler session. The engine compiles
     # its programs inside it: that only adds events of other names.
@@ -121,7 +141,7 @@ def runs(tmp_path_factory):
     tracer = Tracer()
     jax.profiler.start_trace(tdir, profiler_options=options)
     try:
-        out["profiler"] = _serve(tracer)
+        out["profiler"] = _serve(tracer, cfg)
     finally:
         jax.profiler.stop_trace()
     out["records"] = tracer.records
@@ -137,7 +157,7 @@ def test_span_reaches_the_xplane_with_its_attrs(runs, name):
     got = [e for e in runs["xplane"] if e[0] == name]
     assert got, f"no {name} event on /host:CPU"
     for _n, _s, _e, attrs in got:
-        assert SPANS[name] == set(attrs), (name, attrs)
+        assert attrs_of(runs, name) == set(attrs), (name, attrs)
     if name.startswith("engine."):
         assert {a["kind"] for *_x, a in got} == {"prefill", "decode"}
 
@@ -145,11 +165,14 @@ def test_span_reaches_the_xplane_with_its_attrs(runs, name):
 @pytest.mark.parametrize("child", sorted(PARENTS))
 def test_span_nests_by_containment(runs, child):
     parents = [e for e in runs["xplane"] if e[0] in PARENTS[child]]
+    calls = [e for e in runs["xplane"]
+             if e[0] in ("serve.prefill", "serve.decode")]
     for _n, s, e, attrs in (x for x in runs["xplane"] if x[0] == child):
         inside = [p for p in parents if p[1] <= s and e <= p[2]]
         assert len(inside) == 1, (child, s, e)
         if child.startswith("engine."):
-            kind = inside[0][0].split(".")[1]  # serve.prefill -> prefill
+            call = [p for p in calls if p[1] <= s and e <= p[2]]
+            kind = call[0][0].split(".")[1]  # serve.prefill -> prefill
             assert attrs["kind"] == kind
 
 
@@ -166,7 +189,7 @@ def test_jsonl_and_xplane_hold_the_same_spans(runs, name):
     order = sorted(jsonl, key=lambda r: (r["t0"], r["seq"]))
     for rec, (_n, _s, _e, attrs) in zip(order,
                                         sorted(xplane, key=lambda e: e[1])):
-        assert rec["attrs"] == attrs and SPANS[name] == set(attrs)
+        assert rec["attrs"] == attrs and attrs_of(runs, name) == set(attrs)
 
 
 @pytest.mark.parametrize("how", ["recorder", "profiler"])
@@ -220,6 +243,86 @@ def test_programs_are_named_by_kind_and_bucket(runs):
     assert buckets == {8, 16, 32}
     assert progs == {f"jit_run_prefill_b{b}" for b in buckets} \
         | {f"jit_run_decode_p{p}" for p in pages}
+
+
+def test_h2d_counts_what_it_uploads(runs):
+    """``engine.h2d``'s ``arrays`` and ``bytes`` are the call's
+    arguments as the host holds them: a decode call's four ``[slots]``
+    vectors and each page group's table, a prefill's ``[1, bucket]``
+    tokens, three int32 scalars and each group's row of its table."""
+    cfg = FAMILIES[runs["family"]]
+    ring = getattr(engine_cls(cfg.spec)(cfg), "ring", 0)
+    tables = cfg.capacity // cfg.page_size + ring  # a row of each group
+    calls = [e for e in runs["xplane"]
+             if e[0] in ("serve.prefill", "serve.decode")]
+    got = [e for e in runs["xplane"] if e[0] == "engine.h2d"]
+    assert {a["kind"] for *_x, a in got} == {"prefill", "decode"}
+    for _n, s, e, a in got:
+        call = next(c[3] for c in calls if c[1] <= s and e <= c[2])
+        groups = 2 if ring else 1
+        if a["kind"] == "decode":
+            want = cfg.slots * (3 * 4 + 1 + 4 * (call["pages"] + ring))
+        else:
+            want = 4 * (call["bucket"] + 3 + tables)
+        assert (a["arrays"], a["bytes"]) == (4 + groups, want), (a, call)
+
+
+# What each program received before ISSUE 37 moved the uploads into
+# ``engine.h2d``, recorded at its parent: two prefills and a decode, each
+# argument ``dtype[shape]``, ``None`` where the program takes none.
+_I, _M = "int32[]", "bool"
+STATE_SPEC = spec_of(sw.SalaSizes(
+    name="toy", vocab=64, d_model=32, num_heads=4, head_dim=8, kv_heads=2,
+    d_ff=64, mixers=(sw.SPARSE, sw.LINEAR, sw.LINEAR, sw.SPARSE), eps=1e-6,
+    rope_base=10_000.0, scale_emb=12.0, scale_depth=1.4, depth=32,
+    dim_model_base=16, kernel_size=4, kernel_stride=2, block_size=4, topk=4,
+    init_blocks=1, window_size=8, dense_len=16))
+HYBRID3 = dict(slots=3, capacity=64, page_size=4)
+RECEIVED = {
+    "dense-paged": (PAGED, ["int32[1,8]", _I, _I, "int32[1,8]", _I],
+                    ["int32[2]", "int32[2]", "int32[2]", "bool[2]",
+                     "int32[2,1]"]),
+    "dense-contiguous": (
+        dataclasses.replace(PAGED, page_size=0, num_pages=0),
+        ["int32[1,8]", _I, _I, _I, _I],
+        ["int32[2]", "int32[2]", "int32[2]", "bool[2]"]),
+    "hybrid-window": (
+        ServeConfig(spec=hybrid.HybridSpec(), num_pages=40, **HYBRID3),
+        ["int32[1,8]", _I, _I, "int32[1,16]", "int32[1,3]", None, _I],
+        ["int32[3]", "int32[3]", "int32[3]", "bool[3]", "int32[3,2]",
+         "int32[3,3]"]),
+    "hybrid-state": (
+        ServeConfig(spec=STATE_SPEC, num_pages=48, **HYBRID3),
+        ["int32[1,8]", _I, _I, "int32[1,16]", None, _I, _I],
+        ["int32[3]", "int32[3]", "int32[3]", "bool[3]", "int32[3,2]",
+         None]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(RECEIVED))
+def test_programs_receive_what_they_did_before_h2d(case):
+    """The same arguments, count, dtypes and shapes, each a device
+    array (one transfer) that is not weakly typed."""
+    cfg, prefill, decode = RECEIVED[case]
+    eng = engine_cls(cfg.spec)(cfg)
+    seen, call = [], eng._call
+
+    def spy(kind, fn, args, want_logits):
+        assert all(a is None or (isinstance(a, jax.Array)
+                                 and not a.weak_type) for a in args)
+        seen.append([None if a is None else
+                     f"{a.dtype}[{','.join(map(str, a.shape))}]"
+                     for a in args])
+        return call(kind, fn, args, want_logits)
+
+    eng._call = spy
+    slots = cfg.slots
+    eng.prefill(np.arange(1, 7, dtype=np.int32), slot=0, request_id=3)
+    eng.prefill(np.arange(1, 5, dtype=np.int32), slot=1, request_id=4)
+    lengths, active = np.zeros(slots, np.int32), np.zeros(slots, bool)
+    lengths[:2], active[:2] = (6, 4), True
+    eng.decode(np.zeros(slots, np.int32), lengths, np.arange(slots), active)
+    assert seen == [prefill, prefill, decode]
 
 
 @pytest.mark.parametrize("layout", ["paged", "contiguous"])
