@@ -274,6 +274,10 @@ def apply_lm_cached(
     prefill samples from its last real row, so the ``[T, vocab]``
     product is never formed.
 
+    ``compute_dtype`` casts every weight inside the program; a caller may
+    hand over weights already in that dtype (the serve engine places
+    them so), and the cast is then nothing.
+
     ``cache_k``/``cache_v [num_layers, B, C, H, D]`` are the per-layer
     ring buffers and ``cache_pos [B, C]`` the absolute position each row
     holds (``ops.kv_cache.PAD_POS`` = unwritten/stale; the attend masks
@@ -370,8 +374,10 @@ def apply_lm_paged(
 ) -> tuple[jax.Array, ...]:
     """Incremental forward against the PAGED (block-table) KV pool — the
     same layer math as :func:`apply_lm_cached` (``last_row`` likewise:
-    logits ``[B, 1, vocab]`` of that row alone), with the per-slot ring
-    replaced by one shared pool read/written through a block table:
+    logits ``[B, 1, vocab]`` of that row alone; ``compute_dtype``
+    likewise: weights already in it, as the serve engine places them,
+    cast to nothing), with the per-slot ring replaced by one shared pool
+    read/written through a block table:
 
     ``pool_k``/``pool_v [num_layers, pages, page_size, H, D]`` and
     ``pool_pos [pages, page_size]`` are the shared pool

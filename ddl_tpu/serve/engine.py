@@ -209,7 +209,9 @@ class ServeConfig:
     temperature: float = 0.0  # 0 = greedy
     top_k: int = 0  # 0 = full vocab (temperature > 0 only)
     seed: int = 0
-    compute_dtype: str | None = None  # None = fp32; "bfloat16" = MXU path
+    # None = fp32; "bfloat16" = MXU path, the dense engine's weights held
+    # in it (cast once when placed: InferenceEngine._place)
+    compute_dtype: str | None = None
     prefix_slots: int = 0  # prefix-cache pool width; 0 = off
     prefill_chunk: int = 0  # chunked-prefill block; 0 = whole-prompt
     prefill_budget: int = 0  # prefill tokens per scheduler tick; 0 = all
@@ -505,8 +507,17 @@ class InferenceEngine(EngineHost):
             path, self.config.spec, self._init_params))
 
     def _place(self, params):
-        """A host tree onto the mesh, as the programs take it."""
-        return multihost.put_tree(self.mesh, self._pspecs, params)
+        """A host tree onto the mesh, as the programs take it: in the
+        compute dtype where one is set, cast once on the device after
+        each leaf is placed (a tp shard keeps its PartitionSpec), so no
+        program casts a weight again. No copy in the handed-over dtype
+        is kept: nothing in serving writes weights. With
+        ``compute_dtype=None`` the tree keeps its own dtypes."""
+        placed = multihost.put_tree(self.mesh, self._pspecs, params)
+        dtype = self.config.dtype()
+        if dtype is None:
+            return placed
+        return jax.tree.map(lambda a: a.astype(dtype), placed)
 
     # -- sampling ----------------------------------------------------------
 

@@ -231,9 +231,26 @@ def _pool_sized_copies(hlo: str, at_least: int) -> list[str]:
     return found
 
 
+def _weight_casts(hlo: str, spec) -> list[str]:
+    """Instructions of an optimised HLO module, in any computation, that
+    read a value of a dense weight's shape from a parameter and convert
+    it to bf16: a weight cast to the compute dtype inside the program.
+    The one-row head's product, rewritten as a multiply and a reduction
+    in one fusion, widens its bf16 weight to fp32 there and narrows the
+    product back: neither is a cast of a weight to bf16."""
+    e, f, v = spec.d_model, spec.d_ff, spec.vocab
+    dims = "|".join(f"{a},{b}" for a, b in (
+        (v, e), (e, v), (e, e), (e, f), (f, e)))
+    cast = re.compile(rf"= bf16\[({dims})\]\S* convert\(%?param")
+    return [line.strip()[:100] for line in hlo.splitlines()
+            if cast.search(line)]
+
+
 @pytest.mark.parametrize("program", ["decode_32x1_p32", "prefill_1x512",
                                      "decode_32x1_p32_int8",
-                                     "prefill_1x512_one_row"])
+                                     "prefill_1x512_one_row",
+                                     "decode_32x1_p32_bf16_weights",
+                                     "prefill_1x512_one_row_bf16_weights"])
 def test_paged_forward_writes_the_stacked_pool_in_place(v5e, program):
     """``apply_lm_paged`` at the widths of ``serve-1b-closed32`` (8 heads
     of 256, a bf16 pool of 512 pages x 64 rows, fp32 weights computed in
@@ -257,7 +274,14 @@ def test_paged_forward_writes_the_stacked_pool_in_place(v5e, program):
     The prefill the scheduler runs applies the head to its last real
     row alone (ISSUE 33, ``last_row``): no ``[512, 50304]`` value, which
     the all-rows form holds in fp32 (103 MB; 412 MB at the 2048 bucket)
-    for a host that dropped it."""
+    for a host that dropped it.
+
+    Handed fp32 weights, each program casts every matrix to bf16 inside
+    it, and the decode program holds the whole ``[50304, 2048]``
+    embedding cast to look up 32 rows: 206 MB of its temporaries. The
+    dense engine holds its weights in the compute dtype
+    (``InferenceEngine._place``): handed them so (``_bf16_weights``), a
+    program casts no weight, and the decode holds under 60 MB."""
     from ddl_tpu.models.transformer import (LMSpec, apply_lm_paged,
                                             init_lm_params)
 
@@ -267,12 +291,19 @@ def test_paged_forward_writes_the_stacked_pool_in_place(v5e, program):
     b, t, tp = (slots, 1, 32) if program.startswith("decode") else (
         1, 512, 36)
     int8 = program.endswith("int8")
+    bf16_weights = program.endswith("bf16_weights")
+    program = program.removesuffix("_bf16_weights")
     on = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=v5e)
     stack = (spec.num_layers, pages, page, spec.num_heads)
     pool = on(stack + (spec.head_dim,), jnp.int8 if int8 else jnp.bfloat16)
     scales = (on(stack, jnp.float32),) * 2 if int8 else (None, None)
     layer_pool_bytes = pool.dtype.itemsize * math.prod(pool.shape[1:])
     assert layer_pool_bytes == (67_108_864 if int8 else 134_217_728)
+    weights = jax.eval_shape(
+        lambda: init_lm_params(jax.random.PRNGKey(0), spec))
+    if bf16_weights:
+        weights = jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, jnp.bfloat16), weights)
 
     one_row = program.endswith("one_row")
 
@@ -286,20 +317,20 @@ def test_paged_forward_writes_the_stacked_pool_in_place(v5e, program):
             last_row=positions[0, 0] if one_row else None)
 
     compiled = jax.jit(forward, donate_argnums=(1, 2, 3, 4, 5)).lower(
-        _shapes_on(jax.eval_shape(
-            lambda: init_lm_params(jax.random.PRNGKey(0), spec)), v5e),
+        _shapes_on(weights, v5e),
         pool, pool, on((pages, page), jnp.int32), *scales,
         on((b, t), jnp.int32), on((b, tp), jnp.int32), on((b, t), jnp.int32),
         on((b, t), jnp.int32)).compile()
     hlo = compiled.as_text()
     assert _pool_sized_copies(hlo, layer_pool_bytes) == []
+    assert (_weight_casts(hlo, spec) == []) == bf16_weights
     if program == "decode_32x1_p32":
         assert sum("custom-call(" in line and "paged_decode_attention" in line
                    for line in hlo.splitlines()) == spec.num_layers
         assert f"[{slots},{tp * page},{spec.num_heads},{spec.head_dim}]" \
             not in hlo
-        # 206 MB of them the embedding table's cast to bf16.
-        assert compiled.memory_analysis().temp_size_in_bytes < 250e6
+        assert compiled.memory_analysis().temp_size_in_bytes < (
+            60e6 if bf16_weights else 250e6)
     else:
         assert "paged_decode_attention" not in hlo
     if program.startswith("prefill"):
