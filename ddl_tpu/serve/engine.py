@@ -120,6 +120,49 @@ def _named(fn, name: str):
     return fn
 
 
+def _arg(*shape, dtype=jnp.int32):
+    """One argument of a program's packed layout (:func:`_takes_packed`)."""
+    return jax.ShapeDtypeStruct(shape, dtype)
+
+
+def pack_args(values) -> np.ndarray:
+    """One call's arguments as the one int32 vector its program takes:
+    each value that is not ``None`` flattened, in the order given (an
+    int one element, a bool array 0 / 1)."""
+    return np.concatenate([np.asarray(v, np.int32).reshape(-1)
+                           for v in values if v is not None])
+
+
+def unpack_args(packed, layout) -> tuple:
+    """The arguments :func:`pack_args` put in ``packed``, cut out by
+    static slices: each entry of ``layout`` gives one's shape and dtype
+    (a bool is ``!= 0``), ``None`` an argument that was not sent."""
+    n = sum(a.size for a in layout if a is not None)
+    if packed.shape != (n,) or packed.dtype != jnp.int32:
+        raise ValueError(f"this program takes {n} packed int32 values, got "
+                         f"{packed.dtype}{list(packed.shape)}")
+    args, at = [], 0
+    for a in layout:
+        if a is None:
+            args.append(None)
+            continue
+        v = packed[at:at + a.size].reshape(a.shape)
+        args.append(v != 0 if a.dtype == jnp.bool_ else v)
+        at += a.size
+    return tuple(args)
+
+
+def _takes_packed(run, layout):
+    """``run(params, cache, *args)`` as a program takes it: ``(params,
+    cache, packed)``, one host-to-device transfer a call. ``layout``,
+    fixed when the program is built, is ``args``' shapes and dtypes."""
+
+    def take(params, cache, packed):
+        return run(params, cache, *unpack_args(packed, layout))
+
+    return take
+
+
 class _LedgeredProgram:
     """First-call AOT capture of one cached serve program for the
     collective ledger (ISSUE 20, obs.comms). Built ONLY when the
@@ -134,8 +177,8 @@ class _LedgeredProgram:
     ``Compiled`` object to the hook (which fetches the optimized HLO
     text and publishes the ledger), and dispatches every call —
     including the first — through that same executable. ``Compiled``
-    honors the jit's donation and accepts the host scalars the call
-    sites pass, so the dispatch semantics are the jit's own. ``lower``
+    honors the jit's donation, so the dispatch semantics are the jit's
+    own. ``lower``
     delegates to the underlying jitted fn (the AOT probes in tests
     lower cached programs directly)."""
 
@@ -437,6 +480,17 @@ class InferenceEngine(EngineHost):
             return jfn
         return _LedgeredProgram(self, kind, key, jfn)
 
+    def _program(self, kind: str, key: int, name: str, run, layout):
+        """One prefill or decode program: ``run(params, cache, *args)``
+        named ``name``, taking ``args`` packed (``layout``:
+        :func:`_takes_packed`), the cache donated; jitted, ledgered and
+        noted as built."""
+        fn = self._ledgered(kind, key, jax.jit(
+            _named(_takes_packed(run, layout), name),
+            donate_argnums=donation_for(self.mesh, 1)))
+        self._note_compile(kind, key)
+        return fn
+
     # -- state -------------------------------------------------------------
 
     def reset(self) -> None:
@@ -560,8 +614,9 @@ class InferenceEngine(EngineHost):
 
     def _prefill_fn(self, bucket: int, all_rows: bool = False):
         """Compiled prefill for prompt blocks padded to ``bucket``
-        tokens: ``(params, cache, tokens [1, bucket], length, base,
-        slot, request_id) -> (next_token, logits [1, vocab], cache)``:
+        tokens: ``(params, cache, packed) -> (next_token, logits [1,
+        vocab], cache)``, ``packed`` holding ``tokens [1, bucket],
+        length, base, slot, request_id`` (:func:`_takes_packed`):
         the head is applied to the last real row alone, the one the
         token is sampled from. ``all_rows`` is the other form, for a
         caller that asked for logits: ``logits [bucket, vocab]`` of
@@ -618,14 +673,16 @@ class InferenceEngine(EngineHost):
             out_specs=(P_(), self._cspecs),
             check_vma=False,
         )
-        fns[bucket] = self._prefill_program(shard, bucket, all_rows, "c")
+        fns[bucket] = self._prefill_program(shard, bucket, all_rows, "c",
+                                            _arg())
         return fns[bucket]
 
     def _prefill_program(self, shard, bucket: int, all_rows: bool,
-                         layout: str):
+                         layout: str, where):
         """Either layout's prefill program around its ``shard``: sample
         from the last real row's logits, name, jit and ledger it.
-        ``where`` is the slot (contiguous) or its table (paged)."""
+        ``where`` is the slot (contiguous) or its table (paged); the
+        argument ``where``, its shape."""
 
         def run(params, cache, tokens, length, base, where, request_id):
             logits, cache = shard(params, cache, tokens, length, base, where)
@@ -642,18 +699,15 @@ class InferenceEngine(EngineHost):
             return nxt, logits, cache
 
         kind, form = ("prefill_rows", "rows_") if all_rows else ("prefill", "")
-        fn = self._ledgered(
-            kind, bucket,
-            jax.jit(_named(run, f"run_prefill_{form}{layout}{bucket}"),
-                    donate_argnums=donation_for(self.mesh, 1)),
-        )
-        self._note_compile(kind, bucket)
-        return fn
+        return self._program(kind, bucket,
+                             f"run_prefill_{form}{layout}{bucket}", run,
+                             self._prefill_layout(bucket, where))
 
     def _decode(self):
         """Compiled decode step: one token for every slot at once.
-        ``(params, cache, last_tokens [S], lengths [S], request_ids [S],
-        active [S]) -> (next_tokens [S], logits [S, vocab], cache)``."""
+        ``(params, cache, packed) -> (next_tokens [S], logits [S,
+        vocab], cache)``, ``packed`` holding ``last_tokens [S], lengths
+        [S], request_ids [S], active [S]``."""
         if self._decode_fn is not None:
             return self._decode_fn
         fwd = self._shard_forward()
@@ -682,12 +736,8 @@ class InferenceEngine(EngineHost):
             nxt = jax.vmap(self._sample)(logits, request_ids, lengths + 1)
             return nxt, logits, cache
 
-        self._decode_fn = self._ledgered(
-            "decode", 0,
-            jax.jit(_named(run, "run_decode_c"),
-                    donate_argnums=donation_for(self.mesh, 1)),
-        )
-        self._note_compile("decode", 0)
+        self._decode_fn = self._program("decode", 0, "run_decode_c", run,
+                                        self._decode_layout())
         return self._decode_fn
 
     # -- paged compiled programs -------------------------------------------
@@ -721,10 +771,10 @@ class InferenceEngine(EngineHost):
 
     def _prefill_paged_fn(self, bucket: int, all_rows: bool = False):
         """Paged prefill for prompt blocks padded to ``bucket`` tokens:
-        ``(params, pool, tokens [1, bucket], length, base,
-        table [1, max_pages], request_id) -> (next_token,
-        logits [1, vocab], pool)``, or ``logits [bucket, vocab]`` in
-        the ``all_rows`` form (``run_prefill_rows_b<bucket>``). Same
+        ``(params, pool, packed) -> (next_token, logits [1, vocab],
+        pool)``, or ``logits [bucket, vocab]`` in the ``all_rows`` form
+        (``run_prefill_rows_b<bucket>``); ``packed`` holds ``tokens [1,
+        bucket], length, base, table [1, max_pages], request_id``. Same
         sampling/offset/form contract
         as the contiguous ``_prefill_fn`` — writes route through the
         slot's block table instead of a slot slice, padded tails map
@@ -762,7 +812,8 @@ class InferenceEngine(EngineHost):
             out_specs=(P_(), self._pcspecs),
             check_vma=False,
         )
-        fns[bucket] = self._prefill_program(shard, bucket, all_rows, "b")
+        fns[bucket] = self._prefill_program(shard, bucket, all_rows, "b",
+                                            _arg(1, self.max_pages))
         return fns[bucket]
 
     def _decode_paged(self, pages: int):
@@ -771,8 +822,9 @@ class InferenceEngine(EngineHost):
         instead of ``capacity``, so per-token cost tracks what the batch
         actually holds. One compiled program per bucket (powers of two
         capped at the table width), same sampling contract as the
-        contiguous ``_decode``. Inactive slots' writes map out of
-        bounds and DROP — a mid-prefill or free slot touches nothing."""
+        contiguous ``_decode``, ``packed`` holding its four vectors and
+        ``table [S, pages]``. Inactive slots' writes map out of bounds
+        and DROP — a mid-prefill or free slot touches nothing."""
         if pages in self._decode_paged_fns:
             return self._decode_paged_fns[pages]
         ps, num_pages = self.page_size, self.num_pages
@@ -803,13 +855,10 @@ class InferenceEngine(EngineHost):
             nxt = jax.vmap(self._sample)(logits, request_ids, lengths + 1)
             return nxt, logits, pool
 
-        fn = self._ledgered(
-            "decode", pages,
-            jax.jit(_named(run, f"run_decode_p{pages}"),
-                    donate_argnums=donation_for(self.mesh, 1)),
-        )
+        fn = self._program("decode", pages, f"run_decode_p{pages}", run,
+                           self._decode_layout(_arg(self.config.slots,
+                                                    pages)))
         self._decode_paged_fns[pages] = fn
-        self._note_compile("decode", pages)
         return fn
 
     def _copy_page(self):
@@ -1063,12 +1112,9 @@ class InferenceEngine(EngineHost):
             where = self._prefill_where(slot, base, t)
             fn = (self._prefill_paged_fn if self.paged
                   else self._prefill_fn)(bucket, want_logits)
-            # the tables first: the runtime sees the transfers in the
-            # order the hooks used to make them
-            *where, tokens, length, at, rid = self._h2d(
-                "prefill", (*where, tokens, t, base, request_id))
-            args = (tokens, length, at, *where, rid)
-        out, logits = self._call("prefill", fn, args, want_logits)
+            packed = self._h2d("prefill",
+                               (tokens, t, base, *where, request_id))
+        out, logits = self._call("prefill", fn, packed, want_logits)
         self._counted("prefill", out[1:])
         return int(out[0]), logits[:t] if want_logits else None
 
@@ -1087,11 +1133,9 @@ class InferenceEngine(EngineHost):
             lengths_np = np.asarray(lengths, np.int32)
             active_np = np.asarray(active, bool)
             fn, where = self._decode_where(lengths_np, active_np, _pages)
-            *where, tokens, lens, rids, act = self._h2d("decode", (
-                *where, np.asarray(last_tokens, np.int32), lengths_np,
-                np.asarray(request_ids, np.int32), active_np))
-            args = (tokens, lens, rids, act, *where)
-        out, logits = self._call("decode", fn, args, want_logits)
+            packed = self._h2d("decode", (last_tokens, lengths_np,
+                                          request_ids, active_np, *where))
+        out, logits = self._call("decode", fn, packed, want_logits)
         slots = self.config.slots
         self._counted("decode", out[slots:], lengths_np, active_np)
         return out[:slots], logits
@@ -1099,7 +1143,7 @@ class InferenceEngine(EngineHost):
     def _prefill_where(self, slot: int, base: int, t: int) -> tuple:
         """Make room for rows ``base..base+t-1`` of ``slot``; the
         prefill program's arguments that say where they go, as host
-        values (:meth:`_h2d` uploads them)."""
+        values (:meth:`_h2d` packs and uploads them)."""
         if not self.paged:
             return (slot,)
         self._ensure_rows(slot, base + t)
@@ -1114,28 +1158,35 @@ class InferenceEngine(EngineHost):
         pb = self._decode_bucket(lengths, active, _pages)
         return self._decode_paged(pb), (self.tables[:, :pb],)
 
+    def _prefill_layout(self, bucket: int, *where) -> tuple:
+        """What a prefill program's ``run`` takes after the params and
+        the cache, in the order :meth:`prefill` packs it: ``tokens [1,
+        bucket], length, base, *where, request_id``."""
+        return (_arg(1, bucket), _arg(), _arg(), *where, _arg())
+
+    def _decode_layout(self, *where) -> tuple:
+        """A decode program's likewise: ``last_tokens, lengths,
+        request_ids, active`` (``[slots]`` each), ``*where``."""
+        s = self.config.slots
+        return (_arg(s), _arg(s), _arg(s), _arg(s, dtype=jnp.bool_), *where)
+
     @staticmethod
-    def _h2d(kind: str, values: tuple) -> tuple:
-        """Every host->device transfer of one call's arguments, in the
-        order given, inside ``engine.h2d`` (``arrays``: how many,
-        ``bytes``: their host bytes): a numpy array by ``jnp.asarray``,
-        an int as an int32 scalar, ``None`` (an argument the program
-        does not take) as it is."""
-        sent = [v for v in values if v is not None]
-        nbytes = sum(v.nbytes if isinstance(v, np.ndarray) else 4
-                     for v in sent)
-        with span("engine.h2d", kind=kind, arrays=len(sent), bytes=nbytes):
-            return tuple(v if v is None
-                         else jnp.asarray(v) if isinstance(v, np.ndarray)
-                         else jnp.int32(v) for v in values)
+    def _h2d(kind: str, values: tuple):
+        """One call's arguments in ONE host->device transfer, inside
+        ``engine.h2d`` (``arrays``: the transfers made, 1; ``bytes``:
+        the packed buffer's): :func:`pack_args` of ``values``, ``None``
+        (an argument the program does not take) left out."""
+        nbytes = 4 * sum(np.size(v) for v in values if v is not None)
+        with span("engine.h2d", kind=kind, arrays=1, bytes=nbytes):
+            return jnp.asarray(pack_args(values))
 
     def _counted(self, kind: str, counts, lengths=None, active=None) -> None:
         """What a program returned behind its sampled ids (``counts``);
         the dense family's programs count nothing."""
 
-    def _call(self, kind: str, fn, args, want_logits: bool):
+    def _call(self, kind: str, fn, packed, want_logits: bool):
         """Run one compiled program on the placed params, the cache
-        and ``args`` (already on the device: :meth:`_h2d`, inside
+        and ``packed`` (already on the device: :meth:`_h2d`, inside
         ``engine.upload``) and bring back what the host reads of it,
         ``(out np [n], logits np or None)``: ``out`` is the small array
         that starts with the sampled ids, the logits come only if the
@@ -1145,7 +1196,7 @@ class InferenceEngine(EngineHost):
         its span is entered on every call. The ``engine.*`` spans have
         no tracer: they reach the profiler alone and read no clock."""
         with span("engine.dispatch", kind=kind):
-            out, logits, self.cache = fn(self.params, self.cache, *args)
+            out, logits, self.cache = fn(self.params, self.cache, packed)
         with span("engine.wait", kind=kind):
             out = np.asarray(out).reshape(-1)
         with span("engine.fetch_logits", kind=kind):

@@ -66,9 +66,8 @@ import numpy as np
 from jax import lax
 
 from ..models import hybrid
-from ..parallel.mesh import donation_for
 from .cache import HybridKVCache, PagePool, hybrid_cache, ring_columns
-from .engine import InferenceEngine, _named
+from .engine import InferenceEngine, _arg
 
 # What the family does not serve yet, by the config field that asks for
 # it: each is refused by name when the engine is built.
@@ -231,6 +230,10 @@ class HybridEngine(InferenceEngine):
         array; ``None`` (no argument at all) without the group."""
         return self.win_tables[rows] if self.ring else None
 
+    def _win_layout(self, rows: int):
+        """:meth:`_win_table`'s place in a program's packed layout."""
+        return _arg(rows, self.ring) if self.ring else None
+
     def _forward(self, params, cache: HybridKVCache, tokens, **kw):
         spec = self.config.spec
         pools = {i: kept if kept[2] is not None else kept[:2]
@@ -247,12 +250,13 @@ class HybridEngine(InferenceEngine):
                         for i in layers))
 
     def _prefill_paged_fn(self, bucket: int, all_rows: bool = False):
-        """``(params, cache, tokens [1, bucket], length, base, g_table [1,
+        """``(params, cache, packed) -> ([next, assigned, touched],
+        logits [1, vocab] of the last real position, cache)``, ``packed``
+        holding ``tokens [1, bucket], length, base, g_table [1,
         max_pages], w_table [1, R] or None, slot or None (a pattern with
-        linear layers: whose state), request_id) -> ([next, assigned,
-        touched], logits [1, vocab] of the last real position, cache)``.
-        The family's one form: a caller that asks for logits
-        (``all_rows``) gets that row."""
+        linear layers: whose state), request_id``. The family's one
+        form: a caller that asks for logits (``all_rows``) gets that
+        row."""
         if bucket in self._prefill_fns:
             return self._prefill_fns[bucket]
 
@@ -270,19 +274,20 @@ class HybridEngine(InferenceEngine):
             nxt = self._sample(logits[0], request_id, base + length)
             return jnp.concatenate([nxt[None], counts]), logits, cache
 
-        fn = self._ledgered(
-            "prefill", bucket,
-            jax.jit(_named(run, f"run_prefill_b{bucket}"),
-                    donate_argnums=donation_for(self.mesh, 1)))
+        fn = self._program("prefill", bucket, f"run_prefill_b{bucket}", run,
+                           self._prefill_layout(
+                               bucket, _arg(1, self.max_pages),
+                               self._win_layout(1),
+                               _arg() if self.has_state else None))
         self._prefill_fns[bucket] = fn
-        self._note_compile("prefill", bucket)
         return fn
 
     def _decode_paged(self, pages: int):
-        """``(params, cache, last_tokens [S], lengths [S], request_ids [S],
-        active [S], g_table [S, pages], w_table [S, R] or None) -> ([next [S],
-        assigned, touched], logits [S, vocab], cache)``. A slot that is
-        not active writes nothing and counts nothing."""
+        """``(params, cache, packed) -> ([next [S], assigned, touched],
+        logits [S, vocab], cache)``, ``packed`` holding ``last_tokens
+        [S], lengths [S], request_ids [S], active [S], g_table [S,
+        pages], w_table [S, R] or None``. A slot that is not active
+        writes nothing and counts nothing."""
         if pages in self._decode_paged_fns:
             return self._decode_paged_fns[pages]
 
@@ -297,12 +302,11 @@ class HybridEngine(InferenceEngine):
             nxt = jax.vmap(self._sample)(logits, request_ids, lengths + 1)
             return jnp.concatenate([nxt, counts]), logits, cache
 
-        fn = self._ledgered(
-            "decode", pages,
-            jax.jit(_named(run, f"run_decode_p{pages}"),
-                    donate_argnums=donation_for(self.mesh, 1)))
+        slots = self.config.slots
+        fn = self._program("decode", pages, f"run_decode_p{pages}", run,
+                           self._decode_layout(_arg(slots, pages),
+                                               self._win_layout(slots)))
         self._decode_paged_fns[pages] = fn
-        self._note_compile("decode", pages)
         return fn
 
     # -- host API: the base class's bracket, through its three hooks -------

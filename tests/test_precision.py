@@ -46,6 +46,7 @@ from ddl_tpu.serve import (
     ServeConfig,
 )
 from ddl_tpu.serve.cache import kv_row_bytes
+from ddl_tpu.serve.engine import pack_args
 from ddl_tpu.strategies.seq import SeqConfig, SeqTrainer
 from ddl_tpu.train.config import TrainConfig
 from ddl_tpu.train.trainer import make_train_step
@@ -170,12 +171,13 @@ def test_fp32_paged_serve_off_path_no_int8():
     eng = InferenceEngine(ServeConfig(**cfg))
     assert not eng.quantized
     assert len(jax.tree.leaves(eng.cache)) == 3
-    txt = eng._decode_paged(2).lower(
-        eng.params, eng.cache,
+    packed = pack_args((
         np.zeros(2, np.int32), np.zeros(2, np.int32),
         np.zeros(2, np.int32), np.zeros(2, bool),
-        np.zeros((2, eng.max_pages), np.int32),
-    ).as_text()
+        np.zeros((2, 2), np.int32),
+    ))
+    txt = eng._decode_paged(2).lower(
+        eng.params, eng.cache, jnp.asarray(packed)).as_text()
     assert " s8[" not in txt
     # The int8 pool carries exactly the two extra scale planes.
     q = InferenceEngine(ServeConfig(**cfg, kv_dtype="int8"))

@@ -16,6 +16,7 @@ from ddl_tpu.models import hybrid
 from ddl_tpu.models.transformer import LMSpec
 from ddl_tpu.obs import MetricRegistry
 from ddl_tpu.serve import Request, Scheduler, ServeConfig, engine_cls
+from ddl_tpu.serve.engine import pack_args
 from ddl_tpu.serve.sim import CostModelEngine
 
 # A vocabulary no other width of the model equals: "<bucket>x48x" in a
@@ -48,10 +49,10 @@ def _prompt(n, seed, vocab=SPEC.vocab):
 def _prefill_text(eng, all_rows):
     """The lowered text of one prefill program at ``BUCKET``."""
     build = eng._prefill_paged_fn if eng.paged else eng._prefill_fn
-    where = (jnp.asarray(eng.tables[:1]) if eng.paged else jnp.int32(0))
+    where = eng.tables[:1] if eng.paged else 0
+    packed = pack_args((np.zeros((1, BUCKET), np.int32), 5, 0, where, 0))
     return build(BUCKET, all_rows).lower(
-        eng.params, eng.cache, jnp.zeros((1, BUCKET), jnp.int32),
-        jnp.int32(5), jnp.int32(0), where, jnp.int32(0)).as_text()
+        eng.params, eng.cache, jnp.asarray(packed)).as_text()
 
 
 def _asking(eng):

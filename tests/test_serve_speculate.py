@@ -24,6 +24,7 @@ from ddl_tpu.serve import (
     greedy_accept,
     propose_draft,
 )
+from ddl_tpu.serve.engine import pack_args
 
 SPEC = TINY_SPEC
 
@@ -169,11 +170,11 @@ def test_speculate_k0_compiles_byte_identical_program():
     for cfg in (base, dataclasses.replace(base, speculate_k=4)):
         eng = InferenceEngine(cfg)
         S = cfg.slots
-        zeros = jnp.zeros(S, jnp.int32)
+        zeros = np.zeros(S, np.int32)
+        packed = pack_args((zeros, zeros, zeros, np.zeros(S, bool),
+                            np.zeros((S, 1), np.int32)))
         lowered = eng._decode_paged(1).lower(
-            eng.params, eng.cache, zeros, zeros, zeros,
-            jnp.zeros(S, bool), jnp.zeros((S, 1), jnp.int32),
-        )
+            eng.params, eng.cache, jnp.asarray(packed))
         texts.append(lowered.as_text())
     assert texts[0] == texts[1]
 

@@ -17,6 +17,7 @@ import os
 import re
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -25,6 +26,7 @@ from ddl_tpu.models.transformer import TINY_SPEC
 from ddl_tpu.obs.trace import NULL_TRACER, Tracer, span
 from ddl_tpu.serve import (InferenceEngine, Request, Scheduler, ServeConfig,
                            engine_cls)
+from ddl_tpu.serve import engine as engine_mod
 from perf import sala_weights as sw
 from perf.serve_sala_runner import spec_of
 
@@ -246,10 +248,10 @@ def test_programs_are_named_by_kind_and_bucket(runs):
 
 
 def test_h2d_counts_what_it_uploads(runs):
-    """``engine.h2d``'s ``arrays`` and ``bytes`` are the call's
-    arguments as the host holds them: a decode call's four ``[slots]``
-    vectors and each page group's table, a prefill's ``[1, bucket]``
-    tokens, three int32 scalars and each group's row of its table."""
+    """``engine.h2d`` makes one transfer a call (``arrays`` 1) of the
+    packed buffer (``bytes``): a decode call's four ``[slots]`` vectors
+    and each page group's table, a prefill's ``[1, bucket]`` tokens,
+    three scalars and each group's row of its table, 4 B a value."""
     cfg = FAMILIES[runs["family"]]
     ring = getattr(engine_cls(cfg.spec)(cfg), "ring", 0)
     tables = cfg.capacity // cfg.page_size + ring  # a row of each group
@@ -259,17 +261,16 @@ def test_h2d_counts_what_it_uploads(runs):
     assert {a["kind"] for *_x, a in got} == {"prefill", "decode"}
     for _n, s, e, a in got:
         call = next(c[3] for c in calls if c[1] <= s and e <= c[2])
-        groups = 2 if ring else 1
         if a["kind"] == "decode":
-            want = cfg.slots * (3 * 4 + 1 + 4 * (call["pages"] + ring))
+            want = cfg.slots * 4 * (4 + call["pages"] + ring)
         else:
             want = 4 * (call["bucket"] + 3 + tables)
-        assert (a["arrays"], a["bytes"]) == (4 + groups, want), (a, call)
+        assert (a["arrays"], a["bytes"]) == (1, want), (a, call)
 
 
-# What each program received before ISSUE 37 moved the uploads into
-# ``engine.h2d``, recorded at its parent: two prefills and a decode, each
-# argument ``dtype[shape]``, ``None`` where the program takes none.
+# What each program's ``run`` received before the uploads moved into
+# ``engine.h2d`` and were packed, as recorded then: a prefill and a
+# decode, each argument ``dtype[shape]``, ``None`` where it takes none.
 _I, _M = "int32[]", "bool"
 STATE_SPEC = spec_of(sw.SalaSizes(
     name="toy", vocab=64, d_model=32, num_heads=4, head_dim=8, kv_heads=2,
@@ -299,30 +300,102 @@ RECEIVED = {
 }
 
 
-@pytest.mark.parametrize("case", sorted(RECEIVED))
-def test_programs_receive_what_they_did_before_h2d(case):
-    """The same arguments, count, dtypes and shapes, each a device
-    array (one transfer) that is not weakly typed."""
-    cfg, prefill, decode = RECEIVED[case]
-    eng = engine_cls(cfg.spec)(cfg)
-    seen, call = [], eng._call
+def _spy_calls(monkeypatch, eng):
+    """Record what each program call receives beside the params and the
+    cache (``dtype[shape]``), and what its ``run`` receives once unpacked
+    (at the program's trace, ``None`` where it takes no argument)."""
+    calls, unpacked = [], []
+    call, unpack = eng._call, engine_mod.unpack_args
 
-    def spy(kind, fn, args, want_logits):
-        assert all(a is None or (isinstance(a, jax.Array)
-                                 and not a.weak_type) for a in args)
-        seen.append([None if a is None else
-                     f"{a.dtype}[{','.join(map(str, a.shape))}]"
-                     for a in args])
-        return call(kind, fn, args, want_logits)
+    def spy_call(kind, fn, packed, want_logits):
+        calls.append(f"{packed.dtype}[{','.join(map(str, packed.shape))}]"
+                     if isinstance(packed, jax.Array) and not packed.weak_type
+                     else repr(packed))
+        return call(kind, fn, packed, want_logits)
 
-    eng._call = spy
-    slots = cfg.slots
+    def spy_unpack(packed, layout):
+        args = unpack(packed, layout)
+        unpacked.append([None if a is None else
+                         f"{a.dtype}[{','.join(map(str, a.shape))}]"
+                         for a in args])
+        return args
+
+    monkeypatch.setattr(eng, "_call", spy_call)
+    monkeypatch.setattr(engine_mod, "unpack_args", spy_unpack)
+    return calls, unpacked
+
+
+def _prefill_prefill_decode(eng):
     eng.prefill(np.arange(1, 7, dtype=np.int32), slot=0, request_id=3)
     eng.prefill(np.arange(1, 5, dtype=np.int32), slot=1, request_id=4)
+    slots = eng.config.slots
     lengths, active = np.zeros(slots, np.int32), np.zeros(slots, bool)
     lengths[:2], active[:2] = (6, 4), True
     eng.decode(np.zeros(slots, np.int32), lengths, np.arange(slots), active)
-    assert seen == [prefill, prefill, decode]
+
+
+@pytest.mark.parametrize("case", sorted(RECEIVED))
+def test_programs_receive_what_they_did_before_h2d(case, monkeypatch):
+    """Each call hands its program ONE int32 vector, not weakly typed,
+    of as many values as the arguments hold; unpacked, ``run`` receives
+    the same arguments as before, count, dtypes and shapes, ``None``
+    where it took none (each program traced once: two prefills of one
+    bucket, then a decode)."""
+    cfg, prefill, decode = RECEIVED[case]
+    eng = engine_cls(cfg.spec)(cfg)
+    calls, unpacked = _spy_calls(monkeypatch, eng)
+    _prefill_prefill_decode(eng)
+    assert unpacked == [prefill, decode]
+
+    def size(arg):  # "int32[1,8]" -> 8
+        dims = arg[arg.index("[") + 1:-1]
+        return int(np.prod([int(d) for d in dims.split(",") if d]))
+
+    sizes = [sum(size(a) for a in args if a is not None)
+             for args in (prefill, prefill, decode)]
+    assert calls == [f"int32[{n}]" for n in sizes]
+
+
+@pytest.mark.parametrize("case", sorted(RECEIVED))
+def test_each_call_makes_one_transfer(case, monkeypatch):
+    """A prefill and a decode of a warm engine put ONE array on the
+    device each: ``jax.device_put`` and ``jnp.asarray`` counted."""
+    cfg = RECEIVED[case][0]
+    eng = engine_cls(cfg.spec)(cfg)
+    _prefill_prefill_decode(eng)  # every program built before counting
+    made = []
+    for mod, name in ((jax, "device_put"), (jnp, "asarray")):
+        real = getattr(mod, name)
+        monkeypatch.setattr(mod, name, lambda *a, _r=real, **k: (
+            made.append(1), _r(*a, **k))[1])
+    for _ in range(2):
+        before = len(made)
+        _prefill_prefill_decode(eng)
+        assert len(made) - before == 3
+
+
+@pytest.mark.parametrize("case", ["dense-paged", "hybrid-state"])
+def test_packed_programs_serve_the_ids_of_per_argument_calls(
+        case, monkeypatch):
+    """A short ``Scheduler.run`` gives the same ids when each program's
+    unchanged ``run`` takes its arguments one transfer each, as before
+    packing: the packed path changes no arithmetic."""
+    cfg = dataclasses.replace(RECEIVED[case][0], prefill_chunk=8)
+    packed = _serve(None, cfg)
+
+    def per_argument(kind, values):
+        return tuple(v if v is None
+                     else jnp.asarray(v) if isinstance(v, np.ndarray)
+                     else jnp.int32(v) for v in values)
+
+    call = InferenceEngine._call
+    monkeypatch.setattr(engine_mod, "_takes_packed", lambda run, layout: run)
+    monkeypatch.setattr(InferenceEngine, "_h2d", staticmethod(per_argument))
+    monkeypatch.setattr(InferenceEngine, "_call", lambda self, kind, fn, args,
+                        want: call(self, kind, lambda p, c, a: fn(p, c, *a),
+                                   args, want))
+    assert _serve(None, cfg) == packed
+    assert sum(map(len, packed.values())) == 4 + 5 + 6 + 7
 
 
 @pytest.mark.parametrize("layout", ["paged", "contiguous"])
